@@ -1,10 +1,10 @@
 """Tiled-CSL format benchmarks: encode throughput, compression ratio,
-padding overhead, and reorder conflict scores vs sparsity.
+slot padding and bytes per non-zero vs sparsity.
 
 Validates the format-level numbers everything else relies on:
-  * bytes ratio vs dense bf16 (the Load-as-Sparse win): 4B/nz words
-  * measured pad overhead (the IMBALANCE constant in launch/specs.py)
-  * sublane conflict score: reorder=none vs interleave vs greedy (Alg.3)
+  * bytes ratio vs dense bf16 (the Load-as-Sparse win)
+  * measured slot padding (what ``roofline.analytic_max_nnz`` models)
+  * streamed bytes per true non-zero, padding included
 
 CSV: name,us_per_call,derived.
 """
@@ -30,16 +30,10 @@ def run(full: bool = False) -> List[str]:
         t = tiled_csl.encode(a)
         enc_us = (time.perf_counter() - t0) * 1e6
         ratio = t.nbytes_sparse / t.nbytes_dense
-        w0 = np.asarray(t.words[0, 0])
-        nz0 = int(np.asarray(t.nnz[0, 0]))
-        score_i = tiled_csl.sublane_conflict_score(w0, nz0, t.k_tb)
-        t_none = tiled_csl.encode(a, reorder="none")
-        wn = np.asarray(t_none.words[0, 0])
-        score_n = tiled_csl.sublane_conflict_score(wn, nz0, t_none.k_tb)
         rows.append(
             f"tiledcsl_encode_{m}x{k}_s{int(s * 100)},{enc_us:.0f},"
             f"bytes_ratio={ratio:.3f};pad_overhead={t.pad_overhead:.3f};"
-            f"conflict_interleave={score_i:.2f};conflict_none={score_n:.2f};"
+            f"slots={t.slots};bytes_per_nnz={t.bytes_per_nonzero:.2f};"
             f"mb_per_s={(m * k * 4 / 2 ** 20) / (enc_us / 1e6):.0f}")
     # roundtrip sanity at 80%
     a = rng.standard_normal((1024, 1024), dtype=np.float32)
@@ -49,7 +43,7 @@ def run(full: bool = False) -> List[str]:
     rel = err / float(np.max(np.abs(a)))
     rows.append(f"tiledcsl_roundtrip_relerr,{rel * 1e6:.3f},bf16_rounding")
 
-    # grouped encoding (gate+up style): the shared max_nnz costs a little
+    # grouped encoding (gate+up style): the shared slot count costs a little
     # extra padding vs two independent encodings — measure that delta, since
     # it is the price of the one-launch grouped kernel (DESIGN.md §8).
     mats = []
@@ -64,6 +58,6 @@ def run(full: bool = False) -> List[str]:
     rows.append(
         f"tiledcsl_encode_group_g2_1024x1024_s80,{enc_us:.0f},"
         f"bytes_ratio={tg.nbytes_sparse / tg.nbytes_dense:.3f};"
-        f"shared_maxnnz_overhead={tg.nbytes_sparse / solo_bytes - 1.0:.4f};"
+        f"shared_slots_overhead={tg.nbytes_sparse / solo_bytes - 1.0:.4f};"
         f"pad_overhead={tg.pad_overhead:.3f}")
     return rows

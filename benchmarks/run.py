@@ -5,7 +5,7 @@
   e2e_throughput   Fig.13 / Fig.15 / Fig.16 + Table 1 — tokens/chip-s, memory
   serving_load     DESIGN.md §13 — open-loop TTFT/TPOT percentiles
   spec_decode      DESIGN.md §11 — speculative tokens/step + accept rate
-  format_bench     Tiled-CSL format: compression, padding, reorder scores
+  format_bench     Tiled-CSL format: compression, slot padding, bytes/nnz
   pruning_study    §6.3.1 — pruning accuracy case study (reduced scale)
   roofline (CSV)   §Roofline rows from dry-run records, when present
 
@@ -31,6 +31,8 @@ def main() -> None:
                     help="loadgen trace seed (reproducible traffic)")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (e2e_throughput, format_bench, kernel_bench,
                             pruning_study, serving_load, spec_decode,
                             utilization)

@@ -6,8 +6,8 @@ extract stage (the paper's shared-memory pressure analogue):
 
     mxu_util  = T_ideal_compute / T_step
     hbm_util  = T_memory / T_step
-    vmem_cost = extract bytes (nnz · (4B read + 4B scatter write)) + dense
-                A-tile write-through — relative to VMEM bw (~22x HBM).
+    vmem_cost = extract bytes (slot words read + dense A-tile write) + MXU
+                operand reads — relative to VMEM bw (~22x HBM).
 
 Fig.11 (latency breakdown): per-stage times of the LSCD kernel under the
 two-level-overlap model (stages overlap; wall = max(stages)):
@@ -27,12 +27,13 @@ from repro.core import roofline
 VMEM_BW = 18e12  # ~per-chip VMEM bandwidth (v5e class, order-of-magnitude)
 
 
-def stage_times(m: int, k: int, n: int, sparsity: float,
-                pad: float = 0.05) -> dict:
-    nnz = m * k * (1 - sparsity) * (1 + pad)
-    gmem = (nnz * 4 + 2 * (k * n + m * n)) / roofline.HBM_BW
-    # extract: read words + scatter-write nnz vals + zero-fill m*k
-    vmem = (nnz * 8 + m * k * 2           # sparse->dense transform
+def stage_times(m: int, k: int, n: int, sparsity: float) -> dict:
+    # column-slotted words at the analytic slot count (128x128 tiles)
+    words = m * k / (128 * 128) * roofline.analytic_max_nnz(
+        128, 128, sparsity, columns=m // 128 * k)
+    gmem = (words * 4 + 2 * (k * n + m * n)) / roofline.HBM_BW
+    # extract: read the slot words + write the dense tile
+    vmem = (words * 4 + m * k * 2         # sparse->dense transform
             + (m * k + k * n) * 2          # MXU operand reads
             + m * n * 4) / VMEM_BW
     mxu = 2.0 * m * k * n / roofline.PEAK_FLOPS_BF16

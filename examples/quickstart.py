@@ -1,7 +1,7 @@
 """Quickstart: the Flash-LLM pipeline in 60 lines.
 
   1. make a dense weight, prune it to 80% unstructured sparsity
-  2. reformat to Tiled-CSL (the paper's sparse encoding + AOT reorder)
+  2. reformat to Tiled-CSL (the paper's sparse encoding, column-slotted)
   3. run the Load-as-Sparse / Compute-as-Dense SpMM (Pallas, interpret
      mode on CPU) and check it against the dense result
   4. print the memory + roofline numbers behind the paper's claim

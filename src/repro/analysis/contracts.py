@@ -19,16 +19,19 @@ so the *enforcement sites* can import it without cycles:
 
 Checked invariants (one rule id each):
 
-KC-LOC    ``m_tb * k_tb <= 65536``: the packed Tiled-CSL word stores the
-          intra-tile location in 16 bits; a larger tile silently wraps
-          ``loc & 0xFFFF`` and corrupts weight placement.
+KC-LOC    ``m_tb < 65535``: the packed Tiled-CSL word stores the in-tile
+          row in 16 bits and reserves the all-ones row for padding; a
+          taller tile would wrap ``row & 0xFFFF`` or alias the pad marker
+          and corrupt weight placement.
 KC-GRID   the dense dims must tile evenly (``m % m_tb == k % k_tb == 0``)
           — the BlockSpec index maps assume exact tiling of M and K (N is
           exempt: ``ops.spmm`` pads N to the tile before launch).
 KC-SPLIT  ``1 <= split_k <= Kt``: a K slice with zero real tiles is pure
           partials traffic; ``split_k < 1`` breaks the partials grid.
 KC-NTB    ``n_tb`` must be a positive multiple of 8 (VPU sublane quantum)
-          and at most 128 (TPU lane width).
+          and at most 128 (TPU lane width). On the ``pallas`` backend
+          Mosaic also needs the N block to fill the lanes: ``n_tb == 128``
+          or a single N tile (``n_tb`` equal to the padded N).
 KC-VMEM   the launch's static VMEM footprint — double-buffered in/out
           blocks plus accumulator scratch, for BOTH kernels of a split-K
           pair — must fit the per-backend budget
@@ -57,8 +60,9 @@ from repro.analysis import budgets
 from repro.analysis.findings import Finding
 from repro.core import roofline
 
-#: 16-bit intra-tile location capacity of the packed Tiled-CSL word.
-MAX_TILE_ELEMS = 65536
+#: Rows the packed Tiled-CSL word's 16-bit row field can address; the
+#: all-ones value (``tiled_csl.PAD_ROW``) is the padding marker.
+MAX_TILE_ROWS = 0xFFFF
 
 #: TPU vector lane/sublane geometry the N tile must respect.
 LANE_WIDTH = 128
@@ -81,19 +85,19 @@ class ScheduleContractError(ValueError):
         super().__init__("; ".join(f"{f.rule}: {f.message}" for f in findings))
 
 
-def tile_loc_ok(m_tb: int, k_tb: int) -> bool:
-    """KC-LOC predicate: tile fits the 16-bit intra-tile loc field."""
-    return m_tb * k_tb <= MAX_TILE_ELEMS
+def tile_loc_ok(m_tb: int) -> bool:
+    """KC-LOC predicate: tile rows fit the 16-bit row field."""
+    return 1 <= m_tb < MAX_TILE_ROWS
 
 
-def require_tile_loc(m_tb: int, k_tb: int) -> None:
+def require_tile_loc(m_tb: int) -> None:
     """Raise ``ValueError`` on KC-LOC violation (shared with
     ``tiled_csl.encode`` — the message is part of its API)."""
-    if not tile_loc_ok(m_tb, k_tb):
+    if not tile_loc_ok(m_tb):
         raise ValueError(
-            f"tile geometry ({m_tb},{k_tb}) needs {m_tb * k_tb} intra-tile "
-            f"locations but the 16-bit loc field holds at most "
-            f"{MAX_TILE_ELEMS}")
+            f"tile height m_tb={m_tb} does not fit the 16-bit row field "
+            f"(at most {MAX_TILE_ROWS - 1} rows; {MAX_TILE_ROWS} marks "
+            f"padding)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,6 +107,8 @@ class VmemBreakdown:
     ``main_bytes`` is the compute kernel's footprint; ``reduce_bytes`` the
     split-K reduce kernel's (0 when ``split_k == 1``). The checkable
     footprint is their max — the two are separate launches.
+    ``expand_bytes`` is the dense tile the slot expansion builds (f32,
+    plus its cast copy for the MXU).
     """
 
     words_bytes: int
@@ -110,12 +116,14 @@ class VmemBreakdown:
     out_block_bytes: int
     bias_bytes: int
     acc_scratch_bytes: int
+    expand_bytes: int
     reduce_bytes: int
 
     @property
     def main_bytes(self) -> int:
         return (self.words_bytes + self.b_block_bytes + self.out_block_bytes
-                + self.bias_bytes + self.acc_scratch_bytes)
+                + self.bias_bytes + self.acc_scratch_bytes
+                + self.expand_bytes)
 
     @property
     def total_bytes(self) -> int:
@@ -123,27 +131,27 @@ class VmemBreakdown:
 
 
 def schedule_vmem_breakdown(m_tb: int, k_tb: int, n_tb: int, split_k: int, *,
-                            group: int = 1, max_nnz: Optional[int] = None,
-                            sparsity: float = 0.0, b_dtype_bytes: int = 4,
+                            max_nnz: int, group: int = 1,
+                            b_dtype_bytes: int = 4,
                             out_dtype_bytes: int = 4) -> VmemBreakdown:
     """Model the VMEM-resident bytes of one LSCD SpMM launch.
 
     Mirrors the BlockSpecs in ``kernels/spmm.py`` exactly: the A stream is
-    one tile's packed words ``[max_nnz]`` (uint32), B is a ``[k_tb, n_tb]``
-    block, the output block is ``[group, m_tb, n_tb]`` (f32 partials
+    one tile's packed words ``[slots, k_tb]`` (uint32, ``slots * k_tb ==
+    max_nnz``; VMEM pads the lane dim to 128), the expansion builds one
+    dense ``[m_tb, k_tb]`` f32 tile plus its B-dtype copy, B is a
+    ``[k_tb, n_tb]`` block, the output block is ``[group, m_tb, n_tb]`` (f32 partials
     ``[1, (group,) m_tb, n_tb]`` for split-K pass 1), the accumulator
     scratch is f32 ``[group, m_tb, n_tb]``. In/out blocks are charged at
     ``DOUBLE_BUFFER`` x for the grid pipeline; scratch at 1x. For split-K
     the reduce kernel's ``[split_k, group, m_tb, n_tb]`` f32 input block is
     modeled too and the reported total is the max of the two launches.
-
-    ``max_nnz``, when None, falls back to the DESIGN.md §4 analytic bound
-    from ``sparsity`` — the same estimate the roofline uses.
     """
-    if max_nnz is None:
-        max_nnz = roofline.analytic_max_nnz(m_tb, k_tb, sparsity)
     g = max(1, group)
-    words = 4 * max_nnz * DOUBLE_BUFFER
+    slots = -(-max_nnz // k_tb)
+    lanes = -(-k_tb // LANE_WIDTH) * LANE_WIDTH
+    words = 4 * slots * lanes * DOUBLE_BUFFER
+    expand = m_tb * k_tb * (4 + b_dtype_bytes)
     b_blk = k_tb * n_tb * b_dtype_bytes * DOUBLE_BUFFER
     # split-K pass 1 writes one f32 partials slice [1,(g,)m_tb,n_tb];
     # the fused kernel writes the final [g,m_tb,n_tb] in out_dtype.
@@ -156,7 +164,7 @@ def schedule_vmem_breakdown(m_tb: int, k_tb: int, n_tb: int, split_k: int, *,
         reduce_b = (split_k * g * m_tb * n_tb * 4 * DOUBLE_BUFFER   # partials in
                     + g * m_tb * n_tb * out_dtype_bytes * DOUBLE_BUFFER
                     + bias)
-    return VmemBreakdown(words, b_blk, out_blk, bias, acc, reduce_b)
+    return VmemBreakdown(words, b_blk, out_blk, bias, acc, expand, reduce_b)
 
 
 def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
@@ -171,12 +179,12 @@ def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
     ``path`` labels the findings (e.g. ``select(m,k,n)`` or a bench cell).
     """
     out: List[Finding] = []
-    if not tile_loc_ok(m_tb, k_tb):
+    if not tile_loc_ok(m_tb):
         out.append(Finding(
             "KC-LOC", path, 0,
-            f"tile ({m_tb},{k_tb}) needs {m_tb * k_tb} intra-tile locations "
-            f"but the 16-bit loc field holds at most {MAX_TILE_ELEMS}",
-            hint="shrink m_tb or k_tb so m_tb*k_tb <= 65536"))
+            f"tile height m_tb={m_tb} does not fit the 16-bit row field "
+            f"(at most {MAX_TILE_ROWS - 1} rows)",
+            hint=f"shrink m_tb below {MAX_TILE_ROWS}"))
     if m_tb < 1 or k_tb < 1 or m % m_tb or k % k_tb:
         out.append(Finding(
             "KC-GRID", path, 0,
@@ -190,6 +198,12 @@ def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
             f"n_tb={n_tb} is not a multiple of {SUBLANE_QUANTUM} in "
             f"[{SUBLANE_QUANTUM}, {LANE_WIDTH}]",
             hint="use the N_TB_LADDER values (8..128)"))
+    elif backend == "pallas" and n_tb != LANE_WIDTH and n > n_tb:
+        out.append(Finding(
+            "KC-NTB", path, 0,
+            f"n_tb={n_tb} splits N={n} into blocks narrower than the "
+            f"{LANE_WIDTH} lanes, which Mosaic cannot tile",
+            hint=f"use n_tb={LANE_WIDTH}, or one N tile >= N"))
     kt = -(-k // k_tb) if k_tb >= 1 else 0
     if split_k < 1 or (kt and split_k > kt):
         out.append(Finding(
@@ -198,9 +212,13 @@ def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
             hint="cap split_k at the K tile count"))
     budget = budgets.vmem_budget(backend)
     if budget is not None and not out:
+        if max_nnz is None:   # the DESIGN.md §4 bound the roofline uses
+            max_nnz = roofline.analytic_max_nnz(
+                m_tb, k_tb, sparsity,
+                columns=max(1, group) * -(-m // m_tb) * kt * k_tb)
         bd = schedule_vmem_breakdown(
             m_tb, k_tb, n_tb, split_k, group=group, max_nnz=max_nnz,
-            sparsity=sparsity, b_dtype_bytes=b_dtype_bytes,
+            b_dtype_bytes=b_dtype_bytes,
             out_dtype_bytes=out_dtype_bytes)
         if bd.total_bytes > budget:
             which = ("reduce kernel" if bd.reduce_bytes > bd.main_bytes

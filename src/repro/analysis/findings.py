@@ -31,7 +31,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 RULES: Dict[str, str] = {
     # kernel contract checker (contracts.py / kernel_pass.py)
     "KC-VMEM": "kernel launch VMEM footprint exceeds the backend budget",
-    "KC-LOC": "tile geometry overflows the 16-bit intra-tile loc field",
+    "KC-LOC": "tile height overflows the 16-bit in-tile row field",
     "KC-GRID": "grid/index-map divisibility broken for the launch shape",
     "KC-SPLIT": "split_k outside [1, Kt] wastes or breaks the partials grid",
     "KC-NTB": "N tile not lane-aligned (multiple of 8, cap 128)",

@@ -80,7 +80,7 @@ def _walk_eqns(jaxpr, visit) -> None:
 
 
 def _sub_jaxprs(val):
-    import jax.core as jcore
+    import jax.extend.core as jcore
     vals = val if isinstance(val, (tuple, list)) else (val,)
     for v in vals:
         if isinstance(v, jcore.ClosedJaxpr):

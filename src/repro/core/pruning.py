@@ -52,8 +52,8 @@ def tile_balanced_mask(scores: jax.Array, sparsity: float,
     """Beyond-paper: keep exactly ceil((1-s)·m_tb·k_tb) top elements per tile.
 
     Still *unstructured within the tile* (any position allowed), but per-tile
-    counts are equal, so the padded Tiled-CSL stream has ~zero padding
-    overhead and perfectly balanced per-tile decode work. Accuracy impact is
+    counts are equal. (The column-slotted Tiled-CSL layout pads to the
+    fullest tile *column*, which this does not balance.) Accuracy impact is
     between global-unstructured and block-structured pruning; the paper's
     accuracy argument (element freedom) is preserved at tile granularity.
     """
@@ -110,27 +110,14 @@ def _pad_to_tiles(w: np.ndarray, m_tb: int, k_tb: int) -> np.ndarray:
 def sparsify_matrix(w: jax.Array, sparsity: float, *,
                     method: str = "magnitude", balanced: bool = False,
                     m_tb: int = tiled_csl.DEFAULT_M_TB,
-                    k_tb: int = tiled_csl.DEFAULT_K_TB,
-                    max_nnz: Optional[int] = None,
-                    reorder: str = "interleave") -> tiled_csl.TiledCSL:
-    """Prune a dense [M, K] weight and encode it as Tiled-CSL.
-
-    ``max_nnz`` overrides the per-matrix pad target (needed when stacking
-    layers for lax.scan: every layer's encoding must share one max_nnz).
-    """
+                    k_tb: int = tiled_csl.DEFAULT_K_TB
+                    ) -> tiled_csl.TiledCSL:
+    """Prune a dense [M, K] weight and encode it as Tiled-CSL."""
     wp = np.asarray(jax.device_get(
         prune(jnp.asarray(w, jnp.float32), sparsity, method=method,
               balanced=balanced)))
     wp = _pad_to_tiles(wp, m_tb, k_tb)
-    t = tiled_csl.encode(wp, m_tb=m_tb, k_tb=k_tb, reorder=reorder)
-    if max_nnz is not None and max_nnz != t.max_nnz:
-        if max_nnz < t.max_nnz:
-            raise ValueError(f"max_nnz override {max_nnz} < required {t.max_nnz}")
-        pad = max_nnz - t.max_nnz
-        words = jnp.pad(t.words, ((0, 0), (0, 0), (0, pad)))
-        t = tiled_csl.TiledCSL(words=words, nnz=t.nnz, shape=t.shape,
-                               m_tb=t.m_tb, k_tb=t.k_tb, dtype=t.dtype)
-    return t
+    return tiled_csl.encode(wp, m_tb=m_tb, k_tb=k_tb)
 
 
 def _pregroupable(ws) -> bool:
@@ -140,9 +127,9 @@ def _pregroupable(ws) -> bool:
     if not all(isinstance(w, tiled_csl.TiledCSL) for w in ws):
         return False
     key = (ws[0].shape, ws[0].m_tb, ws[0].k_tb, ws[0].words.ndim,
-           ws[0].words.shape[0] if ws[0].words.ndim == 4 else None)
+           ws[0].words.shape[0] if ws[0].words.ndim == 5 else None)
     return all((w.shape, w.m_tb, w.k_tb, w.words.ndim,
-                w.words.shape[0] if w.words.ndim == 4 else None) == key
+                w.words.shape[0] if w.words.ndim == 5 else None) == key
                for w in ws) and sparse_linear.balanced_group(ws)
 
 
@@ -195,13 +182,13 @@ def group_projections(params: Any) -> Any:
 
 def sparsify_params(params: Any, sparsity: float,
                     should_sparsify: Callable[[str], bool],
-                    *, method: str = "magnitude", balanced: bool = False,
-                    reorder: str = "interleave") -> Any:
+                    *, method: str = "magnitude", balanced: bool = False
+                    ) -> Any:
     """Walk a params pytree; convert selected 2-D weights to Tiled-CSL.
 
     ``should_sparsify(path_str)`` decides per leaf (e.g. keep router /
     embedding / norm weights dense). Stacked scan weights [L, M, K] are
-    encoded per layer with a shared max_nnz and re-stacked.
+    encoded per layer, padded to one shared slot count and re-stacked.
     """
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     treedef = jax.tree_util.tree_structure(params)
@@ -212,16 +199,13 @@ def sparsify_params(params: Any, sparsity: float,
                 and should_sparsify(name)):
             if leaf.ndim == 2:
                 out_leaves.append(sparsify_matrix(
-                    leaf, sparsity, method=method, balanced=balanced,
-                    reorder=reorder))
+                    leaf, sparsity, method=method, balanced=balanced))
             else:  # stacked [L, M, K] scan weights
                 per_layer = [sparsify_matrix(
-                    leaf[i], sparsity, method=method, balanced=balanced,
-                    reorder=reorder) for i in range(leaf.shape[0])]
-                mx = max(t.max_nnz for t in per_layer)
-                per_layer = [sparsify_matrix(
-                    leaf[i], sparsity, method=method, balanced=balanced,
-                    max_nnz=mx, reorder=reorder) for i in range(leaf.shape[0])]
+                    leaf[i], sparsity, method=method, balanced=balanced)
+                    for i in range(leaf.shape[0])]
+                mx = max(t.slots for t in per_layer)
+                per_layer = [tiled_csl.pad_slots(t, mx) for t in per_layer]
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
                 out_leaves.append(stacked)
         else:

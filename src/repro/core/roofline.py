@@ -1,4 +1,4 @@
-"""Three-term roofline model (compute / memory / collective) for TPU v5e.
+"""Three-term roofline model (compute / memory / collective) for the TPU.
 
 Terms (per step, per the assignment spec):
 
@@ -16,13 +16,48 @@ level by the benchmarks.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 import re
 from typing import Dict, Optional
 
-# ---- TPU v5e hardware constants (assignment-specified) ---------------------
-PEAK_FLOPS_BF16 = 197e12      # 197 TFLOP/s bf16 per chip
-HBM_BW = 819e9                # 819 GB/s per chip
-ICI_BW = 50e9                 # ~50 GB/s per link
+# ---- per-chip peaks, keyed by jax ``Device.device_kind`` --------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float            # FLOP/s, bf16 MXU
+    hbm_bw: float                # bytes/s
+    hbm_bytes: float             # HBM capacity
+    ici_bw: float                # bytes/s per chip-to-chip link
+
+
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+#: of HBM at 819 GB/s, 1,600 Gbit/s of interconnect per chip (4 links).
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(bf16_flops=197e12, hbm_bw=819e9,
+                               hbm_bytes=16e9, ici_bw=50e9),
+}
+
+#: The chip the analytic (device-free) roofline describes. Every model in
+#: this module reads these constants; a second chip in the table needs its
+#: peaks threaded through first.
+ANALYTIC_PEAKS = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = ANALYTIC_PEAKS.bf16_flops
+HBM_BW = ANALYTIC_PEAKS.hbm_bw
+ICI_BW = ANALYTIC_PEAKS.ici_bw
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """Peaks of a measured device. A kind missing from the table is an
+    error, never a default: a roofline share against another chip's peaks
+    is wrong by construction."""
+    if device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it "
+            f"to roofline.DEVICE_PEAKS with its source "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -153,15 +188,9 @@ def parse_collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    jax >= 0.5 returns a flat dict; 0.4.x returns a one-element list of
-    dicts (one per partitioned executable). Always hand back a dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def from_cost_analysis(cost: dict, hlo_text: str, chips: int, *,
@@ -352,17 +381,33 @@ class SplitKTerms:
         return d
 
 
-# Analytic per-tile stream bound when no measured encoding is at hand:
-# tile_elems · (1−s) · IMBALANCE, padded to PAD_QUANTUM words (DESIGN.md §4;
-# IMBALANCE measured for random unstructured masks at 128×128).
-_MAX_NNZ_IMBALANCE = 1.15
-_PAD_QUANTUM_WORDS = 128
+# Analytic per-tile stream bound when no measured encoding is at hand
+# (DESIGN.md §4). The column-slotted layout gives every tile column the slot
+# count of the fullest column among all the columns that share one
+# encoding: every tile of a matrix, of every layer of a scan stack and of
+# every member of a projection group. For a random unstructured mask a
+# column of m_tb rows holds Binomial(m_tb, 1−s) non-zeros; the model takes
+# the median of the maximum over ``columns`` such columns (the smallest c
+# with columns·P(X > c) <= 1/2) and rounds up to the 8-row sublane quantum.
+# At 80% sparsity on 128-row tiles: one 1536x8960 matrix (~1e5 columns)
+# gives 47 -> 48 slots, a 28-layer stack of them (~3e6) 51 -> 56 slots.
+_SLOT_QUANTUM = 8
 
 
-def analytic_max_nnz(m_tb: int, k_tb: int, sparsity: float) -> int:
-    words = m_tb * k_tb * (1.0 - sparsity) * _MAX_NNZ_IMBALANCE
-    q = _PAD_QUANTUM_WORDS
-    return int(-(-words // q) * q) if words > 0 else q
+@functools.lru_cache(maxsize=1024)
+def analytic_max_nnz(m_tb: int, k_tb: int, sparsity: float, *,
+                     columns: int) -> int:
+    """Padded words per tile (``slots * k_tb``) of an encoding whose slot
+    count is shared by ``columns`` tile columns."""
+    d = min(max(1.0 - sparsity, 0.0), 1.0)
+    pmf = [math.comb(m_tb, j) * d ** j * (1.0 - d) ** (m_tb - j)
+           for j in range(m_tb + 1)]
+    c, above = m_tb, 0.0                  # above = P(X > c)
+    while c > 0 and max(columns, 1) * (above + pmf[c]) <= 0.5:
+        above += pmf[c]
+        c -= 1
+    slots = max(math.ceil(c / _SLOT_QUANTUM), 1) * _SLOT_QUANTUM
+    return min(slots, math.ceil(m_tb / _SLOT_QUANTUM) * _SLOT_QUANTUM) * k_tb
 
 
 def lscd_splitk_terms(m: int, k: int, n: int, sparsity: float, *,
@@ -391,7 +436,8 @@ def lscd_splitk_terms(m: int, k: int, n: int, sparsity: float, *,
     nt = -(-n // n_tb)
     n_pad = nt * n_tb
     if max_nnz is None:
-        max_nnz = analytic_max_nnz(m_tb, k_tb, sparsity)
+        max_nnz = analytic_max_nnz(m_tb, k_tb, sparsity,
+                                   columns=group * mt * kt * k_tb)
     a_once = float(group) * mt * kt * (max_nnz * 4.0)     # words stream
     b_once = 2.0 * k * n_pad                              # bf16 activation
     c_bytes = float(group) * 2.0 * m * n_pad              # bf16 outputs

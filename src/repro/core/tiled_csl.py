@@ -5,29 +5,29 @@ list of 32-bit words, each packing a 16-bit value with a 16-bit intra-tile
 location, plus a ``TileOffsets`` array delimiting each tile's span in the flat
 ``NonZeros`` stream.
 
-TPU adaptation (see DESIGN.md §2):
+TPU adaptation — the *column-slotted* layout (see DESIGN.md §2):
 
 * values are bf16 (TPU-native 16-bit float) instead of fp16;
-* Pallas block specs need static shapes, so the per-tile lists are padded to a
-  per-matrix ``max_nnz`` (rounded up to a multiple of PAD_QUANTUM words).
-  Padding words are ``0x00000000`` == (+0.0 | loc 0) and are *scatter-added*
-  by the kernel, i.e. exact no-ops;
-* the ahead-of-time sparse data reorder (paper Alg.3) buckets non-zeros by
-  VPU **sublane** (``row % 8``) instead of the 32 shared-memory banks, and
-  interleaves buckets so every group of 8 consecutive words targets distinct
-  sublanes where the distribution allows. Two implementations are provided:
-  ``greedy`` — the paper's Alg.3 max-bucket drain, faithful but per-tile
-  Python; ``interleave`` — a fully vectorised equivalent (identical conflict
-  score when buckets are balanced) that encodes multi-billion-parameter
-  matrices in seconds. ``interleave`` is the default.
+* each tile is a ``[slots, k_tb]`` block of words: lane ``c`` holds tile
+  column ``c``, and slot ``r`` of that lane holds the column's r-th
+  non-zero as ``(bf16 value << 16) | row-in-tile``. The kernel rebuilds the
+  dense tile by a compare-select per slot (``kernels/spmm.py``), which
+  Mosaic lowers; a flat per-tile word list would need a scatter, which it
+  does not;
+* Pallas block specs need static shapes, so every column of every tile
+  carries the same ``slots`` count: the matrix's largest per-tile column
+  count, rounded up to ``SLOT_QUANTUM`` (the uint32 sublane tile, so a
+  tile's block is (8, 128)-aligned in VMEM and in HBM). Unused slots hold
+  ``PAD_WORD`` = (+0.0 | row 0xFFFF): no tile has that row, so the
+  expansion never selects it.
 
 The format is sharding-transparent: encoding is generated per TP shard, and
 tiles never cross shard boundaries (shards are tile-aligned by construction).
 
 Grouped encodings (:func:`encode_group` / :func:`group_stack`) stack G
 same-shape matrices on a leading group axis of ``words``/``nnz`` with one
-shared ``max_nnz``, so the grouped LSCD kernel can produce all G outputs in
-a single launch that streams the activation matrix once (DESIGN.md §8).
+shared ``slots`` count, so the grouped LSCD kernel can produce all G outputs
+in a single launch that streams the activation matrix once (DESIGN.md §8).
 Per-layer scan stacks (``pruning.sparsify_params`` on [L, M, K] leaves) use
 the same representation — a group is just "independent same-shape matrices
 sharing one pad target".
@@ -47,25 +47,28 @@ from repro.analysis import contracts
 # Default tile geometry: MXU native 128x128 (paper: 128x64 for 128 threads).
 DEFAULT_M_TB = 128
 DEFAULT_K_TB = 128
-# Pad per-tile word counts to a multiple of this (one 128-lane vreg row of
-# words = 512B, the efficient HBM DMA granule). Coarser quanta waste up to
-# 20% traffic on padding at 80% sparsity (measured); 128 keeps it <4%.
-PAD_QUANTUM = 128
-# Number of reorder buckets == VPU sublanes per vreg.
-N_SUBLANES = 8
+# Slots per column are padded to a multiple of the uint32 sublane tile, so
+# every tile block is (8, 128)-aligned and the kernel reads slots in whole
+# 8-row slabs.
+SLOT_QUANTUM = 8
+# Row field value that marks an unused slot (no tile has 65535 rows).
+PAD_ROW = 0xFFFF
+PAD_WORD = PAD_ROW   # (+0.0 << 16) | PAD_ROW
 
 
 @dataclasses.dataclass(frozen=True)
 class TiledCSL:
-    """A sparse matrix of logical shape ``(m, k)`` in padded Tiled-CSL format.
+    """A sparse matrix of logical shape ``(m, k)`` in column-slotted
+    Tiled-CSL format.
 
     Attributes:
-      words:  uint32[mt, kt, max_nnz] — packed (bf16 value | 16-bit location)
-              words per tile, AOT-reordered, zero-padded. A *grouped*
-              encoding (see :func:`encode_group`) carries a leading group
-              axis: uint32[G, mt, kt, max_nnz] — G same-shape matrices
-              sharing one ``max_nnz`` so a single kernel launch can stream
-              all G weight streams against one activation block.
+      words:  uint32[mt, kt, slots, k_tb] — per tile, lane ``c`` lists
+              column ``c``'s non-zeros as (bf16 value | 16-bit row) words,
+              padded with ``PAD_WORD``. A *grouped* encoding (see
+              :func:`encode_group`) carries a leading group axis:
+              uint32[G, mt, kt, slots, k_tb] — G same-shape matrices
+              sharing one ``slots`` count so a single kernel launch can
+              stream all G weight streams against one activation block.
       nnz:    int32[mt, kt] (or int32[G, mt, kt]) — true non-zero count per
               tile (<= max_nnz).
       shape:  logical dense shape (m, k) of *each* matrix;
@@ -83,21 +86,28 @@ class TiledCSL:
 
     # ---- derived -----------------------------------------------------------
     @property
+    def slots(self) -> int:
+        """Word slots per tile column (padding included)."""
+        return int(self.words.shape[-2])
+
+    @property
     def max_nnz(self) -> int:
-        return int(self.words.shape[-1])
+        """Words per tile, padding included — what the kernel DMAs per
+        tile (``slots * k_tb``)."""
+        return self.slots * int(self.words.shape[-1])
 
     @property
     def group(self) -> Optional[int]:
-        """Number of grouped matrices, or None for a plain 2-D encoding.
+        """Number of grouped matrices, or None for a plain encoding.
 
-        Caveat: grouped-ness is inferred from ``words.ndim == 4``, which is
+        Caveat: grouped-ness is inferred from ``words.ndim == 5``, which is
         the SAME layout scan/expert stacks use ([L, ...] / [E, ...] leaves
         from ``pruning.sparsify_params``) — "G independent same-shape
         matrices sharing one pad target" is one representation. Callers
         that hold a *stack* must slice the lead axis (scan does; MoE vmaps)
         before treating a leaf as a projection group; the grouped ops
         cannot tell a stack from a group on their own."""
-        return int(self.words.shape[0]) if self.words.ndim == 4 else None
+        return int(self.words.shape[0]) if self.words.ndim == 5 else None
 
     @property
     def grid(self) -> Tuple[int, int]:
@@ -117,7 +127,7 @@ class TiledCSL:
         """Bytes of the dense bf16 counterpart — counting every matrix in
         the leading word axes (group and/or scan-stack), to match what
         ``nbytes_sparse`` streams."""
-        n_mats = int(np.prod(self.words.shape[:-3], dtype=np.int64))
+        n_mats = int(np.prod(self.words.shape[:-4], dtype=np.int64))
         return int(np.prod(self.shape)) * 2 * n_mats
 
     @property
@@ -126,6 +136,12 @@ class TiledCSL:
         total_words = self.words.size
         real = self.n_nonzero
         return 1.0 - real / max(total_words, 1)
+
+    @property
+    def bytes_per_nonzero(self) -> float:
+        """Streamed A bytes per true non-zero, padding included (dense
+        bf16 costs ``2 / density``)."""
+        return self.nbytes_sparse / max(self.n_nonzero, 1)
 
 
 def _tcsl_flatten_with_keys(t: TiledCSL):
@@ -149,69 +165,42 @@ jax.tree_util.register_pytree_with_keys(
 # packing helpers
 # ---------------------------------------------------------------------------
 
-def pack_words(values: np.ndarray, locs: np.ndarray) -> np.ndarray:
-    """Pack bf16 values and 16-bit locations into uint32 words.
+def pack_words(values: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """Pack bf16 values and 16-bit in-tile rows into uint32 words.
 
-    word = (bf16_bits << 16) | loc   — the paper's (val, loc) 32-bit layout.
+    word = (bf16_bits << 16) | row — the paper's (val, loc) 32-bit layout,
+    with the column implied by the word's lane.
     """
     v = np.ascontiguousarray(values, dtype=np.float32)
     # f32 -> bf16 bits: round-to-nearest-even on the high 16 bits.
     bits32 = v.view(np.uint32)
     rounded = bits32 + np.uint32(0x7FFF) + ((bits32 >> np.uint32(16)) & np.uint32(1))
     bf16_bits = (rounded >> np.uint32(16)).astype(np.uint32)
-    loc = np.asarray(locs, dtype=np.uint32) & np.uint32(0xFFFF)
-    return (bf16_bits << np.uint32(16)) | loc
+    row = np.asarray(rows, dtype=np.uint32) & np.uint32(0xFFFF)
+    return (bf16_bits << np.uint32(16)) | row
 
 
 def unpack_words(words: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Inverse of :func:`pack_words` → (f32 values, int32 locations)."""
+    """Inverse of :func:`pack_words` → (f32 values, int32 rows)."""
     w = np.ascontiguousarray(words, dtype=np.uint32)
-    bf16_bits = (w >> np.uint32(16)).astype(np.uint32)
-    vals = (bf16_bits << np.uint32(16)).view(np.float32)
-    locs = (w & np.uint32(0xFFFF)).astype(np.int32)
-    return vals, locs
+    vals = (w & np.uint32(0xFFFF0000)).view(np.float32)
+    rows = (w & np.uint32(0xFFFF)).astype(np.int32)
+    return vals, rows
 
 
-# ---------------------------------------------------------------------------
-# AOT sparse data reordering (paper Alg.3, TPU sublane adaptation)
-# ---------------------------------------------------------------------------
+def pad_slots(t: TiledCSL, slots: int) -> TiledCSL:
+    """Pad ``t`` to ``slots`` word slots per column with ``PAD_WORD``.
 
-def _greedy_reorder_tile(rows: np.ndarray, cols: np.ndarray,
-                         vals: np.ndarray) -> np.ndarray:
-    """Paper-faithful Alg.3: repeatedly drain the fullest sublane bucket.
-
-    Returns the permutation over this tile's non-zeros.
-    """
-    n = rows.shape[0]
-    sub = rows % N_SUBLANES
-    buckets = [list(np.nonzero(sub == b)[0]) for b in range(N_SUBLANES)]
-    counts = np.array([len(b) for b in buckets])
-    heads = np.zeros(N_SUBLANES, np.int64)
-    order = np.empty(n, np.int64)
-    for i in range(n):
-        b = int(np.argmax(counts))
-        order[i] = buckets[b][heads[b]]
-        heads[b] += 1
-        counts[b] -= 1
-    return order
-
-
-def sublane_conflict_score(words: np.ndarray, nnz: int, k_tb: int) -> float:
-    """Mean number of *distinct* sublanes per group of 8 consecutive words.
-
-    8.0 is perfectly conflict-free; lower means serialized VPU stores.
-    Used by tests to assert the reorder helps vs raw row-major order.
-    """
-    if nnz == 0:
-        return float(N_SUBLANES)
-    _, locs = unpack_words(np.asarray(words)[:nnz])
-    rows = locs // k_tb
-    sub = rows % N_SUBLANES
-    scores = []
-    for g in range(0, nnz, N_SUBLANES):
-        grp = sub[g:g + N_SUBLANES]
-        scores.append(len(np.unique(grp)) / len(grp) * N_SUBLANES)
-    return float(np.mean(scores))
+    jit-safe (pure pad). Used to give a scan stack or a projection group
+    one shared slot count."""
+    if slots < t.slots:
+        raise ValueError(f"cannot pad {t.slots} slots down to {slots}")
+    if slots == t.slots:
+        return t
+    widths = ((0, 0),) * (t.words.ndim - 2) + ((0, slots - t.slots), (0, 0))
+    words = jnp.pad(t.words, widths, constant_values=PAD_WORD)
+    return TiledCSL(words=words, nnz=t.nnz, shape=t.shape, m_tb=t.m_tb,
+                    k_tb=t.k_tb, dtype=t.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -220,18 +209,13 @@ def sublane_conflict_score(words: np.ndarray, nnz: int, k_tb: int) -> float:
 
 def encode(dense: np.ndarray | jax.Array,
            m_tb: int = DEFAULT_M_TB,
-           k_tb: int = DEFAULT_K_TB,
-           reorder: str = "interleave",
-           pad_quantum: int = PAD_QUANTUM) -> TiledCSL:
-    """Encode a dense (m, k) matrix into padded Tiled-CSL.
+           k_tb: int = DEFAULT_K_TB) -> TiledCSL:
+    """Encode a dense (m, k) matrix into column-slotted Tiled-CSL.
 
     ``m`` and ``k`` must be multiples of the tile geometry (pad upstream —
-    ``ops.spmm`` handles ragged shapes). Zero elements are dropped; everything
-    else is kept with bf16-rounded values.
-
-    reorder: "interleave" (vectorised sublane interleave, default),
-             "greedy" (paper Alg.3, per-tile Python — slow, tests only),
-             "none" (row-major order; worst-case conflict baseline).
+    ``pruning.sparsify_matrix`` does). Zero elements are dropped; everything
+    else is kept with bf16-rounded values. Within a column, slots run in
+    ascending row order.
     """
     a = np.asarray(jax.device_get(dense))
     orig_dtype = jnp.bfloat16 if a.dtype == jnp.bfloat16 else jnp.dtype(str(a.dtype))
@@ -239,64 +223,31 @@ def encode(dense: np.ndarray | jax.Array,
     m, k = a.shape
     if m % m_tb or k % k_tb:
         raise ValueError(f"shape {(m, k)} not tile-aligned to ({m_tb},{k_tb})")
-    # The packed word carries a 16-bit intra-tile location; a larger tile
-    # would silently wrap ``loc & 0xFFFF`` in pack_words and corrupt the
-    # weight placement. Shared predicate with the static checker (rule
+    # The packed word carries a 16-bit in-tile row whose all-ones value
+    # marks padding. Shared predicate with the static checker (rule
     # KC-LOC, DESIGN.md §12) so encoding and checker cannot disagree.
-    contracts.require_tile_loc(m_tb, k_tb)
+    contracts.require_tile_loc(m_tb)
     mt, kt = m // m_tb, k // k_tb
-    n_tiles = mt * kt
 
-    # Coordinates of all non-zeros, vectorised.
-    rr, cc = np.nonzero(a)
-    vv = a[rr, cc]
-    tile_id = (rr // m_tb) * kt + (cc // k_tb)
-    in_r, in_c = rr % m_tb, cc % k_tb
+    mask = a != 0
+    # Running count down each column inside its row of tiles: an entry's
+    # slot is the number of non-zeros above it in its tile column.
+    cum = np.cumsum(mask.reshape(mt, m_tb, k), axis=1, dtype=np.int32)
+    col_counts = cum[:, -1, :]                                # [mt, k]
+    slots = max(int(col_counts.max()) if col_counts.size else 0, 1)
+    slots = -(-slots // SLOT_QUANTUM) * SLOT_QUANTUM
 
-    counts = np.bincount(tile_id, minlength=n_tiles).astype(np.int64)
-    max_nnz = max(int(counts.max()) if counts.size and len(vv) else 1, 1)
-    max_nnz = -(-max_nnz // pad_quantum) * pad_quantum  # ceil to quantum
-
-    words = np.zeros((n_tiles, max_nnz), np.uint32)
-    if len(vv):
-        if reorder == "greedy":
-            # Paper Alg.3: per-tile max-bucket drain (Python loop; tests only).
-            order = np.argsort(tile_id, kind="stable")
-            starts0 = np.concatenate(
-                [[0], np.cumsum(np.bincount(tile_id[order], minlength=n_tiles))])
-            perm = np.empty(len(vv), np.int64)
-            for t in range(n_tiles):
-                s, e = starts0[t], starts0[t + 1]
-                if e == s:
-                    continue
-                sl = order[s:e]
-                perm[s:e] = sl[_greedy_reorder_tile(in_r[sl], in_c[sl], vv[sl])]
-        elif reorder == "interleave":
-            # Vectorised sublane interleave: rank within (tile, bucket), then
-            # order by (tile, rank, bucket) — groups of 8 consecutive words
-            # cycle through distinct sublanes while buckets last.
-            bucket = in_r % N_SUBLANES
-            grp = tile_id * N_SUBLANES + bucket
-            order0 = np.argsort(grp, kind="stable")
-            grp_sorted = grp[order0]
-            grp_start = np.concatenate(
-                [[0], np.cumsum(np.bincount(grp_sorted, minlength=n_tiles * N_SUBLANES))])
-            rank_key = np.empty(len(vv), np.int64)
-            rank_key[order0] = np.arange(len(vv)) - grp_start[grp_sorted]
-            perm = np.lexsort((bucket, rank_key, tile_id))
-        else:  # "none" — row-major within tile (worst-case conflict baseline)
-            perm = np.lexsort((in_c, in_r, tile_id))
-
-        # perm is tile-sorted for every method; compute slot = (tile, rank).
-        tgt_tile = tile_id[perm]
-        starts = np.concatenate([[0], np.cumsum(np.bincount(tgt_tile, minlength=n_tiles))])
-        rank = np.arange(len(vv)) - starts[tgt_tile]
-        locs = (in_r[perm].astype(np.int64) * k_tb + in_c[perm]).astype(np.uint32)
-        words[tgt_tile, rank] = pack_words(vv[perm], locs)
+    words = np.full((mt, kt, slots, k_tb), PAD_WORD, np.uint32)
+    rr, cc = np.nonzero(mask)
+    if rr.size:
+        slot = cum.reshape(m, k)[rr, cc] - 1
+        words[rr // m_tb, cc // k_tb, slot, cc % k_tb] = pack_words(
+            a[rr, cc], rr % m_tb)
+    nnz = col_counts.reshape(mt, kt, k_tb).sum(axis=-1, dtype=np.int32)
 
     return TiledCSL(
-        words=jnp.asarray(words.reshape(mt, kt, max_nnz)),
-        nnz=jnp.asarray(counts.reshape(mt, kt).astype(np.int32)),
+        words=jnp.asarray(words),
+        nnz=jnp.asarray(nnz),
         shape=(m, k),
         m_tb=m_tb,
         k_tb=k_tb,
@@ -306,21 +257,18 @@ def encode(dense: np.ndarray | jax.Array,
 
 def encode_group(weights: Sequence[np.ndarray | jax.Array],
                  m_tb: int = DEFAULT_M_TB,
-                 k_tb: int = DEFAULT_K_TB,
-                 reorder: str = "interleave",
-                 pad_quantum: int = PAD_QUANTUM) -> TiledCSL:
+                 k_tb: int = DEFAULT_K_TB) -> TiledCSL:
     """Encode G same-shape (m, k) matrices as one grouped Tiled-CSL.
 
     The result stacks per-weight ``words``/``nnz`` along a leading group
-    axis and shares one ``max_nnz`` (the max over the group, re-padded with
-    exact-no-op zero words), so the grouped LSCD kernel can stream every
-    weight with a single static block shape while B is streamed once.
+    axis and shares one ``slots`` count (the max over the group, re-padded
+    with ``PAD_WORD``), so the grouped LSCD kernel can stream every weight
+    with a single static block shape while B is streamed once.
     Tiles stay per-weight — grouping changes layout, not tiling or math.
     """
     if not weights:
         raise ValueError("encode_group needs at least one weight")
-    ts = [encode(w, m_tb=m_tb, k_tb=k_tb, reorder=reorder,
-                 pad_quantum=pad_quantum) for w in weights]
+    ts = [encode(w, m_tb=m_tb, k_tb=k_tb) for w in weights]
     shapes = {t.shape for t in ts}
     if len(shapes) != 1:
         raise ValueError(f"grouped weights must share one shape, got {shapes}")
@@ -330,23 +278,23 @@ def encode_group(weights: Sequence[np.ndarray | jax.Array],
 def group_stack(ts: Sequence[TiledCSL]) -> TiledCSL:
     """Stack already-encoded same-shape TiledCSLs into a grouped TiledCSL.
 
-    Pads every member's word stream to the group max ``max_nnz`` (padding
-    words are exact no-ops) and stacks ``words``/``nnz``. jit-safe: pure
-    pad/stack, usable at trace time on weights captured as arguments —
-    though the production path pre-groups once at weight-reformat time
-    (:func:`encode_group` / ``pruning.group_projections``) so the serving
-    hot path carries no restacking traffic.
+    Pads every member to the group's largest ``slots`` (:func:`pad_slots`)
+    and stacks ``words``/``nnz``. jit-safe: pure pad/stack, usable at trace
+    time on weights captured as arguments — though the production path
+    pre-groups once at weight-reformat time (:func:`encode_group` /
+    ``pruning.group_projections``) so the serving hot path carries no
+    restacking traffic.
 
     Members that are themselves layer-stacked scan leaves (words
-    ``[L, mt, kt, w]``, as produced by ``pruning.sparsify_params`` on
-    ``[L, M, K]`` weights) stack on axis 1 → words ``[L, G, mt, kt, w]``;
-    ``lax.scan`` slices the leading L back off, yielding a per-layer
-    grouped TiledCSL inside the scan body.
+    ``[L, mt, kt, slots, k_tb]``, as produced by ``pruning.sparsify_params``
+    on ``[L, M, K]`` weights) stack on axis 1 → words
+    ``[L, G, mt, kt, slots, k_tb]``; ``lax.scan`` slices the leading L back
+    off, yielding a per-layer grouped TiledCSL inside the scan body.
     """
     ts = list(ts)
     if not ts:
         raise ValueError("group_stack needs at least one TiledCSL")
-    lead = ts[0].words.ndim - 3
+    lead = ts[0].words.ndim - 4
     if lead not in (0, 1):
         raise ValueError("group_stack members must be plain or scan-stacked "
                          f"encodings, got words rank {ts[0].words.ndim}")
@@ -357,17 +305,15 @@ def group_stack(ts: Sequence[TiledCSL]) -> TiledCSL:
         if (t.shape, t.m_tb, t.k_tb) != (ts[0].shape, ts[0].m_tb, ts[0].k_tb):
             raise ValueError("group_stack members must share shape and tile "
                              f"geometry, got {[(t.shape, t.m_tb, t.k_tb) for t in ts]}")
-    mx = max(t.max_nnz for t in ts)
-    pad = lambda w, d: w if w.shape[-1] == mx else jnp.pad(
-        w, ((0, 0),) * (d - 1) + ((0, mx - w.shape[-1]),))
-    words = jnp.stack([pad(t.words, t.words.ndim) for t in ts], axis=lead)
+    mx = max(t.slots for t in ts)
+    words = jnp.stack([pad_slots(t, mx).words for t in ts], axis=lead)
     nnz = jnp.stack([t.nnz for t in ts], axis=lead)
     return TiledCSL(words=words, nnz=nnz, shape=ts[0].shape,
                     m_tb=ts[0].m_tb, k_tb=ts[0].k_tb, dtype=ts[0].dtype)
 
 
 def group_slice(t: TiledCSL, g: int) -> TiledCSL:
-    """Member ``g`` of a grouped TiledCSL as a plain 2-D encoding."""
+    """Member ``g`` of a grouped TiledCSL as a plain encoding."""
     if t.group is None:
         raise ValueError("group_slice needs a grouped TiledCSL")
     return TiledCSL(words=t.words[g], nnz=t.nnz[g], shape=t.shape,
@@ -381,20 +327,11 @@ def decode(t: TiledCSL) -> np.ndarray:
     """
     if t.group is not None:
         return np.stack([decode(group_slice(t, g)) for g in range(t.group)])
-    m, k = t.shape
-    mt, kt = t.grid
-    words = np.asarray(jax.device_get(t.words)).reshape(mt * kt, t.max_nnz)
-    nnz = np.asarray(jax.device_get(t.nnz)).reshape(mt * kt)
-    out = np.zeros((m, k), np.float32)
-    for tid in range(mt * kt):
-        n = int(nnz[tid])
-        if n == 0:
-            continue
-        vals, locs = unpack_words(words[tid, :n])
-        ti, tj = divmod(tid, kt)
-        r = ti * t.m_tb + locs // t.k_tb
-        c = tj * t.k_tb + locs % t.k_tb
-        np.add.at(out, (r, c), vals)
+    vals, rows = unpack_words(np.asarray(jax.device_get(t.words)))
+    ti, tj, _, c = np.nonzero(rows != PAD_ROW)
+    r = rows[rows != PAD_ROW]
+    out = np.zeros(t.shape, np.float32)
+    out[ti * t.m_tb + r, tj * t.k_tb + c] = vals[rows != PAD_ROW]
     return out
 
 
@@ -409,19 +346,18 @@ def decode_jax(t: TiledCSL) -> jax.Array:
         return jax.vmap(lambda w, n: decode_jax(TiledCSL(
             words=w, nnz=n, shape=t.shape, m_tb=t.m_tb, k_tb=t.k_tb,
             dtype=t.dtype)))(t.words, t.nnz)
-    mt, kt = t.grid
-    max_nnz = t.max_nnz
     words = t.words.astype(jnp.uint32)
-    bf16_bits = (words >> 16).astype(jnp.uint16)
-    vals = jax.lax.bitcast_convert_type(bf16_bits, jnp.bfloat16).astype(jnp.float32)
-    locs = (words & 0xFFFF).astype(jnp.int32)
-    in_r = locs // t.k_tb
-    in_c = locs % t.k_tb
-    ti = jax.lax.broadcasted_iota(jnp.int32, (mt, kt, max_nnz), 0)
-    tj = jax.lax.broadcasted_iota(jnp.int32, (mt, kt, max_nnz), 1)
+    real = (words & 0xFFFF) != PAD_ROW
+    # Padding slots add +0.0 at their tile's (0, column): exact no-ops.
+    vals = jnp.where(real, jax.lax.bitcast_convert_type(
+        words & jnp.uint32(0xFFFF0000), jnp.float32), 0.0)
+    in_r = jnp.where(real, words & 0xFFFF, 0).astype(jnp.int32)
+    shp = words.shape
+    ti = jax.lax.broadcasted_iota(jnp.int32, shp, 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, shp, 1)
+    in_c = jax.lax.broadcasted_iota(jnp.int32, shp, 3)
     rows = (ti * t.m_tb + in_r).reshape(-1)
     cols = (tj * t.k_tb + in_c).reshape(-1)
-    flat_idx = rows * t.shape[1] + cols
     out = jnp.zeros((t.shape[0] * t.shape[1],), jnp.float32)
-    out = out.at[flat_idx].add(vals.reshape(-1))
+    out = out.at[rows * t.shape[1] + cols].add(vals.reshape(-1))
     return out.reshape(t.shape).astype(t.dtype)

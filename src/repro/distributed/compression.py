@@ -19,13 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.6 exposes shard_map at the top level; 0.4.x only under
-# jax.experimental. Prefer the top-level one when present (the experimental
-# module is slated for removal), fall back otherwise.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -59,7 +52,7 @@ def make_compressed_grad_allreduce(mesh: Mesh, *, axis: str = "data"):
     def reduce_leaf(g):
 
         @functools.partial(
-            _shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=P(*([axis] + [None] * (g.ndim - 1))),
             out_specs=P(*([axis] + [None] * (g.ndim - 1))))
         def f(gs):

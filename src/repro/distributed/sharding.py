@@ -7,7 +7,7 @@ matched against ``jax.tree_util.keystr`` paths. Conventions:
 * ``data`` (+ ``pod``) axes: batch DP; optionally FSDP weight shards.
 * activations: batch over ("pod","data"), model-parallel dims over "model"
   (propagated by GSPMD from the param + input shardings).
-* Tiled-CSL leaves: ``words [*, mt, kt, max_nnz]`` shard ``mt`` (the out-dim
+* Tiled-CSL leaves: ``words [*, mt, kt, slots, k_tb]`` shard ``mt`` (the out-dim
   tile axis) over model — the encoding is tile-aligned so TP shards never
   split a tile (DESIGN.md §5).
 
@@ -76,15 +76,15 @@ def _replicate(path: str, ndim: int) -> P:
     return P(*((None,) * 0))
 
 
-# Tiled-CSL: words [lead..., mt, kt, max_nnz]; nnz [lead..., mt, kt].
+# Tiled-CSL: words [lead..., mt, kt, slots, k_tb]; nnz [lead..., mt, kt].
 def _csl_words(out_sharded: bool):
     def build(path: str, ndim: int) -> P:
-        lead = ndim - 3
+        lead = ndim - 4
         if _is_routed_expert(path):
             pre = ((None,) * (lead - 1) + ("model",)) if lead >= 1 else ()
-            return P(*pre, None, None, None)
+            return P(*pre, None, None, None, None)
         mt_ax, kt_ax = ("model", None) if out_sharded else (None, "model")
-        return P(*((None,) * lead), mt_ax, kt_ax, None)
+        return P(*((None,) * lead), mt_ax, kt_ax, None, None)
     return build
 
 
@@ -104,7 +104,7 @@ def _csl_nnz(out_sharded: bool):
 _COL = ("wq", "wk", "wv", "gate", "up", "w_uq", "w_ukv", "w_dq", "in_proj",
         "w_x", "w_gate", "wa", "lm_head",
         # reformat-time grouped projections (pruning.group_projections):
-        # words [*, G, mt, kt, w] — the generic lead-axis handling in
+        # words [*, G, mt, kt, slots, k_tb] — the generic lead-axis handling in
         # _csl_words leaves the group axis unsharded, mt over model.
         "gate_up", "wqkv")
 _ROW = ("wo", "down", "out_proj", "w_out")
@@ -136,8 +136,8 @@ def rule_for(path: str, ndim: int, *, fsdp: bool = False,
     # MoE router [.., E, d]: out dim IS the expert dim — align with EP.
     if family(("router",)):
         if is_words:
-            lead = ndim - 3
-            return P(*((None,) * lead), "model", None, None)
+            lead = ndim - 4
+            return P(*((None,) * lead), "model", None, None, None)
         if is_nnz:
             return P(*((None,) * (ndim - 2)), "model", None)
         return P(*((None,) * (ndim - 2)), "model", None)
@@ -295,22 +295,10 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 
 def _context_mesh() -> Optional[Mesh]:
-    """The physical mesh from the enclosing ``with mesh:`` context, if any."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # noqa: BLE001
-        return None
+    """The mesh of the enclosing ``with mesh:`` / ``jax.set_mesh`` context,
+    if any."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x, *axes):

@@ -19,11 +19,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import budgets, contracts
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_tiles: int):
     k = pl.program_id(2)
@@ -32,8 +27,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_tiles: int):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
-                            b_ref[...].astype(jnp.float32),
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...].astype(a_ref.dtype),
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == k_tiles - 1)
@@ -45,8 +39,9 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_tiles: int):
                                               "out_dtype", "interpret"))
 def dense_gemm(a: jax.Array, b: jax.Array, *, m_tb: int = 128,
                k_tb: int = 128, n_tb: int = 128,
-               out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
-    """C[M,N] = A[M,K] @ B[K,N], MXU-tiled. Dims must divide the tiles."""
+               out_dtype=jnp.float32, interpret: bool) -> jax.Array:
+    """C[M,N] = A[M,K] @ B[K,N], MXU-tiled in A's dtype with f32
+    accumulation. Dims must divide the tiles."""
     m, k = a.shape
     n = b.shape[1]
     if m % m_tb or k % k_tb or n % n_tb:
@@ -73,7 +68,7 @@ def dense_gemm(a: jax.Array, b: jax.Array, *, m_tb: int = 128,
         out_specs=pl.BlockSpec((m_tb, n_tb), lambda mi, ni, ki: (mi, ni)),
         scratch_shapes=[pltpu.VMEM((m_tb, n_tb), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

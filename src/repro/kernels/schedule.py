@@ -55,10 +55,6 @@ N_TB_LADDER = (8, 16, 32, 64, 128)
 SPLIT_LADDER = (1, 2, 4, 8, 16)
 MKTB_LADDER = (128, 64)
 
-# tiled_csl 16-bit intra-tile location bound (rule KC-LOC; the shared
-# predicate lives in analysis.contracts so encode/select cannot disagree).
-_MAX_TILE_ELEMS = contracts.MAX_TILE_ELEMS
-
 _ENV_CACHE_VAR = "REPRO_SCHEDULE_CACHE"
 
 
@@ -198,8 +194,8 @@ def candidates(m: int, k: int, n: int, *,
 
     Tile candidates honour the encoding constraints: the dense dims must
     tile evenly (encode pads to the tile multiple, so launch-time fixed
-    geometry always divides) and ``m_tb * k_tb`` must stay under the
-    16-bit intra-tile location bound. Split candidates are capped at Kt —
+    geometry always divides) and ``m_tb`` must fit the 16-bit row field
+    (KC-LOC). Split candidates are capped at Kt —
     a slice with zero real K tiles is legal but pure waste.
     """
     m_opts = (m_tb,) if m_tb else tuple(x for x in MKTB_LADDER if m % x == 0)
@@ -209,7 +205,7 @@ def candidates(m: int, k: int, n: int, *,
     out = []
     for mtb in m_opts:
         for ktb in k_opts:
-            if not contracts.tile_loc_ok(mtb, ktb):   # KC-LOC
+            if not contracts.tile_loc_ok(mtb):   # KC-LOC
                 continue
             kt = -(-k // ktb)
             n_opts = (n_tb,) if n_tb else N_TB_LADDER
@@ -321,7 +317,7 @@ def select(m: int, k: int, n: int, sparsity: float, *,
                             backend)
 
 
-def autotune(t, n: int, *, backend: str = "interpret",
+def autotune(t, n: int, *, backend: str,
              cache: Optional[ScheduleCache] = None, reps: int = 2,
              epilogue: str = "none",
              splits: Optional[Sequence[int]] = None,
@@ -333,8 +329,9 @@ def autotune(t, n: int, *, backend: str = "interpret",
     fixed, so the sweep covers ``n_tb`` x ``split_k`` only. The winner is
     persisted to ``cache`` (or the ``REPRO_SCHEDULE_CACHE`` file) under the
     shape+backend key, where :func:`select` finds it on the next dispatch.
-    Interpret-mode timing ranks schedules by traced work, not TPU wall
-    time — on-hardware runs should use ``backend="pallas"``.
+    ``backend`` has no default: ``"pallas"`` times the TPU kernels, while
+    ``"interpret"`` ranks schedules by traced work on the CPU, not by TPU
+    wall time.
     """
     import jax
     import jax.numpy as jnp

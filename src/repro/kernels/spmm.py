@@ -6,21 +6,26 @@ activation matrix. The kernel mirrors the paper's design point-for-point,
 re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
 
 * **Load-as-Sparse**: the only A traffic is the compressed ``words`` block —
-  ``uint32[max_nnz]`` per (m, k) tile — streamed HBM→VMEM by the Pallas grid
-  pipeline. This is the paper's ``gmem2reg`` + the reduced-footprint insight.
-* **Sparse→Dense transform**: unpack (bf16 value | 16-bit loc) words and
-  scatter-add into a zeroed VMEM dense-A workspace (paper: ``rst_smem`` +
-  ``extract`` on SIMT cores; here: VPU scatter). Padding words are
-  ``(+0.0 | loc 0)`` so scatter-*add* makes them exact no-ops — no masking
-  needed in the inner loop (the paper needs Alg.2's ``nnz_thread`` bound;
-  our padded format trades that branch for a few wasted no-op lanes).
+  ``uint32[slots, k_tb]`` per (m, k) tile, column-slotted (DESIGN.md §2) —
+  streamed HBM→VMEM by the Pallas grid pipeline. This is the paper's
+  ``gmem2reg`` + the reduced-footprint insight.
+* **Sparse→Dense transform** (:func:`_expand_tile`): the dense tile is
+  built on the VPU by one compare-select per slot. Slot ``r`` is a row of
+  ``k_tb`` words, one per tile column; broadcast down the sublanes, its row
+  fields are compared with the row index of every tile element and its
+  values selected where they match (paper: ``rst_smem`` + ``extract`` on
+  SIMT cores). Mosaic has no scatter, so the paper's per-word store becomes
+  ``slots`` full-tile selects. Padding slots carry a row no tile has and
+  never match, so the inner loop needs no bound (the paper needs Alg.2's
+  ``nnz_thread``).
 * **Compute-as-Dense**: a full ``(M_TB, K_TB) @ (K_TB, N_TB)`` MXU matmul per
-  grid step, ``preferred_element_type=f32`` — redundant FLOPs tolerated
-  because the op is memory-bound (paper §3.2.2).
+  grid step in B's dtype, ``preferred_element_type=f32`` — redundant FLOPs
+  tolerated because the op is memory-bound (paper §3.2.2); the tile's
+  values are bf16, so casting the expanded tile to a bf16 B loses nothing.
 * **Two-level overlap** (paper §4.2): inter-iteration double buffering is the
   Mosaic grid pipeliner (HBM→VMEM DMA of block *i+1* overlaps the body of
   block *i*); intra-iteration overlap is the DMA engine running async with
-  the VPU scatter and MXU dot by construction.
+  the VPU expansion and MXU dot by construction.
 * **TileOffsets prefetch** (paper Alg.1 lines 5-12): the per-tile ``nnz``
   array rides in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch and
   gates an all-zero-tile fast path (``pl.when(nnz > 0)``) — a beyond-paper
@@ -35,7 +40,7 @@ stack otherwise pays after every projection (DESIGN.md §8):
   instead of write-preact / read-preact / write-act. ``sparse_linear.linear``
   and the model MLPs route through this path for Tiled-CSL weights.
 * **Grouped SpMM** (``lscd_spmm_grouped``) — a grouped Tiled-CSL (G
-  same-shape weights, shared ``max_nnz``; ``tiled_csl.encode_group``) adds a
+  same-shape weights, shared ``slots``; ``tiled_csl.encode_group``) adds a
   fourth, innermost grid dimension. For each (m, n, k) step the G word
   streams are visited back-to-back while the B block index stays fixed, so
   the pipeliner streams B *once* for all G outputs. Binary epilogues
@@ -62,10 +67,12 @@ Grids (DESIGN.md §4, §9):
   sizes) per shape; at N <= 64 the N-tile count is 1 and S > 1 is the only
   way to put more than Mt programs in flight.
 
-Validated in ``interpret=True`` mode against ``ref.spmm_ref`` /
-``ref.spmm_grouped_ref`` (tests sweep shapes × sparsities × dtypes × tile
-geometries × group sizes × epilogues); on-TPU lowering uses the same code
-path with ``interpret=False``.
+Every raw entry takes ``interpret`` as a required keyword: ``True`` runs
+the kernel body in the Pallas interpreter (CPU validation against
+``ref.spmm_ref`` / ``ref.spmm_grouped_ref`` over shapes × sparsities ×
+dtypes × tile geometries × group sizes × epilogues), ``False`` lowers it
+through Mosaic for the TPU (``tests/test_tpu_compile.py`` compiles every
+entry for a described v5e).
 """
 
 from __future__ import annotations
@@ -76,11 +83,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 from repro.analysis import contracts
 from repro.core import tiled_csl
@@ -146,26 +148,55 @@ def epilogue_kind(name: str, *, groups: int = 1) -> str:
     raise ValueError(f"unknown epilogue {name!r}; known: {known}")
 
 
-def _unpack_scatter(words, m_tb: int, k_tb: int) -> jax.Array:
-    """words uint32[max_nnz] → dense f32[m_tb, k_tb] via VPU scatter-add."""
-    val_bits = (words >> 16).astype(jnp.uint16)
-    vals = jax.lax.bitcast_convert_type(val_bits, jnp.bfloat16)
-    locs = (words & 0xFFFF).astype(jnp.int32)
-    rows = locs // k_tb
-    cols = locs - rows * k_tb
-    a_dense = jnp.zeros((m_tb, k_tb), jnp.float32)
-    # Padding words add +0.0 at (0, 0): exact no-op under scatter-ADD.
-    return a_dense.at[rows, cols].add(vals.astype(jnp.float32))
+def _expand_tile(words_ref, m_tb: int, dtype) -> jax.Array:
+    """Column-slotted words ``[slots, k_tb]`` → dense ``[m_tb, k_tb]`` tile.
+
+    Reads the slots in 8-row slabs (one uint32 vreg of sublanes); each slot
+    row broadcasts down the tile and selects its value where its row field
+    equals the element's row. An element takes at most one slot's value
+    (rows are unique within a column), so the selects chain without adds.
+    """
+    slots, k_tb = words_ref.shape
+    q = tiled_csl.SLOT_QUANTUM
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (m_tb, k_tb), 0)
+
+    def slab(i, a):
+        w = words_ref[pl.ds(pl.multiple_of(i * q, q), q), :]
+        rows = (w & 0xFFFF).astype(jnp.int32)
+        # The bf16 value sits in the high half: masking the row field off
+        # leaves exactly that value as an f32 bit pattern.
+        vals = jax.lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+        for j in range(q):
+            a = jnp.where(row_ids == rows[j:j + 1], vals[j:j + 1], a)
+        return a
+
+    a = jax.lax.fori_loop(0, slots // q, slab,
+                          jnp.zeros((m_tb, k_tb), jnp.float32))
+    return a.astype(dtype)
+
+
+def _tile_dot(words_ref, b_ref, m_tb: int) -> jax.Array:
+    """One grid step's contribution: expand the tile, MXU matmul, f32."""
+    a = _expand_tile(words_ref, m_tb, b_ref.dtype)
+    return jnp.dot(a, b_ref[...], preferred_element_type=jnp.float32)
+
+
+def _words_spec(t: tiled_csl.TiledCSL, index_map) -> pl.BlockSpec:
+    """One tile's ``[slots, k_tb]`` word block; leading axes squeezed.
+    The block's two minor dims are the array's own, which Mosaic accepts
+    at any size."""
+    lead = t.words.ndim - 2
+    return pl.BlockSpec((pl.squeezed,) * lead + (t.slots, t.k_tb), index_map)
 
 
 def _lscd_spmm_kernel(nnz_ref,            # SMEM int32[Mt, Kt] (scalar prefetch)
-                      words_ref,          # VMEM uint32[1, 1, max_nnz]
+                      words_ref,          # VMEM uint32[slots, K_TB]
                       b_ref,              # VMEM bf16/f32[K_TB, N_TB]
                       o_ref,              # VMEM out[M_TB, N_TB]
                       acc_ref,            # VMEM scratch f32[M_TB, N_TB]
                       *,
                       m_tb: int,
-                      k_tb: int,
                       k_tiles: int,
                       epilogue: str = "none",
                       bias_ref=None):
@@ -179,11 +210,9 @@ def _lscd_spmm_kernel(nnz_ref,            # SMEM int32[Mt, Kt] (scalar prefetch)
 
     @pl.when(nnz > 0)
     def _body():
-        # ---- sparse -> dense transform (paper Fig.6b; VPU scatter-add) ----
-        a_dense = _unpack_scatter(words_ref[0, 0, :], m_tb, k_tb)
-        # ---- compute-as-dense (MXU) ---------------------------------------
-        acc_ref[...] += jnp.dot(a_dense, b_ref[...].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
+        # sparse -> dense transform (paper Fig.6b; VPU compare-select),
+        # then compute-as-dense (MXU)
+        acc_ref[...] += _tile_dot(words_ref, b_ref, m_tb)
 
     @pl.when(k == k_tiles - 1)
     def _flush():
@@ -206,7 +235,7 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
               *,
               n_tb: int = 128,
               out_dtype=jnp.float32,
-              interpret: bool = True,
+              interpret: bool,
               epilogue: str = "none",
               bias: jax.Array | None = None) -> jax.Array:
     """Raw kernel entry. Requires N % n_tb == 0; see ops.spmm for padding.
@@ -229,12 +258,12 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
     grid = (mt, nt, kt)
     in_specs = [
         # Compressed A tile: the ONLY A traffic (load-as-sparse).
-        pl.BlockSpec((1, 1, t.max_nnz), lambda m_, n_, k_, nnz: (m_, k_, 0)),
+        _words_spec(t, lambda m_, n_, k_, nnz: (m_, k_, 0, 0)),
         # Dense activation tile.
         pl.BlockSpec((t.k_tb, n_tb), lambda m_, n_, k_, nnz: (k_, n_)),
     ]
     args = [t.nnz, t.words, b]
-    body = dict(m_tb=t.m_tb, k_tb=t.k_tb, k_tiles=kt, epilogue=epilogue)
+    body = dict(m_tb=t.m_tb, k_tiles=kt, epilogue=epilogue)
     if bias is None:
         kernel = functools.partial(_lscd_spmm_kernel, bias_ref=None, **body)
     else:
@@ -254,7 +283,7 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
             scratch_shapes=[pltpu.VMEM((t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -262,10 +291,10 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
 
 
 def _lscd_spmm_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref, o_ref,
-                           acc_ref, *, m_tb, k_tb, k_tiles, epilogue):
+                           acc_ref, *, m_tb, k_tiles, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
     _lscd_spmm_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
-                      m_tb=m_tb, k_tb=k_tb, k_tiles=k_tiles,
+                      m_tb=m_tb, k_tiles=k_tiles,
                       epilogue=epilogue, bias_ref=bias_ref)
 
 
@@ -274,14 +303,13 @@ def _lscd_spmm_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref, o_ref,
 # ---------------------------------------------------------------------------
 
 def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
-                              words_ref,  # VMEM uint32[1, 1, 1, max_nnz]
+                              words_ref,  # VMEM uint32[slots, K_TB]
                               b_ref,      # VMEM bf16/f32[K_TB, N_TB]
                               o_ref,      # VMEM out[G, M_TB, N_TB] (unary)
                                           #      or [M_TB, N_TB]   (binary)
                               acc_ref,    # VMEM scratch f32[G, M_TB, N_TB]
                               *,
                               m_tb: int,
-                              k_tb: int,
                               k_tiles: int,
                               groups: int,
                               epilogue: str = "none",
@@ -301,9 +329,7 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
 
     @pl.when(nnz > 0)
     def _body():
-        a_dense = _unpack_scatter(words_ref[0, 0, 0, :], m_tb, k_tb)
-        contrib = jnp.dot(a_dense, b_ref[...].astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
+        contrib = _tile_dot(words_ref, b_ref, m_tb)
         # Static-index stores (unrolled over the small G) — no dynamic VMEM
         # indexing in the inner loop.
         for gi in range(groups):
@@ -334,11 +360,11 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
 
 
 def _lscd_spmm_grouped_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref,
-                                   o_ref, acc_ref, *, m_tb, k_tb, k_tiles,
+                                   o_ref, acc_ref, *, m_tb, k_tiles,
                                    groups, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
     _lscd_spmm_grouped_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
-                              m_tb=m_tb, k_tb=k_tb, k_tiles=k_tiles,
+                              m_tb=m_tb, k_tiles=k_tiles,
                               groups=groups, epilogue=epilogue,
                               bias_ref=bias_ref)
 
@@ -350,13 +376,13 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
                       *,
                       n_tb: int = 128,
                       out_dtype=jnp.float32,
-                      interpret: bool = True,
+                      interpret: bool,
                       epilogue: str = "none",
                       bias: jax.Array | None = None) -> jax.Array:
     """Grouped kernel entry: C[G, M, N] (or C[M, N] for binary epilogues).
 
     ``t`` is a grouped Tiled-CSL (``tiled_csl.encode_group`` /
-    ``group_stack``): G same-shape [M, K] weights sharing one ``max_nnz``.
+    ``group_stack``): G same-shape [M, K] weights sharing one ``slots``.
     The grid gains an innermost group dimension; consecutive group steps
     reuse the resident B block, so B is streamed once for all G outputs and
     the per-(m, n) output block (the full [G, M_TB, N_TB] column for unary
@@ -386,13 +412,11 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
         # Group g's compressed A tile (the only A traffic). The B block
         # index is independent of g, so the pipeliner holds B resident
         # across the G inner steps.
-        pl.BlockSpec((1, 1, 1, t.max_nnz),
-                     lambda m_, n_, k_, g_, nnz: (g_, m_, k_, 0)),
+        _words_spec(t, lambda m_, n_, k_, g_, nnz: (g_, m_, k_, 0, 0)),
         pl.BlockSpec((t.k_tb, n_tb), lambda m_, n_, k_, g_, nnz: (k_, n_)),
     ]
     args = [t.nnz, t.words, b]
-    body = dict(m_tb=t.m_tb, k_tb=t.k_tb, k_tiles=kt, groups=groups,
-                epilogue=epilogue)
+    body = dict(m_tb=t.m_tb, k_tiles=kt, groups=groups, epilogue=epilogue)
     if bias is None:
         kernel = functools.partial(_lscd_spmm_grouped_kernel, bias_ref=None,
                                    **body)
@@ -424,7 +448,7 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
             scratch_shapes=[pltpu.VMEM((groups, t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
         ),
@@ -445,13 +469,12 @@ def _splitk_chunk(kt: int, split_k: int) -> int:
 
 
 def _lscd_spmm_splitk_kernel(nnz_ref,      # SMEM int32[Mt, Kt]
-                             words_ref,    # VMEM uint32[1, 1, max_nnz]
+                             words_ref,    # VMEM uint32[slots, K_TB]
                              b_ref,        # VMEM bf16/f32[K_TB, N_TB]
                              p_ref,        # VMEM f32[1, M_TB, N_TB] partials
                              acc_ref,      # VMEM scratch f32[M_TB, N_TB]
                              *,
                              m_tb: int,
-                             k_tb: int,
                              k_tiles: int,
                              k_chunk: int):
     m, kl = pl.program_id(1), pl.program_id(3)
@@ -468,9 +491,7 @@ def _lscd_spmm_splitk_kernel(nnz_ref,      # SMEM int32[Mt, Kt]
 
     @pl.when(nnz > 0)
     def _body():
-        a_dense = _unpack_scatter(words_ref[0, 0, :], m_tb, k_tb)
-        acc_ref[...] += jnp.dot(a_dense, b_ref[...].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += _tile_dot(words_ref, b_ref, m_tb)
 
     @pl.when(kl == k_chunk - 1)
     def _flush_partial():
@@ -500,7 +521,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
                      n_tb: int = 128,
                      split_k: int = 2,
                      out_dtype=jnp.float32,
-                     interpret: bool = True,
+                     interpret: bool,
                      epilogue: str = "none",
                      bias: jax.Array | None = None) -> jax.Array:
     """Split-K kernel entry: grid ``(S, Mt, Nt, ceil(Kt/S))`` + a reduce.
@@ -529,8 +550,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
     k_chunk = _splitk_chunk(kt, split_k)
 
     kernel = functools.partial(
-        _lscd_spmm_splitk_kernel, m_tb=t.m_tb, k_tb=t.k_tb, k_tiles=kt,
-        k_chunk=k_chunk)
+        _lscd_spmm_splitk_kernel, m_tb=t.m_tb, k_tiles=kt, k_chunk=k_chunk)
     k_ix = lambda s_, kl_: jnp.minimum(s_ * k_chunk + kl_, kt - 1)
     partials = pl.pallas_call(
         kernel,
@@ -538,9 +558,8 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
             num_scalar_prefetch=1,
             grid=(split_k, mt, nt, k_chunk),
             in_specs=[
-                pl.BlockSpec((1, 1, t.max_nnz),
-                             lambda s_, m_, n_, kl_, nnz: (m_, k_ix(s_, kl_),
-                                                           0)),
+                _words_spec(t, lambda s_, m_, n_, kl_, nnz:
+                            (m_, k_ix(s_, kl_), 0, 0)),
                 pl.BlockSpec((t.k_tb, n_tb),
                              lambda s_, m_, n_, kl_, nnz: (k_ix(s_, kl_),
                                                            n_)),
@@ -550,7 +569,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
             scratch_shapes=[pltpu.VMEM((t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -573,7 +592,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((t.m_tb, n_tb), lambda m_, n_: (m_, n_)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -581,13 +600,12 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
 
 
 def _lscd_spmm_splitk_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
-                                     words_ref,  # VMEM uint32[1,1,1,max_nnz]
+                                     words_ref,  # VMEM uint32[slots, K_TB]
                                      b_ref,      # VMEM bf16/f32[K_TB, N_TB]
                                      p_ref,      # VMEM f32[1, G, M_TB, N_TB]
                                      acc_ref,    # scratch f32[G, M_TB, N_TB]
                                      *,
                                      m_tb: int,
-                                     k_tb: int,
                                      k_tiles: int,
                                      k_chunk: int,
                                      groups: int):
@@ -604,9 +622,7 @@ def _lscd_spmm_splitk_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
 
     @pl.when(nnz > 0)
     def _body():
-        a_dense = _unpack_scatter(words_ref[0, 0, 0, :], m_tb, k_tb)
-        contrib = jnp.dot(a_dense, b_ref[...].astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
+        contrib = _tile_dot(words_ref, b_ref, m_tb)
         for gi in range(groups):
             @pl.when(g == gi)
             def _store(gi=gi):
@@ -644,7 +660,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
                              n_tb: int = 128,
                              split_k: int = 2,
                              out_dtype=jnp.float32,
-                             interpret: bool = True,
+                             interpret: bool,
                              epilogue: str = "none",
                              bias: jax.Array | None = None) -> jax.Array:
     """Grouped split-K entry: grid ``(S, Mt, Nt, ceil(Kt/S), G)`` + reduce.
@@ -672,8 +688,8 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
     k_chunk = _splitk_chunk(kt, split_k)
 
     kernel = functools.partial(
-        _lscd_spmm_splitk_grouped_kernel, m_tb=t.m_tb, k_tb=t.k_tb,
-        k_tiles=kt, k_chunk=k_chunk, groups=groups)
+        _lscd_spmm_splitk_grouped_kernel, m_tb=t.m_tb, k_tiles=kt,
+        k_chunk=k_chunk, groups=groups)
     k_ix = lambda s_, kl_: jnp.minimum(s_ * k_chunk + kl_, kt - 1)
     partials = pl.pallas_call(
         kernel,
@@ -681,9 +697,8 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
             num_scalar_prefetch=1,
             grid=(split_k, mt, nt, k_chunk, groups),
             in_specs=[
-                pl.BlockSpec((1, 1, 1, t.max_nnz),
-                             lambda s_, m_, n_, kl_, g_, nnz:
-                             (g_, m_, k_ix(s_, kl_), 0)),
+                _words_spec(t, lambda s_, m_, n_, kl_, g_, nnz:
+                            (g_, m_, k_ix(s_, kl_), 0, 0)),
                 pl.BlockSpec((t.k_tb, n_tb),
                              lambda s_, m_, n_, kl_, g_, nnz:
                              (k_ix(s_, kl_), n_)),
@@ -694,7 +709,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
             scratch_shapes=[pltpu.VMEM((groups, t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((split_k, groups, m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary"),
         ),
@@ -726,7 +741,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

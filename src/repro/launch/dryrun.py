@@ -68,7 +68,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     }
     t0 = time.time()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             cell = specs_mod.build_cell(
                 cfg, shape, mesh, weight_mode=weight_mode,
                 sparsity=sparsity, remat=remat, microbatches=microbatches)

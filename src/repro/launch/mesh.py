@@ -8,6 +8,7 @@ device query.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,11 +20,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int | None = None, model: int = 2):
     """Small mesh over the actual local devices (tests / examples)."""
     n = n_devices or len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: the sharding rules and
+    ``sharding.constrain`` hand placement to GSPMD, which the default
+    Explicit axes of current JAX refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -43,6 +43,7 @@ import numpy as np
 from repro import configs
 from repro.core import pruning, tiled_csl
 from repro.distributed import fault_tolerance as ft
+from repro.launch import compile_cache
 from repro.models import transformer, nn
 from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
@@ -66,6 +67,17 @@ examples:
   python -m repro.launch.serve --arch tinyllama_1_1b --smoke --sparsity 0.8 \\
       --backend interpret --profile-kernels
 """
+
+
+_SPARSE_PROJECTIONS = ("'wq'", "'wk'", "'wv'", "'wo'", "'gate'", "'up'",
+                       "'down'")
+
+
+def should_sparsify(path: str) -> bool:
+    """Weights ``--sparsity`` prunes: the attention and FFN projection
+    matrices. Their biases ([L, out] leaves) stay dense."""
+    return path.endswith("['w']") and any(k in path
+                                          for k in _SPARSE_PROJECTIONS)
 
 
 def main() -> None:
@@ -163,6 +175,7 @@ def main() -> None:
                          "fenced after the run drains, and print the "
                          "predicted-vs-measured roofline drift table")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.trace_out:
         obs_trace.get_tracer().enable()
     profiler = obs_profile.KernelProfiler() if args.profile_kernels else None
@@ -180,9 +193,7 @@ def main() -> None:
         t0 = time.time()
         params = pruning.sparsify_params(
             params, args.sparsity,
-            should_sparsify=lambda n: any(
-                k in n for k in ("'wq'", "'wk'", "'wv'", "'wo'", "'gate'",
-                                 "'up'", "'down'")),
+            should_sparsify=should_sparsify,
             balanced=args.balanced)
         params = pruning.group_projections(params)
         csl = [l for l in jax.tree.leaves(

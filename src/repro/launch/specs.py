@@ -8,8 +8,8 @@ for lower/compile.
 Weight modes (DESIGN.md §4, dry-run accounting note):
   dense      — baseline; f32 for train, bf16 for serving.
   sparse_xla — Tiled-CSL params with the XLA decompress-then-matmul path.
-               The TiledCSL ShapeDtypeStructs use an analytic max_nnz:
-               ceil(tile_elems·(1-s)·IMBALANCE / PAD_QUANTUM)·PAD_QUANTUM.
+               The TiledCSL ShapeDtypeStructs use the analytic slot count
+               of ``roofline.analytic_max_nnz``.
 """
 
 from __future__ import annotations
@@ -22,18 +22,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import tiled_csl
+from repro.core import roofline, tiled_csl
 from repro.distributed import sharding
 from repro.models import transformer
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.serving import engine
 from repro.training import optimizer as opt_mod
 from repro.training import train_loop
-
-# Measured typical per-tile nnz imbalance of random unstructured sparsity
-# (max tile nnz / mean) at 128x128 tiles; tile-balanced pruning makes it 1.0.
-IMBALANCE = 1.15
-
 
 def _struct(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(int(x) for x in shape), dtype)
@@ -55,11 +50,12 @@ def _csl_struct(out_dim: int, in_dim: int, sparsity: float,
     mp = -(-out_dim // m_tb) * m_tb
     kp = -(-in_dim // k_tb) * k_tb
     mt, kt = mp // m_tb, kp // k_tb
-    nnz = m_tb * k_tb * (1.0 - sparsity) * IMBALANCE
-    max_nnz = int(-(-int(np.ceil(nnz)) // tiled_csl.PAD_QUANTUM)
-                  * tiled_csl.PAD_QUANTUM)
+    # one slot count for the whole leaf: every layer / expert of the stack
+    n_mats = int(np.prod(lead, dtype=np.int64))
+    slots = roofline.analytic_max_nnz(
+        m_tb, k_tb, sparsity, columns=n_mats * mt * kp) // k_tb
     return tiled_csl.TiledCSL(
-        words=_struct(lead + (mt, kt, max_nnz), jnp.uint32),
+        words=_struct(lead + (mt, kt, slots, k_tb), jnp.uint32),
         nnz=_struct(lead + (mt, kt), jnp.int32),
         shape=(mp, kp), m_tb=m_tb, k_tb=k_tb, dtype=jnp.bfloat16)
 

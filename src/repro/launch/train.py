@@ -23,9 +23,25 @@ from repro import configs
 from repro.core import pruning
 from repro.distributed import fault_tolerance as ft
 from repro.distributed import sharding
+from repro.launch import compile_cache
+from repro.launch import mesh as mesh_mod
 from repro.training import data as data_mod
 from repro.training import optimizer as opt_mod
 from repro.training import train_loop
+
+
+def sharded_step(step_fn, state: train_loop.TrainState, mesh):
+    """The --mesh train step, jitted with the state sharded over ``mesh``
+    (params by the DESIGN.md §5 rules, AdamW moments like their params,
+    step counters replicated) and donated; call it inside
+    ``jax.set_mesh(mesh)``."""
+    p_sh = sharding.params_shardings(state.params, mesh)
+    o_sh = opt_mod.AdamWState(
+        step=sharding.replicated(mesh),
+        mu=jax.tree.map(lambda _, s: s, state.opt_state.mu, p_sh),
+        nu=jax.tree.map(lambda _, s: s, state.opt_state.nu, p_sh))
+    s_sh = train_loop.TrainState(p_sh, o_sh, sharding.replicated(mesh))
+    return jax.jit(step_fn, in_shardings=(s_sh, None), donate_argnums=(0,))
 
 
 def main() -> None:
@@ -48,6 +64,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     opt = opt_mod.AdamW(lr=opt_mod.cosine_schedule(
@@ -85,16 +102,9 @@ def main() -> None:
                                          microbatches=args.microbatches)
     if args.mesh:
         d, m = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
-        p_sh = sharding.params_shardings(state.params, mesh)
-        o_sh = opt_mod.AdamWState(
-            step=sharding.replicated(mesh),
-            mu=jax.tree.map(lambda _, s: s, state.opt_state.mu, p_sh),
-            nu=jax.tree.map(lambda _, s: s, state.opt_state.nu, p_sh))
-        s_sh = train_loop.TrainState(p_sh, o_sh, sharding.replicated(mesh))
-        ctx = mesh
-        step_fn = jax.jit(step_fn, in_shardings=(
-            s_sh, None), donate_argnums=(0,))
+        mesh = mesh_mod.make_mesh((d, m), ("data", "model"))
+        ctx = jax.set_mesh(mesh)
+        step_fn = sharded_step(step_fn, state, mesh)
     else:
         import contextlib
         ctx = contextlib.nullcontext()

@@ -46,7 +46,7 @@ def _expert_ffn(params, xe: jax.Array) -> jax.Array:
     """xe: [E, C*, d] -> [E, C*, d] — batched per-expert SwiGLU.
 
     Expert weights may be stacked dense arrays [E, f, d] or stacked
-    TiledCSL (words [E, mt, kt, w]); the latter uses a vmapped XLA
+    TiledCSL (words [E, mt, kt, slots, k_tb]); the latter uses a vmapped XLA
     reference decode (kernel path is per-expert at serving time).
     """
     def one(w_stack, x, out_dim):

@@ -97,12 +97,17 @@ class KernelProfiler:
         import jax.numpy as jnp
         import numpy as np
 
-        from repro.core import tiled_csl
+        from repro.core import roofline, tiled_csl
         from repro.kernels import ops  # late import: ops imports obs.profile
 
         rows: List[Dict[str, Any]] = []
         for key in sorted(self.launches):
             rec = self.launches[key]
+            # The prediction uses the analytic chip's peaks, the only entry
+            # of roofline.DEVICE_PEAKS: a device measurement on any other
+            # chip raises before anything runs instead of borrowing them.
+            if rec.backend == "pallas":
+                roofline.peaks_for(jax.devices()[0].device_kind)
             rng = np.random.default_rng(seed)
 
             def _sparse(r):

@@ -16,7 +16,7 @@ import pytest
 
 from repro.analysis import budgets, contracts, findings, lint, trace_audit
 from repro.analysis.contracts import ScheduleContractError
-from repro.core import tiled_csl
+from repro.core import roofline, tiled_csl
 from repro.kernels import ops, schedule
 from repro.kernels import spmm as spmm_mod
 
@@ -33,22 +33,36 @@ def _rules(fs, *, suppressed=False):
 # ---------------------------------------------------------------------------
 
 def test_loc_predicate_shared_with_encode():
-    assert contracts.tile_loc_ok(128, 128)
-    assert not contracts.tile_loc_ok(256, 512)
-    with pytest.raises(ValueError, match="16-bit loc"):
-        contracts.require_tile_loc(256, 512)
-    # encode routes through the SAME predicate (satellite: the ad-hoc
-    # guard is gone) — same message, same bound
-    with pytest.raises(ValueError, match="16-bit loc"):
-        tiled_csl.encode(np.zeros((256, 512), np.float32), 256, 512)
+    assert contracts.tile_loc_ok(128)
+    assert contracts.tile_loc_ok(0xFFFE)
+    assert not contracts.tile_loc_ok(0xFFFF)      # the padding row marker
+    with pytest.raises(ValueError, match="16-bit row"):
+        contracts.require_tile_loc(0xFFFF)
+    # encode routes through the SAME predicate — same message, same bound
+    with pytest.raises(ValueError, match="16-bit row"):
+        tiled_csl.encode(np.zeros((0xFFFF, 1), np.float32), 0xFFFF, 1)
     assert _rules(contracts.check_schedule(
-        256, 512, 8, m_tb=256, k_tb=512, n_tb=8, split_k=1)) == ["KC-LOC"]
+        0xFFFF, 128, 8, m_tb=0xFFFF, k_tb=128, n_tb=8, split_k=1)) == ["KC-LOC"]
 
 
 def test_indivisible_grid_flagged():
     got = contracts.check_schedule(100, 256, 8, m_tb=128, k_tb=128,
                                    n_tb=8, split_k=1)
     assert _rules(got) == ["KC-GRID"]
+
+
+@pytest.mark.parametrize("n,n_tb,backend,ok", [
+    (24, 8, "pallas", False),      # three 8-wide N blocks: Mosaic refuses
+    (24, 8, "interpret", True),    # the interpreter tiles anything
+    (24, 32, "pallas", True),      # one N tile covering the padded N
+    (8, 8, "pallas", True),        # decode: one exact N tile
+    (512, 128, "pallas", True),    # full-lane blocks
+    (512, 64, "pallas", False),
+])
+def test_ntb_lane_rule_on_pallas(n, n_tb, backend, ok):
+    got = contracts.check_schedule(128, 256, n, m_tb=128, k_tb=128,
+                                   n_tb=n_tb, split_k=1, backend=backend)
+    assert _rules(got) == ([] if ok else ["KC-NTB"])
 
 
 def test_split_bounds_flagged():
@@ -76,8 +90,10 @@ def test_vmem_overflow_flagged_with_breakdown():
                                    sparsity=0.8)
     assert _rules(got) == ["KC-VMEM"]
     assert "reduce kernel" in got[0].message
-    bd = contracts.schedule_vmem_breakdown(128, 128, 128, 64, group=2,
-                                           sparsity=0.8)
+    bd = contracts.schedule_vmem_breakdown(
+        128, 128, 128, 64, group=2,
+        max_nnz=roofline.analytic_max_nnz(128, 128, 0.8,
+                                          columns=2 * 64 * 64 * 128))
     assert bd.reduce_bytes > budgets.vmem_budget("pallas")
     assert bd.total_bytes == max(bd.main_bytes, bd.reduce_bytes)
     # the xla reference path has no VMEM contract

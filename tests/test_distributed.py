@@ -15,6 +15,7 @@ import pytest
 
 from repro import configs
 from repro.distributed import sharding
+from repro.launch import mesh as mesh_mod
 from repro.launch import specs as specs_mod
 
 
@@ -23,7 +24,7 @@ from repro.launch import specs as specs_mod
 # ---------------------------------------------------------------------------
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return mesh_mod.make_mesh((1, 1), ("data", "model"))
 
 
 def test_params_shardings_cover_every_leaf():
@@ -57,16 +58,16 @@ def test_rule_for_expected_specs():
     assert sharding.rule_for("['embed']['table']", 2) == P("model", None)
     # norms replicated
     assert sharding.rule_for("['final_norm']['scale']", 1) == P()
-    # Tiled-CSL words of a column-parallel weight
-    assert sharding.rule_for("['layers']['mlp']['up']['w'].words", 4) == \
-        P(None, "model", None, None)
+    # Tiled-CSL words [L, mt, kt, slots, k_tb] of a column-parallel weight
+    assert sharding.rule_for("['layers']['mlp']['up']['w'].words", 5) == \
+        P(None, "model", None, None, None)
     # fsdp adds data on the free dim
     assert sharding.rule_for("['layers']['attn']['wq']['w']", 3,
                              fsdp=True) == P(None, "model", "data")
 
 
 def test_fit_spec_drops_nondivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh11()
     # degenerate 1x1 mesh: everything divides
     P = jax.sharding.PartitionSpec
     assert sharding.fit_spec(P("model", None), (7, 3), mesh) == \
@@ -114,6 +115,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from repro import configs
         from repro.distributed import sharding
+        from repro.launch import mesh as mesh_mod
         from repro.training import optimizer as opt_mod, train_loop, data as data_mod
         from repro.models import transformer
 
@@ -128,8 +130,8 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(state, batch)
 
         # 4x2 mesh sharded
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with mesh:
+        mesh = mesh_mod.make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             p_sh = sharding.params_shardings(state.params, mesh)
             o_sh = opt_mod.AdamWState(
                 step=sharding.replicated(mesh),
@@ -161,16 +163,13 @@ def test_compressed_psum_bounds():
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
         from repro.distributed import compression
+        from repro.launch import mesh as mesh_mod
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = mesh_mod.make_mesh((8,), ("data",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)),
                         jnp.float32)
 
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
-
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("data", None), out_specs=P("data", None))
         def f(xs):
             return compression.compressed_psum(xs[0], "data")[None]
@@ -194,14 +193,15 @@ def test_dryrun_machinery_small_mesh():
         import jax
         from repro import configs
         from repro.core import roofline
+        from repro.launch import mesh as mesh_mod
         from repro.launch import specs as specs_mod
         from repro.models.config import ShapeConfig
 
         cfg = dataclasses.replace(configs.smoke("qwen2_moe_a2_7b"),
                                   moe_subgroup=32)
         shape = ShapeConfig("train_tiny", "train", 32, 8)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with mesh:
+        mesh = mesh_mod.make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             cell = specs_mod.build_cell(cfg, shape, mesh)
             lowered = jax.jit(cell.fn,
                               in_shardings=cell.in_shardings).lower(*cell.args)
@@ -223,13 +223,14 @@ def test_decode_cell_small_mesh():
         import jax
         from repro import configs
         from repro.core import roofline
+        from repro.launch import mesh as mesh_mod
         from repro.launch import specs as specs_mod
         from repro.models.config import ShapeConfig
 
         cfg = configs.smoke("tinyllama_1_1b")
         shape = ShapeConfig("decode_tiny", "decode", 64, 8)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with mesh:
+        mesh = mesh_mod.make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             cell = specs_mod.build_cell(cfg, shape, mesh)
             compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings) \\
                 .lower(*cell.args).compile()

@@ -350,6 +350,17 @@ def _small_csl(seed=0, m=128, k=256, sparsity=0.8):
     return tiled_csl.encode(dense)
 
 
+def test_profiler_refuses_device_without_published_peaks():
+    """A measured (pallas) launch is compared with the measuring device's
+    own peaks; a device missing from roofline.DEVICE_PEAKS (here the CPU)
+    raises before anything runs."""
+    prof = obs_profile.KernelProfiler()
+    prof.note_dispatch("spmm", 128, 256, 8, 0.8, 1, 1024, 128, 128,
+                       "pallas", schedule.Schedule(128, 128, 8, 1))
+    with pytest.raises(ValueError, match="no published peaks"):
+        prof.measure(reps=1)
+
+
 def test_profiler_records_and_measures():
     t = _small_csl()
     b = jnp.ones((256, 8), jnp.float32)

@@ -1,5 +1,6 @@
 """Roofline model + HLO collective parser unit tests."""
 
+import numpy as np
 import pytest
 
 from repro.core import roofline
@@ -135,12 +136,29 @@ def test_splitk_terms_restream_accounting():
 def test_splitk_terms_validation_and_max_nnz():
     with pytest.raises(ValueError, match="split_k"):
         roofline.lscd_splitk_terms(128, 128, 8, 0.8, split_k=0)
-    # analytic per-tile stream bound: PAD_QUANTUM-aligned, at least one
-    # quantum, and monotone in density
-    q = roofline.analytic_max_nnz(128, 128, 0.8)
-    assert q % 128 == 0 and q >= 128
-    assert roofline.analytic_max_nnz(128, 128, 0.5) > q
-    assert roofline.analytic_max_nnz(128, 128, 1.0) == 128
+    # analytic per-tile stream bound: whole 8-slot quanta of k_tb-word
+    # slots, at least one quantum, at most the dense tile, and monotone in
+    # density
+    cols = 12 * 8960                      # one 1536x8960 matrix
+    q = roofline.analytic_max_nnz(128, 128, 0.8, columns=cols)
+    assert q % (8 * 128) == 0 and q >= 8 * 128
+    assert roofline.analytic_max_nnz(128, 128, 0.5, columns=cols) > q
+    assert roofline.analytic_max_nnz(128, 128, 1.0, columns=cols) == 8 * 128
+    assert roofline.analytic_max_nnz(128, 128, 0.0, columns=cols) == 128 * 128
+
+
+@pytest.mark.parametrize("layers,encoded_slots", [(1, 48), (28, 56)])
+def test_analytic_slots_match_encoded_column_maximum(layers, encoded_slots):
+    """The slot model takes the fullest column over every column sharing
+    one encoding: a whole 28-layer scan stack pads further than a single
+    matrix. ``encoded_slots`` is what encoding an 80%-sparse random mask
+    gives (the column maximum simulated here, rounded to 8)."""
+    cols = layers * 12 * 8960             # 1536x8960 on 128x128 tiles
+    rng = np.random.default_rng(0)
+    top = int(rng.binomial(128, 0.2, size=cols).max())
+    assert -(-top // 8) * 8 == encoded_slots
+    assert roofline.analytic_max_nnz(
+        128, 128, 0.8, columns=cols) == encoded_slots * 128
 
 
 def test_grouped_unary_terms_and_validation():
@@ -156,3 +174,15 @@ def test_grouped_unary_terms_and_validation():
     with pytest.raises(ValueError, match="group=2"):
         roofline.lscd_grouped_terms(m, k, 8, 0.8, group=3,
                                     epilogue="silu_mul")
+
+
+def test_device_peaks_keyed_by_device_kind():
+    """One table keyed by jax device_kind; the analytic constants are the
+    v5e entry; an unknown kind is an error, never a default."""
+    v5e = roofline.peaks_for("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16e9)
+    assert roofline.PEAK_FLOPS_BF16 == v5e.bf16_flops
+    assert roofline.HBM_BW == v5e.hbm_bw
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks_for("cpu")

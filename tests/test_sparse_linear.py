@@ -283,7 +283,7 @@ def test_group_projections_scan_stacked_forward_parity():
         gp, is_leaf=lambda x: isinstance(x, tiled_csl.TiledCSL))[0]
     grouped = [l for p, l in leaves
                if "gate_up" in jax.tree_util.keystr(p)]
-    assert len(grouped) == 1 and grouped[0].words.ndim == 5
+    assert len(grouped) == 1 and grouped[0].words.ndim == 6
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
     lg, _, _ = transformer.forward(gp, {"tokens": tokens}, cfg, mode="train")
     ls, _, _ = transformer.forward(sp, {"tokens": tokens}, cfg, mode="train")

@@ -1,4 +1,5 @@
-"""Tiled-CSL format: roundtrip, reorder invariants, padding accounting.
+"""Tiled-CSL format: roundtrip, column-slotted layout invariants, padding
+accounting.
 
 Deterministic property sweeps (seeded grids over the same space the old
 hypothesis strategies drew from) + targeted unit tests.
@@ -7,6 +8,7 @@ hypothesis strategies drew from) + targeted unit tests.
 import numpy as np
 import pytest
 
+from repro.analysis import contracts
 from repro.core import tiled_csl
 
 
@@ -22,11 +24,11 @@ def _random_sparse(rng, m, k, sparsity):
 
 @pytest.mark.parametrize("m,k", [(128, 128), (256, 384), (512, 128)])
 @pytest.mark.parametrize("sparsity", [0.0, 0.5, 0.8, 0.99])
-@pytest.mark.parametrize("reorder", ["interleave", "none", "greedy"])
-def test_roundtrip(m, k, sparsity, reorder):
+@pytest.mark.parametrize("m_tb,k_tb", [(128, 128), (64, 128), (128, 64)])
+def test_roundtrip(m, k, sparsity, m_tb, k_tb):
     rng = np.random.default_rng(42)
     a = _random_sparse(rng, m, k, sparsity)
-    t = tiled_csl.encode(a, reorder=reorder)
+    t = tiled_csl.encode(a, m_tb=m_tb, k_tb=k_tb)
     dec = tiled_csl.decode(t)
     # bf16 value rounding only; zero/nonzero pattern must be exact
     assert ((dec != 0) == (a != 0)).all() or sparsity == 0.0
@@ -44,56 +46,93 @@ def test_decode_jax_matches_numpy():
                                tiled_csl.decode(t), atol=1e-6)
 
 
-def test_reorder_improves_conflict_score():
-    rng = np.random.default_rng(1)
-    a = _random_sparse(rng, 128, 128, 0.8)
-    t_i = tiled_csl.encode(a, reorder="interleave")
-    t_n = tiled_csl.encode(a, reorder="none")
-    t_g = tiled_csl.encode(a, reorder="greedy")
-    nz = int(np.asarray(t_i.nnz)[0, 0])
-    s_i = tiled_csl.sublane_conflict_score(np.asarray(t_i.words)[0, 0], nz, 128)
-    s_n = tiled_csl.sublane_conflict_score(np.asarray(t_n.words)[0, 0], nz, 128)
-    s_g = tiled_csl.sublane_conflict_score(np.asarray(t_g.words)[0, 0], nz, 128)
-    assert s_i > s_n * 2          # interleave is much better than row-major
-    assert s_g > s_n * 2          # Alg.3 greedy too
-    assert s_i > 7.0              # near conflict-free at this density
+def test_column_slots_exact_placement():
+    """Lane c of a tile lists column c's non-zeros top to bottom; unused
+    slots hold PAD_WORD."""
+    a = np.zeros((128, 128), np.float32)
+    a[3, 5], a[7, 5], a[0, 9] = 1.0, -2.0, 0.5
+    t = tiled_csl.encode(a)
+    w = np.asarray(t.words)[0, 0]
+    assert t.slots == tiled_csl.SLOT_QUANTUM and w.shape == (8, 128)
+    vals, rows = tiled_csl.unpack_words(w)
+    assert (rows[:2, 5] == [3, 7]).all() and (vals[:2, 5] == [1.0, -2.0]).all()
+    assert rows[0, 9] == 0 and vals[0, 9] == 0.5
+    untouched = np.ones(w.shape, bool)
+    untouched[:2, 5] = untouched[0, 9] = False
+    assert (w[untouched] == tiled_csl.PAD_WORD).all()
+    assert int(np.asarray(t.nnz)[0, 0]) == 3
 
 
-def test_reorder_preserves_nonzero_set():
-    """The AOT reorder is a permutation *within* each tile (paper §4.3.3:
-    changes global-memory placement only)."""
+def _select_expand(words, m_tb):
+    """The kernel's transform in numpy: one compare-select per slot."""
+    vals, rows = tiled_csl.unpack_words(words)
+    a = np.zeros((m_tb, words.shape[-1]), np.float32)
+    row_ids = np.arange(m_tb)[:, None]
+    for r in range(words.shape[0]):
+        a = np.where(row_ids == rows[r][None, :], vals[r][None, :], a)
+    return a
+
+
+@pytest.mark.parametrize("sparsity", [0.0, 0.3, 0.6, 0.8, 0.95])
+def test_select_expansion_rebuilds_tiles(sparsity):
+    """Selecting each slot where its row matches rebuilds every tile: rows
+    are unique within a column and PAD_ROW matches no row."""
     rng = np.random.default_rng(2)
-    a = _random_sparse(rng, 256, 256, 0.7)
-    for reorder in ("interleave", "greedy"):
-        t = tiled_csl.encode(a, reorder=reorder)
-        np.testing.assert_allclose(
-            tiled_csl.decode(t), tiled_csl.decode(tiled_csl.encode(a, reorder="none")),
-            atol=0.0)
+    a = _random_sparse(rng, 256, 256, sparsity)
+    t = tiled_csl.encode(a)
+    dec = tiled_csl.decode(t)
+    words = np.asarray(t.words)
+    for mi in range(2):
+        for ki in range(2):
+            np.testing.assert_array_equal(
+                _select_expand(words[mi, ki], 128),
+                dec[mi * 128:(mi + 1) * 128, ki * 128:(ki + 1) * 128])
 
 
 def test_pack_unpack_inverse():
     rng = np.random.default_rng(3)
     vals = rng.standard_normal(1000).astype(np.float32)
-    locs = rng.integers(0, 2 ** 14, 1000)
-    w = tiled_csl.pack_words(vals, locs)
-    v2, l2 = tiled_csl.unpack_words(w)
-    assert (l2 == locs).all()
+    rows = rng.integers(0, 2 ** 14, 1000)
+    w = tiled_csl.pack_words(vals, rows)
+    v2, r2 = tiled_csl.unpack_words(w)
+    assert (r2 == rows).all()
     rel = np.abs(v2 - vals) / (np.abs(vals) + 1e-12)
     assert rel.max() < 0.008      # bf16 mantissa
 
 def test_padding_word_is_exact_noop():
-    """Padding words are (val=+0.0, loc=0): scatter-add contributes nothing."""
-    w = np.zeros(4, np.uint32)
-    vals, locs = tiled_csl.unpack_words(w)
-    assert (vals == 0.0).all() and (locs == 0).all()
+    """PAD_WORD is (+0.0 | PAD_ROW): a row no legal tile has, so the
+    expansion never selects it, and a zero value should anything add it."""
+    vals, rows = tiled_csl.unpack_words(np.full(4, tiled_csl.PAD_WORD,
+                                                np.uint32))
+    assert (vals == 0.0).all() and (rows == tiled_csl.PAD_ROW).all()
+    assert not contracts.tile_loc_ok(tiled_csl.PAD_ROW)
 
 
 def test_pad_overhead_bounded():
+    """Slots follow the fullest tile column, so at 80% sparsity on
+    128-row tiles about half the words are padding (~48 slots for a mean
+    of ~26 non-zeros per column) — the layout still streams fewer bytes
+    than dense bf16."""
     rng = np.random.default_rng(4)
     a = _random_sparse(rng, 1024, 1024, 0.8)
     t = tiled_csl.encode(a)
-    assert t.pad_overhead < 0.10   # PAD_QUANTUM=128 keeps waste small
-    assert t.nbytes_sparse < 0.55 * t.nbytes_dense
+    assert t.pad_overhead < 0.5
+    assert t.nbytes_sparse < 0.8 * t.nbytes_dense
+    assert t.bytes_per_nonzero == pytest.approx(
+        t.nbytes_sparse / t.n_nonzero)
+    assert t.bytes_per_nonzero < 2.0 / 0.2      # dense bf16 per non-zero
+
+
+@pytest.mark.parametrize("extra", [0, 8, 24])
+def test_pad_slots_keeps_matrix(extra):
+    rng = np.random.default_rng(5)
+    t = tiled_csl.encode(_random_sparse(rng, 256, 128, 0.7))
+    tp = tiled_csl.pad_slots(t, t.slots + extra)
+    assert tp.slots == t.slots + extra
+    assert (np.asarray(tp.words)[:, :, t.slots:] == tiled_csl.PAD_WORD).all()
+    np.testing.assert_array_equal(tiled_csl.decode(tp), tiled_csl.decode(t))
+    with pytest.raises(ValueError):
+        tiled_csl.pad_slots(tp, t.slots - 8)
 
 
 def test_misaligned_shape_raises():
@@ -138,51 +177,60 @@ def test_roundtrip_property(mt, kt, sparsity, seed, m_tb):
         assert rel < 0.01
     # derived stats are consistent
     assert t.n_nonzero == int((a != 0).sum())
-    assert t.words.shape[-1] % tiled_csl.PAD_QUANTUM == 0
+    assert t.slots % tiled_csl.SLOT_QUANTUM == 0
     assert int(np.asarray(t.nnz).max()) <= t.max_nnz
 
 
-@pytest.mark.parametrize("seed,sparsity", [
-    (31, 0.3), (32, 0.35), (33, 0.4), (34, 0.45), (35, 0.5),
-    (36, 0.55), (37, 0.6), (38, 0.65), (39, 0.7), (40, 0.75),
-    (41, 0.8), (42, 0.85), (43, 0.9), (44, 0.93), (45, 0.95),
+@pytest.mark.parametrize("seed,sparsity,m_tb", [
+    (31, 0.3, 128), (32, 0.35, 64), (33, 0.4, 128), (34, 0.45, 64),
+    (35, 0.5, 128), (36, 0.55, 64), (37, 0.6, 128), (38, 0.65, 64),
+    (39, 0.7, 128), (40, 0.75, 64), (41, 0.8, 128), (42, 0.85, 64),
+    (43, 0.9, 128), (44, 0.93, 64), (45, 0.95, 128),
 ])
-def test_conflict_score_property(seed, sparsity):
-    """Interleave reorder never does worse than row-major order."""
+def test_slot_layout_property(seed, sparsity, m_tb):
+    """Every lane lists its tile column's non-zeros in ascending row order,
+    packed to the front; the slot count is the fullest column's, rounded
+    up to SLOT_QUANTUM; ``nnz`` counts the real words per tile."""
     rng = np.random.default_rng(seed)
-    a = _random_sparse(rng, 128, 128, sparsity)
-    if (a != 0).sum() < 16:
-        return
-    t_i = tiled_csl.encode(a, reorder="interleave")
-    t_n = tiled_csl.encode(a, reorder="none")
-    nz = int(np.asarray(t_i.nnz)[0, 0])
-    s_i = tiled_csl.sublane_conflict_score(np.asarray(t_i.words)[0, 0], nz, 128)
-    s_n = tiled_csl.sublane_conflict_score(np.asarray(t_n.words)[0, 0], nz, 128)
-    assert s_i >= s_n - 1e-9
+    a = _random_sparse(rng, 2 * m_tb, 256, sparsity)
+    t = tiled_csl.encode(a, m_tb=m_tb)
+    vals, rows = tiled_csl.unpack_words(np.asarray(t.words))
+    real = rows != tiled_csl.PAD_ROW
+    col_counts = real.sum(axis=2)                        # [mt, kt, k_tb]
+    assert t.slots == max(-(-int(col_counts.max()) // 8) * 8, 8)
+    # packed to the front: slot r is real iff r < the column's count
+    assert (real == (np.arange(t.slots)[None, None, :, None]
+                     < col_counts[:, :, None, :])).all()
+    assert (rows[real] < m_tb).all()
+    r = np.where(real, rows, np.iinfo(np.int32).max)
+    assert (np.diff(r, axis=2)[real[:, :, 1:]] > 0).all()
+    np.testing.assert_array_equal(np.asarray(t.nnz), col_counts.sum(-1))
+    assert (vals[~real] == 0.0).all()
 
 
 # ---------------------------------------------------------------------------
-# 16-bit location field overflow guard
+# 16-bit row field overflow guard
 # ---------------------------------------------------------------------------
 
 def test_loc_overflow_tile_geometry_raises():
-    """Regression: m_tb*k_tb > 65536 used to silently wrap ``loc & 0xFFFF``
-    in pack_words and corrupt weight placement; encode must refuse."""
-    a = np.zeros((512, 512), np.float32)
-    a[511, 511] = 1.0
-    with pytest.raises(ValueError, match="16-bit loc"):
-        tiled_csl.encode(a, m_tb=512, k_tb=512)
-    with pytest.raises(ValueError, match="16-bit loc"):
-        tiled_csl.encode(np.zeros((256, 512), np.float32), m_tb=256, k_tb=512)
+    """A tile taller than the 16-bit row field (whose all-ones value marks
+    padding) would wrap ``row & 0xFFFF`` or alias the pad marker and
+    corrupt weight placement; encode must refuse."""
+    with pytest.raises(ValueError, match="16-bit row"):
+        tiled_csl.encode(np.zeros((0xFFFF, 1), np.float32), m_tb=0xFFFF,
+                         k_tb=1)
+    with pytest.raises(ValueError, match="16-bit row"):
+        tiled_csl.encode(np.zeros((0x10000, 1), np.float32), m_tb=0x10000,
+                         k_tb=1)
 
 
 def test_loc_boundary_geometry_roundtrips():
-    """m_tb*k_tb == 65536 is the largest legal tile: the bottom-right
-    element (loc 65535) must survive the roundtrip exactly."""
-    a = np.zeros((256, 256), np.float32)
+    """m_tb == 0xFFFE is the tallest legal tile: its bottom row (row field
+    0xFFFE, one below the pad marker) must survive the roundtrip exactly."""
+    a = np.zeros((0xFFFE, 2), np.float32)
     a[0, 0] = 2.0
-    a[255, 255] = 1.0        # loc = 255*256 + 255 = 65535
-    t = tiled_csl.encode(a, m_tb=256, k_tb=256)
+    a[0xFFFD, 1] = 1.0
+    t = tiled_csl.encode(a, m_tb=0xFFFE, k_tb=1)
     dec = tiled_csl.decode(t)
     np.testing.assert_allclose(dec, a, atol=0.0)
 

@@ -155,7 +155,7 @@ def test_tile_balanced_mask_equalizes_tiles():
     counts = np.asarray(m).reshape(2, 128, 2, 128).transpose(0, 2, 1, 3) \
         .reshape(4, -1).sum(axis=1)
     assert counts.min() == counts.max()   # exactly equal nnz per tile
-    # and the Tiled-CSL encoding of it has zero pad overhead
+    # and the Tiled-CSL encoding carries exactly that count in every tile
     from repro.core import tiled_csl
     t = tiled_csl.encode(np.asarray(jnp.where(m, w, 0.0)))
-    assert t.pad_overhead < 0.02
+    assert (np.asarray(t.nnz) == counts.min()).all()
