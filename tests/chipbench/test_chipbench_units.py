@@ -1,0 +1,310 @@
+"""Unit tests of the chip benchmark's yardstick: traffic, trace reduction,
+metric arithmetic, discovery by name, and the refusal to run off the chip."""
+
+from __future__ import annotations
+
+import collections
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from chipbench_tiny import ROOT
+
+from chipbench import flops, harness, peaks, trace_reduce, traffic
+
+CHAT = traffic.load("chat")
+
+
+# ---------------------------------------------------------------------------
+# traffic
+# ---------------------------------------------------------------------------
+
+def _sched(seed, rate=4.0):
+    return traffic.schedule(CHAT, rate=rate, seed=seed, vocab=50272,
+                            phases=[("warmup", 5.0), ("window", 20.0),
+                                    ("tail", 3.0)])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2 ** 31 + 11, 3 * 2 ** 32 + 5])
+def test_traffic_is_byte_identical_for_a_seed(seed):
+    assert traffic.fingerprint(_sched(seed)) == traffic.fingerprint(
+        _sched(seed))
+
+
+def test_traffic_seeds_permute_the_same_work():
+    a, b = _sched(1), _sched(2)
+    assert traffic.fingerprint(a) != traffic.fingerprint(b)
+    for phase in ("warmup", "window", "tail"):
+        ra = [r for r in a if r.phase == phase]
+        rb = [r for r in b if r.phase == phase]
+        assert len(ra) == len(rb)
+        assert sorted(len(r.prompt) for r in ra) == sorted(
+            len(r.prompt) for r in rb)
+        assert sorted(r.max_new for r in ra) == sorted(r.max_new for r in rb)
+        ga = np.sort(np.diff([r.due for r in ra]))
+        gb = np.sort(np.diff([r.due for r in rb]))
+        assert ga.sum() == pytest.approx(gb.sum(), rel=0.2)
+    win = [r for r in a if r.phase == "window"]
+    assert len(win) == 80
+    assert all(5.0 <= r.due < 25.0 for r in win)
+    assert all(20 <= len(r.prompt) <= 170 and 88 <= r.max_new <= 341
+               for r in a)
+
+
+def test_chat_mix_matches_its_published_means_and_fits_max_len():
+    """LMSYS-Chat-1M (arXiv:2309.11998, Table 1): 69.5 tokens per prompt,
+    214.5 per response; the longest pair fits the cell's max_len."""
+    assert "2309.11998" in CHAT["source"]
+    for n in (51, 1000):
+        assert traffic.lengths(CHAT["prompt_len"], n).mean() == pytest.approx(
+            69.5, rel=0.01)
+        assert traffic.lengths(CHAT["output_len"], n).mean() == pytest.approx(
+            214.5, rel=0.01)
+    cell = harness.load_cell("opt30b-4l-s80.chat")
+    assert (CHAT["prompt_len"]["hi"] + CHAT["output_len"]["hi"]
+            < cell.params["max_len"])
+
+
+def test_length_quantiles():
+    u = traffic.lengths({"dist": "uniform", "lo": 16, "hi": 64}, 1000)
+    assert u.min() == 16 and u.max() == 64
+    assert u.mean() == pytest.approx(40.0, abs=0.6)
+    lu = traffic.lengths({"dist": "log_uniform", "lo": 256, "hi": 1024},
+                         1000)
+    assert lu.min() == 256 and lu.max() == 1024
+    assert np.median(lu) == pytest.approx(512, rel=0.02)
+
+
+# ---------------------------------------------------------------------------
+# trace reduction (a small recorded timeline in the profiler's shape)
+# ---------------------------------------------------------------------------
+
+Ev = collections.namedtuple("Ev", "name start_ns duration_ns stats")
+Line = collections.namedtuple("Line", "name events")
+Plane = collections.namedtuple("Plane", "name lines")
+
+
+K3 = ('%lscd_spmm.3 = bf16[128,64]{1,0:T(8,128)(2,1)S(1)} custom-call('
+      'u32[4,4,8,128]{3,2,1,0:T(8,128)} %w, bf16[128,64]{1,0:T(8,128)S(1)} '
+      '%x), custom_call_target="tpu_custom_call", operand_layout_constraints'
+      '={u32[4,4,8,128]{3,2,1,0}}')
+ALLOC = ('%custom-call.1 = bf16[8,128]{1,0} custom-call(), '
+         'custom_call_target="AllocateBuffer"')
+LOOP = '%while.5 = (s32[], bf16[64,8]) while((s32[], bf16[64,8]) %t)'
+
+
+def _planes():
+    host = Plane("/host:CPU", [Line("python", [
+        Ev("chipbench.window", 1000, 10000, []),
+        Ev("chipbench.step", 1000, 4000, []),
+        Ev("chipbench.wait", 5000, 3000, []),
+        Ev("chipbench.step", 8000, 3000, []),
+        Ev("unrelated", 0, 20000, []),
+    ])])
+    ops = [Ev("%fusion.1 = f32[8] fusion()", 500, 1500, []),
+           Ev(LOOP, 2000, 2500, []),
+           Ev(K3, 2000, 2000, []),
+           Ev(ALLOC, 2100, 10, []),
+           Ev("%fusion.2 = f32[8] fusion()", 3500, 1000, []),
+           Ev(K3, 9000, 1000, []),
+           Ev("%fusion.9 = f32[8] fusion()", 10500, 2000, [])]
+    modules = [Ev("jit_decode(1)", 400, 4200, []),
+               Ev("jit_decode(1)", 8900, 1200, []),
+               Ev("jit_other(2)", 10400, 2200, [])]
+    dev = Plane("/device:TPU:0", [Line("Steps", [Ev("1", 0, 20000, [])]),
+                                  Line("XLA Modules", modules),
+                                  Line("XLA Ops", ops)])
+    return [host, dev]
+
+
+def test_trace_reduction_on_a_small_timeline():
+    r = trace_reduce.reduce_planes(_planes())
+    assert r["n_devices"] == 1
+    assert r["window_s"] == pytest.approx(10000e-9)
+    # busy inside [1000, 11000): 1000-4500, 9000-10000, 10500-11000
+    assert r["busy_s"] == pytest.approx(5000e-9)
+    assert r["pallas_s"] == pytest.approx(3000e-9)
+    assert r["device_ops"][0] == ["lscd_spmm.3", pytest.approx(3000e-9)]
+    assert "while.5" not in dict(r["device_ops"])
+    # the kernel's words are in HBM, its activation and result on chip
+    assert r["kernels"] == {"jit_decode(1)": {
+        "runs": 2, "seconds": pytest.approx(3000e-9),
+        "hbm_bytes": 2 * 4 * 4 * 8 * 128 * 4}}
+    # idle gaps: 4500-9000 (host waiting mostly), 10000-10500 (step)
+    assert r["idle_gaps"][0] == ["wait", pytest.approx(4500e-9)]
+    assert r["idle_gaps"][1] == ["step", pytest.approx(500e-9)]
+
+
+def test_trace_reduction_of_a_recorded_chip_window():
+    """0.5 s of a traced opt30b-4l-s80.chat window on a TPU v5e: four
+    decode steps of 64 slots through the LSCD kernels."""
+    r = trace_reduce.reduce_file(os.path.join(
+        os.path.dirname(__file__), "data", "opt_chat_window.xplane.pb"))
+    assert r["window_s"] == pytest.approx(0.5)
+    assert 0.45 < r["busy_s"] <= r["window_s"]
+    assert 0.2 < r["pallas_s"] < r["busy_s"]
+    assert r["device_ops"][0][0].startswith("lscd_spmm")
+    assert {g[0] for g in r["idle_gaps"]} <= {"step", "submit", "wait",
+                                               "host"}
+    (decode,) = r["kernels"].values()
+    assert decode["runs"] == 4
+    share = harness.load_reader("lscd_decode_roofline")(
+        {"trace": r, "device_kind": "TPU v5 lite"})
+    assert 1.0 < share < 100.0
+
+
+def test_trace_reduction_finds_nothing_without_a_window_or_device():
+    planes = _planes()
+    assert trace_reduce.reduce_planes(planes[1:]) is None
+    assert trace_reduce.reduce_planes(planes[:1]) is None
+
+
+def test_union_of_intervals():
+    assert trace_reduce.union([(5, 7), (0, 2), (1, 3), (7, 8)]) == [
+        (0, 3), (5, 8)]
+
+
+# ---------------------------------------------------------------------------
+# metric arithmetic on a fixed step log
+# ---------------------------------------------------------------------------
+
+def _record():
+    model = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv": 4,
+             "d_ff": 256, "vocab": 512, "mlp_kind": "gelu",
+             "mlp_bias": True, "qkv_bias": True, "norm_kind": "layernorm",
+             "sparsity": 0.8}
+    return {"window_s": 2.0, "tokens": 100, "steps_in_window": 40,
+            "decode_launches": 32, "host_step_s": 1.8,
+            "itl_s": [0.1] * 10 + [0.2] * 10,
+            "delta": {"slot_steps": 160.0, "active_slot_steps": 120.0,
+                      "admit_time_s": 0.5, "decode_time_s": 1.2,
+                      "prefill_tokens": 250.0},
+            "required_flops": 3.94e12, "weight_bytes": 3 * 2 ** 29,
+            "device_kind": "TPU v5 lite", "model": model,
+            "trace": {"window_s": 2.0, "busy_s": 1.5, "pallas_s": 0.9,
+                      "kernels": {"jit_decode(1)": {
+                          "runs": 30, "seconds": 1.0,
+                          "hbm_bytes": 40.95e9},
+                          "jit_prefill(2)": {"runs": 2, "seconds": 0.5,
+                                             "hbm_bytes": 1e9}}}}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("batch_occupancy", 75.0),
+    ("host_ms_per_step", 1e3 * (1.8 - 0.5 - 1.2) / 40),
+    ("itl_tail_p95_ms", 200.0),
+    ("decode_ms", 1e3 * 1.2 / 32),
+    ("prefill_ms_per_token", 1e3 * 0.5 / 250),
+    ("device_idle_share", 25.0),
+    ("pallas_busy_share", 60.0),
+    ("step_mfu", 100.0 * 3.94e12 / (1.5 * 197e12)),
+    ("weight_gib", 1.5),
+    ("lscd_decode_roofline", 5.0),
+])
+def test_metric_reader(name, want):
+    assert harness.load_reader(name)(_record()) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", ["device_idle_share", "pallas_busy_share",
+                                  "lscd_decode_roofline", "step_mfu"])
+def test_trace_metrics_are_silent_without_a_trace(name):
+    rec = dict(_record(), trace=None)
+    assert harness.load_reader(name)(rec) is None
+
+
+def test_every_listed_metric_has_a_reader():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for m in bench["per_layer"]:
+        assert callable(harness.load_reader(m["name"]))
+
+
+def test_required_flops_count_kept_weights_only():
+    m = _record()["model"]
+    kept = sum(round(a * b * 0.2) for a, b in
+               [(128, 128)] * 4 + [(256, 128), (128, 256)])
+    assert flops.projection_flops(m) == 2 * kept * 2
+    assert flops.decode_flops(m, 10) == (2 * kept * 2 + 4 * 128 * 10 * 2
+                                         + 2 * 512 * 128)
+    assert flops.prefill_flops(m, 3) == (3 * 2 * kept * 2
+                                         + 4 * 128 * 6 * 2 + 2 * 512 * 128)
+
+
+def test_peaks_refuse_an_unknown_chip():
+    assert peaks.for_kind("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        peaks.for_kind("cpu")
+
+
+# ---------------------------------------------------------------------------
+# found by name: a new configuration, mix or metric is a new file
+# ---------------------------------------------------------------------------
+
+def test_new_config_mix_and_metric_are_found_without_edits(tmp_path):
+    shutil.copytree(os.path.join(ROOT, "chipbench"), tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("cache", "__pycache__"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    pkg = tmp_path / "chipbench"
+    (pkg / "configs" / "newmodel.json").write_text(json.dumps(
+        {"model": {"n_layers": 1}, "sparsity": 0.5, "weight_seed": 3}))
+    (pkg / "traffic" / "bursty.json").write_text(json.dumps(
+        {"prompt_len": {"dist": "uniform", "lo": 4, "hi": 8},
+         "output_len": {"dist": "uniform", "lo": 1, "hi": 2}}))
+    (pkg / "cells" / "newmodel.bursty.json").write_text(json.dumps(
+        {"n_slots": 2, "rate_per_s": 1.0}))
+    (pkg / "metrics" / "queue_wait_ms.py").write_text(
+        "def read(rec):\n    return 7.0\n")
+    bench["workloads"].append({"name": "newmodel.bursty",
+                               "config": "newmodel", "traffic": "bursty",
+                               "chips": 1, "why": "test"})
+    bench["per_layer"].append({"name": "queue_wait_ms", "unit": "ms",
+                               "better": "lower", "source": "program_span",
+                               "layer": "scheduler",
+                               "moves": "ttft_p50_ms",
+                               "workloads": ["newmodel.bursty"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = harness.load_cell("newmodel.bursty", root=str(tmp_path))
+    assert cell.config["sparsity"] == 0.5
+    assert cell.traffic["prompt_len"]["hi"] == 8
+    assert cell.params["n_slots"] == 2
+    assert "queue_wait_ms" in [m["name"] for m in cell.per_layer]
+    assert harness.load_reader("queue_wait_ms", root=str(tmp_path))({}) == 7.0
+    other = harness.load_cell("opt30b-4l-s80.chat", root=str(tmp_path))
+    assert "queue_wait_ms" not in [m["name"] for m in other.per_layer]
+
+
+# ---------------------------------------------------------------------------
+# the command refuses to run without the chip or without the program
+# ---------------------------------------------------------------------------
+
+def _run(cwd, env_extra=None):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    env.update(env_extra or {})
+    return subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload",
+         "opt30b-4l-s80.chat", "--seed", "2147483659", "--seconds", "1",
+         "--trace", "0"], cwd=cwd, env=env, capture_output=True, text=True,
+        timeout=300)
+
+
+def test_run_on_a_cpu_exits_nonzero_and_prints_no_result():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "accelerator" in p.stderr
+
+
+def test_run_without_the_program_exits_nonzero(tmp_path):
+    shutil.copytree(os.path.join(ROOT, "chipbench"), tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("cache", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    p = _run(str(tmp_path))
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
