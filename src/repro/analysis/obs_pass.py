@@ -2,15 +2,16 @@
 
 The metrics registry and the trace stream are two views of the same events:
 ``SchedulerMetrics.preemptions`` counts what the ``preempt`` trace events
-narrate, ``quarantined`` pairs with ``quarantine`` events, and every fault
-the injector fires must land in the timeline. Instrumentation drift — a new
-code path that bumps a counter but forgets its trace event (or vice versa)
-— silently produces timelines that lie about what the counters report.
+narrate, ``steps`` counts the ``step`` spans, ``quarantined`` pairs with
+``quarantine`` events, and every fault the injector fires must land in
+the timeline. Instrumentation drift — a new code path that bumps a counter
+but forgets its trace event (or vice versa) — silently produces timelines
+that lie about what the counters report.
 
 This pass runs one small fault-laden replay (seeded trace + handcrafted
 :class:`~repro.serving.faults.FaultPlan` covering transient step errors,
 NaN-poisoned logits, a pool storm, and an injected latency spike) with a
-*private* tracer, then asserts counter == event-count for every paired
+*private* tracer, then asserts counter == record count for every paired
 series. A mismatch is an ``OB-EVENT`` finding anchored to the pseudo-path
 ``obs:<scenario>`` (allowlist-suppressible, like trace-audit findings).
 
@@ -37,6 +38,8 @@ PAIRED_SERIES: Tuple[Tuple[str, Tuple[str, str]], ...] = (
     # chunked-prefill mixed steps (§16): one "sched"/"chunk" event per
     # mixed launch (0 == 0 in non-chunked scenarios)
     ("mixed_steps", ("sched", "chunk")),
+    # one "step" span per server step (the span tree's root, §15)
+    ("steps", ("step", "step")),
 )
 
 
@@ -83,9 +86,8 @@ def run_obs_pass(seed: int = 0) -> Tuple[List[Finding], Dict[str, int]]:
     found: List[Finding] = []
     by_key: Dict[Tuple[str, str], int] = {}
     for r in records:
-        if r.kind == "event":
-            k = (r.cat, r.name)
-            by_key[k] = by_key.get(k, 0) + 1
+        k = (r.cat, r.name)
+        by_key[k] = by_key.get(k, 0) + 1
 
     nonzero = 0
     for attr, (cat, name) in PAIRED_SERIES:
@@ -97,7 +99,7 @@ def run_obs_pass(seed: int = 0) -> Tuple[List[Finding], Dict[str, int]]:
             found.append(Finding(
                 "OB-EVENT", path, 0,
                 f"metrics.{attr}={counter} but the trace carries {events} "
-                f"{name!r} event(s)", hint))
+                f"{name!r} record(s)", hint))
     # injected faults only — "retry" is the batcher's *reaction* (paired
     # with step_retries above), not an injector firing
     n_fault_events = sum(1 for r in records

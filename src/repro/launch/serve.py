@@ -24,11 +24,12 @@ Observability knobs (DESIGN.md §15): ``--metrics-port P`` exposes the live
 scheduler counters at ``http://127.0.0.1:P/metrics`` (Prometheus text
 exposition; ``/metrics.json`` for machines) with ``--digest-every S``
 printing a one-line operator digest every S seconds; ``--trace-out t.json``
-records every scheduler decision, engine step, and kernel launch into a
-Perfetto-loadable timeline; ``--profile-kernels`` measures each unique
-sparse-kernel launch after the run drains and prints a predicted-vs-
-measured roofline drift table (pair with ``--backend interpret`` off-TPU —
-the XLA reference path has no schedulable launches to record).
+records every scheduler decision, the span tree of each engine step, every
+kernel launch and every JAX compile into a Perfetto-loadable timeline;
+``--profile-kernels`` measures each unique sparse-kernel launch after the
+run drains and prints a predicted-vs-measured roofline drift table (pair
+with ``--backend interpret`` off-TPU — the XLA reference path has no
+schedulable launches to record).
 """
 
 from __future__ import annotations
@@ -168,16 +169,18 @@ def main() -> None:
                          "metrics every S seconds while serving")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="export the run's structured trace (scheduler "
-                         "decisions, engine steps, kernel launches) as "
-                         "Perfetto/Chrome trace_event JSON")
+                         "decisions, engine step spans, kernel launches, "
+                         "compiles) as Perfetto/Chrome trace_event JSON")
     ap.add_argument("--profile-kernels", action="store_true",
                     help="record every unique kernel launch, re-measure it "
                          "fenced after the run drains, and print the "
                          "predicted-vs-measured roofline drift table")
     args = ap.parse_args()
     compile_cache.enable()
+    stop_compiles = None
     if args.trace_out:
-        obs_trace.get_tracer().enable()
+        stop_compiles = obs_trace.record_compiles(
+            obs_trace.get_tracer().enable())
     profiler = obs_profile.KernelProfiler() if args.profile_kernels else None
     if profiler is not None:
         obs_profile.set_profiler(profiler)
@@ -274,8 +277,6 @@ def main() -> None:
     total_dl = (args.deadline_ms / 1e3
                 if slo is None and args.deadline_ms is not None else None)
     b = server.batcher
-    if args.profile_kernels and args.trace_out:
-        b.stepper.profile = True  # wall_us on step spans (fenced, host-side)
     registry = http_srv = stop_digest = None
     if args.metrics_port is not None or args.digest_every is not None:
         registry = obs_metrics.MetricsRegistry()
@@ -382,6 +383,7 @@ def main() -> None:
         print(f"kernel drift ({rep['n_unique_launches']} unique launches):")
         print(obs_profile.render_drift_table(rep["rows"]))
     if args.trace_out:
+        stop_compiles()
         tr = obs_trace.get_tracer()
         obs_export.write_chrome_trace(tr.records(), args.trace_out)
         print(f"wrote {args.trace_out}: {len(tr)} trace records "

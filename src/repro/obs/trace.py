@@ -19,9 +19,17 @@ allocates nothing.  ``tests/test_obs.py`` pins this with an overhead guard.
 
 Records are plain tuples-of-fields (a small dataclass): ``kind`` is either
 ``"event"`` (instant) or ``"span"`` (has a duration); ``cat`` groups
-records (``sched`` / ``step`` / ``fault`` / ``kernel``); ``track`` names
-the Perfetto row the record lands on (``scheduler``, ``slot0``..``slotN``,
-``engine``, ``kernel``).
+records (``sched`` / ``step`` / ``fault`` / ``kernel`` / ``compile``);
+``track`` names the Perfetto row the record lands on (``scheduler``,
+``slot0``..``slotN``, ``engine``, ``kernel``, ``host``).
+
+The serving step is a span tree on the ``engine`` track (DESIGN §15):
+``step`` holds ``admit`` (``prefill`` launches, ``sample`` with its
+``wait``), ``stage``, the ``decode`` / ``mixed`` / ``verify`` launch with
+its ``wait`` (the blocking device-to-host read), and ``commit``; the
+``stream`` span of the token callbacks follows it. A ``queue`` span on the
+``scheduler`` track covers each stay of a request in the admission queue.
+:func:`record_compiles` adds a ``compile`` span per JAX trace and compile.
 """
 
 from __future__ import annotations
@@ -31,7 +39,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["TraceRecord", "Tracer", "get_tracer", "set_tracer"]
+__all__ = ["TraceRecord", "Tracer", "get_tracer", "set_tracer",
+           "record_compiles"]
 
 _EMPTY: Dict[str, Any] = {}
 
@@ -132,3 +141,28 @@ def set_tracer(tracer: Tracer) -> Tracer:
     global _TRACER
     prev, _TRACER = _TRACER, tracer
     return prev
+
+
+#: ``jax.monitoring`` duration events recorded as ``compile`` spans.
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+def record_compiles(tracer: Tracer) -> Callable[[], None]:
+    """Record every JAX trace and backend compile as a ``compile`` span
+    (cat ``compile``, track ``host``) ending at the listener's
+    ``tracer.clock()`` and lasting the duration JAX reports. Registers a
+    ``jax.monitoring`` listener and returns the function that unregisters
+    it. Opt-in per caller, not a flag: a listener left on would put
+    compile records into one replay's timeline and not the next."""
+    from jax import monitoring
+
+    def listener(event: str, duration_s: float, **kwargs: Any) -> None:
+        if event in COMPILE_EVENTS and tracer.enabled:
+            t1 = tracer.clock()
+            tracer.span("compile", "compile", "host", t1 - duration_s, t1,
+                        fun_name=kwargs.get("fun_name", ""),
+                        event=event.rsplit("/", 1)[-1])
+
+    monitoring.register_event_duration_secs_listener(listener)
+    return lambda: monitoring.unregister_event_duration_listener(listener)

@@ -283,14 +283,19 @@ class StreamingServer:
         """Run one engine step; stream every newly generated token to its
         session's callback, then return the sessions that finished."""
         finished = self.batcher.step()
+        tr = self.batcher.tracer
+        t0 = tr.clock() if tr.enabled else 0.0
+        tokens = 0
         # Stream in uid order (stable, independent of slot assignment).
         for sess in sorted(self._by_uid.values(), key=lambda s: s.uid):
-            self._drain_stream(sess, sess.req)
+            tokens += self._drain_stream(sess, sess.req)
         out: List[GenerationResponse] = []
         for uid in finished:
             sess = self._by_uid.get(uid)
             if sess is not None:
                 out.append(self._close(sess))
+        if tr.enabled:
+            tr.span("step", "stream", "engine", t0, tokens=tokens)
         return out
 
     def run_until_drained(self, max_steps: int = 10_000
@@ -371,11 +376,14 @@ class StreamingServer:
         return server
 
     # -- internals -----------------------------------------------------------
-    def _drain_stream(self, sess: _Session, req) -> None:
-        if sess.on_token is None:
-            sess.delivered = len(req.generated)
-            return
+    def _drain_stream(self, sess: _Session, req) -> int:
+        """Fire ``on_token`` for every token past the session's delivered
+        watermark; returns how many that was."""
         n = len(req.generated)
+        new = n - sess.delivered
+        if sess.on_token is None:
+            sess.delivered = n
+            return new
         for i in range(sess.delivered, n):
             last = req.done and i == n - 1
             att = self._attainment(req) if last else None
@@ -384,6 +392,7 @@ class StreamingServer:
                 index=i, finish_reason=req.finish_reason if last else "",
                 attainment=att))
         sess.delivered = n
+        return new
 
     @staticmethod
     def _attainment(req) -> Optional[SLOAttainment]:

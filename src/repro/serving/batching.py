@@ -345,6 +345,8 @@ class ContinuousBatcher:
         quarantine, each with its explicit ``finish_reason``."""
         sched = self.sched
         m = sched.metrics
+        tr = self.tracer
+        t_step = tr.clock() if tr.enabled else 0.0
         finished: Dict[int, List[int]] = {}
         inj = self.faults
         if inj is not None:
@@ -361,6 +363,9 @@ class ContinuousBatcher:
         sched.expire_deadlines(finished)
         sched.update_degradation()
         t0 = time.monotonic()
+        t_admit = tr.clock() if tr.enabled else 0.0
+        calls, admitted = m.prefill_calls, m.admitted
+        buckets = [] if tr.enabled else None
         if self.chunked:
             # §16 admission: slot assignment + block mapping only — the
             # prompt K/V streams in through the mixed step's chunks below
@@ -378,12 +383,19 @@ class ContinuousBatcher:
                 nxt, ok = self.stepper.sample_admitted(logits, plan.uids,
                                                        plan.counts)
                 sched.commit_admission(plan, nxt, finished, ok=ok)
+                if buckets is not None:
+                    buckets.append(plan.bucket)
         m.admit_time_s += time.monotonic() - t0
+        launches = m.prefill_calls - calls
+        if tr.enabled:
+            tr.span("step", "admit", "engine", t_admit, launches=launches,
+                    rows=m.admitted - admitted, buckets=buckets)
         staged: Dict[int, np.ndarray] = {}
         mixed_plan = None
         if self.paged:
             # Growth / copy-on-write / preemption happen before the step,
             # so the jitted decode sees fully-valid tables.
+            t_stage = tr.clock() if tr.enabled else 0.0
             if self.chunked:
                 mixed_plan, copies = sched.stage_mixed()
             elif self.spec_k and sched.effective_spec_k:
@@ -391,6 +403,9 @@ class ContinuousBatcher:
             else:
                 copies = sched.prepare_decode()
             self.stepper.apply_copies(copies)
+            if tr.enabled:
+                tr.span("step", "stage", "engine", t_stage,
+                        copies=len(copies))
             m.blocks_in_use = sched.pool.blocks_in_use
             m.peak_blocks_in_use = max(m.peak_blocks_in_use, m.blocks_in_use)
         active = sched.active_slot_ids()
@@ -399,7 +414,7 @@ class ContinuousBatcher:
         m.active_slot_steps += len(active)
         m.peak_active_slots = max(m.peak_active_slots, len(active))
         if not active:
-            self._trace_step_end(m, 0, len(finished))
+            self._trace_step(t_step, 0, len(finished), launches)
             return finished
         t0 = time.monotonic()
         if mixed_plan is not None and mixed_plan.chunks:
@@ -408,7 +423,7 @@ class ContinuousBatcher:
                 mixed_plan.n_tokens, mixed_plan.uids, mixed_plan.counts))
             m.compute_positions += mixed_plan.tokens.size
             m.mixed_steps += 1
-            tr = self.tracer
+            t_commit = tr.clock() if tr.enabled else 0.0
             if tr.enabled:
                 # paired with the mixed_steps counter (obs pass OB-EVENT)
                 tr.event("sched", "chunk", "scheduler",
@@ -420,16 +435,18 @@ class ContinuousBatcher:
             good = [s for s in mixed_plan.decode_slots if ok[s]]
             if good:
                 sched.commit_decode(good, tok, finished)
-            sched.commit_chunks(
-                {s: n for s, n in mixed_plan.chunks.items() if ok[s]},
-                tok, finished)
+            chunks = {s: n for s, n in mixed_plan.chunks.items() if ok[s]}
+            sched.commit_chunks(chunks, tok, finished)
+            committed = len(good) + len(chunks)
         elif self.spec_k and any(len(staged.get(s, ())) for s in active):
             vb = sched.build_verify(active, staged)
             tgt, n_acc = self._launch("verify", lambda: self.stepper.verify(
                 vb.tokens, sched.pos, sched.table_arr, vb.draft_lens,
                 vb.uids, vb.counts))
             m.compute_positions += vb.tokens.size
+            t_commit = tr.clock() if tr.enabled else 0.0
             sched.commit_verify(active, tgt, n_acc, finished)
+            committed = len(active)
         else:
             # No drafts anywhere (or spec off): ordinary one-token decode —
             # the drafter contract's degradation path, at window width 1
@@ -439,29 +456,38 @@ class ContinuousBatcher:
                 sched.last_token, sched.pos,
                 sched.table_arr if self.paged else None, uids, counts))
             m.compute_positions += self.n_slots
+            t_commit = tr.clock() if tr.enabled else 0.0
             good = [s for s in active if ok[s]]
             for s in active:
                 if not ok[s]:                    # non-finite logits: contain
                     sched.quarantine_slot(s, finished)
             if good:
                 sched.commit_decode(good, nxt, finished)
+            committed = len(good)
+        if tr.enabled:
+            tr.span("step", "commit", "engine", t_commit, committed=committed)
         m.decode_time_s += time.monotonic() - t0
         if self.paged:
             # refresh after completions freed their tables (the pre-decode
             # sample above is the high-water mark)
             m.blocks_in_use = sched.pool.blocks_in_use
-        self._trace_step_end(m, len(active), len(finished))
+        self._trace_step(t_step, len(active), len(finished), launches)
         return finished
 
-    def _trace_step_end(self, m, n_active: int, n_finished: int) -> None:
-        """Per-step engine 'tick' event — the timeline's heartbeat (fault
-        firings are traced at the source, ``FaultInjector._fire``)."""
+    def _trace_step(self, t0: float, n_active: int, n_finished: int,
+                    launches: int) -> None:
+        """The ``step`` span over the whole server step — the timeline's
+        heartbeat, paired with ``metrics.steps`` (fault firings are traced
+        at the source, ``FaultInjector._fire``)."""
         tr = self.tracer
         if not tr.enabled:
             return
-        tr.event("step", "tick", "engine", step=m.steps, active=n_active,
-                 finished=n_finished, queue=self.sched.queue_depth,
-                 degradation=self.sched.degradation.level)
+        sched = self.sched
+        tr.span("step", "step", "engine", t0, step=sched.metrics.steps,
+                active=n_active, finished=n_finished,
+                queue=sched.queue_depth, admit_launches=launches,
+                blocks_in_use=sched.pool.blocks_in_use if self.paged else 0,
+                degradation=sched.degradation.level)
 
     def run_to_completion(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         out: Dict[int, List[int]] = {}

@@ -88,6 +88,9 @@ class Request:
     submit_t: float = -1.0
     first_token_t: float = -1.0
     finish_t: float = -1.0
+    # start of the current queue stay on the *tracer's* clock (submit or
+    # requeue; None while tracing is off) — the ``queue`` span's start
+    queue_t: Optional[float] = None
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -522,6 +525,7 @@ class Scheduler:
         self.requests[uid] = req
         tr = self.tracer
         if tr.enabled:
+            req.queue_t = tr.clock()
             tr.event("sched", "submit", "scheduler", uid=uid,
                      prompt_len=int(prompt.size), max_new=max_new_tokens)
         return req
@@ -561,8 +565,8 @@ class Scheduler:
             tr.event("sched", "cancel", "scheduler", uid=uid)
             if slot is not None:
                 tr.span("sched", f"req{uid}", f"slot{slot}",
-                        self._slot_admit_t[slot], req.finish_t,
-                        uid=uid, reason="cancelled")
+                        self._slot_admit_t[slot], uid=uid,
+                        reason="cancelled")
         self._retire(req)
         return req
 
@@ -641,8 +645,8 @@ class Scheduler:
             tr.event("sched", "finish", "scheduler", uid=req.uid,
                      reason=reason, tokens=len(req.generated))
             tr.span("sched", f"req{req.uid}", f"slot{slot}",
-                    self._slot_admit_t[slot], req.finish_t,
-                    uid=req.uid, reason=reason, tokens=len(req.generated))
+                    self._slot_admit_t[slot], uid=req.uid, reason=reason,
+                    tokens=len(req.generated))
         self._retire(req)
 
     def _record_attainment(self, req: Request) -> None:
@@ -689,8 +693,8 @@ class Scheduler:
             tr.event("sched", name, "scheduler", uid=req.uid)
             if slot is not None:
                 tr.span("sched", f"req{req.uid}", f"slot{slot}",
-                        self._slot_admit_t[slot], req.finish_t,
-                        uid=req.uid, reason=reason)
+                        self._slot_admit_t[slot], uid=req.uid,
+                        reason=reason)
         self._retire(req)
 
     # -- deadlines / quarantine (DESIGN.md §14) -----------------------------
@@ -913,6 +917,7 @@ class Scheduler:
             tr.event("sched", "preempt", "scheduler", uid=req.uid, slot=s)
             tr.span("sched", f"req{req.uid}", f"slot{s}",
                     self._slot_admit_t[s], uid=req.uid, reason="preempt")
+            req.queue_t = tr.clock()
         self._release_slot(s)
         req.pending = True
         req.admit_step = -1
@@ -1025,10 +1030,25 @@ class Scheduler:
         self._purge_stale()
         return group
 
+    def _trace_queue_stays(self, group: Sequence[Request],
+                           t1: float) -> None:
+        """One ``queue`` span per request leaving the queue at ``t1`` (the
+        start of the admission that takes it), from its submit or requeue;
+        ``resume`` counts the generated tokens a requeued request carries."""
+        tr = self.tracer
+        for req in group:
+            if req.queue_t is not None:
+                tr.span("sched", "queue", "scheduler", req.queue_t, t1,
+                        uid=req.uid, prompt_len=int(req.prompt.size),
+                        resume=len(req.generated))
+                req.queue_t = None
+
     def plan_admission(self) -> Optional[AdmissionPlan]:
         """Resolve the next prefill launch, or None when admission must
         stall (no free slot, empty queue, or the block gate holds the
         head-of-line request back until completions free pool blocks)."""
+        tr = self.tracer
+        t_plan = tr.clock() if tr.enabled else 0.0
         self._purge_stale()
         if not self.queue:
             return None
@@ -1036,6 +1056,8 @@ class Scheduler:
         if not free:
             return None
         group = self._take_group(min(len(free), self.effective_admit_k))
+        if tr.enabled:
+            self._trace_queue_stays(group, t_plan)
         if not group:
             # Block pool full: wait for completions to free blocks. If
             # nothing is in flight and the pool is already fully free,
@@ -1120,10 +1142,11 @@ class Scheduler:
             m.bucket_admits.get(plan.bucket, 0) + 1
         now = self.clock()
         tr = self.tracer
+        t_tr = tr.clock() if tr.enabled else 0.0
         for i, req in enumerate(plan.group):
             s = plan.slots[i]
             self.slots[s] = req
-            self._slot_admit_t[s] = now
+            self._slot_admit_t[s] = t_tr
             if tr.enabled:
                 tr.event("sched", "admit", "scheduler", uid=req.uid,
                          slot=s, bucket=plan.bucket,
@@ -1220,8 +1243,8 @@ class Scheduler:
             # reserve is decode-growth headroom for *other* active requests
             budget = self.pool.available
         m = self.metrics
-        now = self.clock()
         tr = self.tracer
+        t_tr = tr.clock() if tr.enabled else 0.0
         admitted: List[int] = []
         for req in cands:
             if len(admitted) >= limit:
@@ -1249,11 +1272,12 @@ class Scheduler:
             self.pos[s] = 0
             self.last_token[s] = 0
             self.chunk_goal[s] = len(ft)
-            self._slot_admit_t[s] = now
+            self._slot_admit_t[s] = t_tr
             req.admit_step = m.steps
             m.admitted += 1
             m.queue_wait_steps += m.steps - req.submit_step
             if tr.enabled:
+                self._trace_queue_stays((req,), t_tr)
                 tr.event("sched", "admit", "scheduler", uid=req.uid,
                          slot=s, chunked=True, resume=len(ft),
                          queued_steps=m.steps - req.submit_step)
@@ -1556,6 +1580,8 @@ class Scheduler:
                           submit_t=float(d["submit_t"]))
             req.generated = [int(t) for t in d["generated"]]
             req.first_token_t = float(d["first_token_t"])
+            if self.tracer.enabled:
+                req.queue_t = self.tracer.clock()
             self._enqueue(req)
             self.requests[req.uid] = req
             restored.append(req)

@@ -29,7 +29,6 @@ would take. Injection off ⇒ both hooks are dead code.
 
 from __future__ import annotations
 
-import time
 from typing import Iterable, Optional, Tuple
 
 import jax
@@ -62,12 +61,6 @@ class DeviceStepper:
         self.cfg = cfg
         self.backend = backend
         self.tracer = tracer if tracer is not None else get_tracer()
-        # Opt-in profiling mode (--profile-kernels): fences each launch with
-        # block_until_ready so the span's wall_us measures device work, not
-        # dispatch. NEVER on by default — the async hot path must stay async
-        # (DESIGN §15); the fence lives here on the host side, outside the
-        # jitted *_step bodies (OB-SYNC).
-        self.profile = False
         self.ring_len = ring_len
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -178,18 +171,13 @@ class DeviceStepper:
             self.faults.check_launch("prefill")
         tr = self.tracer
         t0 = tr.clock() if tr.enabled else 0.0
-        w0 = time.perf_counter() if self.profile else 0.0
         logits, self.cache = self._prefill(
             self.params, self.cache, jnp.asarray(tokens),
             jnp.asarray(targets), jnp.asarray(lens))
-        if tr.enabled:
-            args = {"rows": int(tokens.shape[0]),
-                    "bucket": int(tokens.shape[1]),
-                    "real_tokens": int(np.sum(lens))}
-            if self.profile:
-                jax.block_until_ready(logits)  # repro: profiling-fence
-                args["wall_us"] = (time.perf_counter() - w0) * 1e6
-            tr.span("step", "prefill", "engine", t0, **args)
+        if tr.enabled:   # the enqueue only: sample_admitted awaits it
+            tr.span("step", "prefill", "engine", t0,
+                    rows=int(tokens.shape[0]), bucket=int(tokens.shape[1]),
+                    real_tokens=int(np.sum(lens)))
         if self.faults is not None:
             mask = self.faults.poison_mask("prefill", logits.shape[0])
             if mask is not None:
@@ -204,14 +192,33 @@ class DeviceStepper:
         request's re-prefill redraws its identical next token. Also
         returns the rows' non-finite scan ([k] bool ``ok``) — the
         scheduler quarantines rows that fail it."""
-        ok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+        tr = self.tracer
+        t0 = tr.clock() if tr.enabled else 0.0
+        ok = jnp.all(jnp.isfinite(logits), axis=-1)
         if self.temperature == 0.0:
-            return np.asarray(jnp.argmax(logits, axis=-1)), ok
-        keys = engine.fold_slot_keys(self._base_key, jnp.asarray(uids),
-                                     jnp.asarray(counts))
-        return np.asarray(engine.sample_per_slot(
-            logits, keys, temperature=self.temperature,
-            top_k=self.top_k)), ok
+            tok = jnp.argmax(logits, axis=-1)
+        else:
+            keys = engine.fold_slot_keys(self._base_key, jnp.asarray(uids),
+                                         jnp.asarray(counts))
+            tok = engine.sample_per_slot(logits, keys,
+                                         temperature=self.temperature,
+                                         top_k=self.top_k)
+        tok, ok = self._to_host(tok, ok)
+        if tr.enabled:
+            tr.span("step", "sample", "engine", t0, rows=int(len(tok)))
+        return tok, ok
+
+    def _to_host(self, a, b) -> Tuple[np.ndarray, np.ndarray]:
+        """Block until the host holds both device results; the blocking
+        read is the ``wait`` span (host time spent blocked on the
+        device)."""
+        tr = self.tracer
+        if not tr.enabled:
+            return np.asarray(a), np.asarray(b)
+        t0 = tr.clock()
+        out = np.asarray(a), np.asarray(b)
+        tr.span("step", "wait", "engine", t0)
+        return out
 
     def apply_copies(self, copies: Iterable[Tuple[int, int]]) -> None:
         """Apply the scheduler's queued copy-on-write block copies (device
@@ -240,21 +247,18 @@ class DeviceStepper:
             uids, counts = jnp.asarray(uids), jnp.asarray(counts)
         tr = self.tracer
         t0 = tr.clock() if tr.enabled else 0.0
-        w0 = time.perf_counter() if self.profile else 0.0
         tok, ok, self.cache = self._decode(
             self.params, self.cache, jnp.asarray(last_token[:, None]),
             jnp.asarray(pos), tables, uids, counts, jnp.asarray(poison))
+        tok, ok = self._to_host(tok, ok)
         if tr.enabled:
             args = {"batch": int(len(self._no_poison))}
             if table_arr is not None:
                 from repro.serving import paged_cache
                 args["blocks_touched"] = int(
                     np.sum(table_arr != paged_cache.TRASH_BLOCK))
-            if self.profile:
-                jax.block_until_ready(tok)  # repro: profiling-fence
-                args["wall_us"] = (time.perf_counter() - w0) * 1e6
             tr.span("step", "decode", "engine", t0, **args)
-        return np.asarray(tok), np.asarray(ok)
+        return tok, ok
 
     def mixed(self, tokens: np.ndarray, pos: np.ndarray,
               table_arr: np.ndarray, n_tokens: np.ndarray,
@@ -273,21 +277,17 @@ class DeviceStepper:
             poison = self._no_poison
         tr = self.tracer
         t0 = tr.clock() if tr.enabled else 0.0
-        w0 = time.perf_counter() if self.profile else 0.0
         tok, ok, self.cache = self._mixed(
             self.params, self.cache, jnp.asarray(tokens),
             jnp.asarray(pos), jnp.asarray(table_arr),
             jnp.asarray(n_tokens), jnp.asarray(uids),
             jnp.asarray(counts), jnp.asarray(poison))
+        tok, ok = self._to_host(tok, ok)
         if tr.enabled:
-            args = {"batch": int(tokens.shape[0]),
-                    "window": int(tokens.shape[1]),
-                    "real_positions": int(np.sum(n_tokens))}
-            if self.profile:
-                jax.block_until_ready(tok)  # repro: profiling-fence
-                args["wall_us"] = (time.perf_counter() - w0) * 1e6
-            tr.span("step", "mixed", "engine", t0, **args)
-        return np.asarray(tok), np.asarray(ok)
+            tr.span("step", "mixed", "engine", t0,
+                    batch=int(tokens.shape[0]), window=int(tokens.shape[1]),
+                    real_positions=int(np.sum(n_tokens)))
+        return tok, ok
 
     def verify(self, tokens: np.ndarray, pos: np.ndarray,
                table_arr: np.ndarray, draft_lens: np.ndarray,
@@ -302,18 +302,14 @@ class DeviceStepper:
             self.faults.check_launch("verify")
         tr = self.tracer
         t0 = tr.clock() if tr.enabled else 0.0
-        w0 = time.perf_counter() if self.profile else 0.0
         tgt, n_acc, self.cache = self._verify(
             self.params, self.cache, jnp.asarray(tokens),
             jnp.asarray(pos), jnp.asarray(table_arr),
             jnp.asarray(draft_lens), jnp.asarray(uids),
             jnp.asarray(counts))
+        tgt, n_acc = self._to_host(tgt, n_acc)
         if tr.enabled:
-            args = {"batch": int(tokens.shape[0]),
-                    "window": int(tokens.shape[1]),
-                    "drafted": int(np.sum(draft_lens))}
-            if self.profile:
-                jax.block_until_ready(tgt)  # repro: profiling-fence
-                args["wall_us"] = (time.perf_counter() - w0) * 1e6
-            tr.span("step", "verify", "engine", t0, **args)
-        return np.asarray(tgt), np.asarray(n_acc)
+            tr.span("step", "verify", "engine", t0,
+                    batch=int(tokens.shape[0]), window=int(tokens.shape[1]),
+                    drafted=int(np.sum(draft_lens)))
+        return tgt, n_acc

@@ -29,6 +29,7 @@ from repro.obs import profile as obs_profile
 from repro.obs.metrics import MetricsRegistry, Reservoir
 from repro.obs.trace import TraceRecord, Tracer, get_tracer
 from repro.serving import api, faults, loadgen
+from repro.serving.config import SchedulerConfig, ServeConfig
 from repro.serving.scheduler import SchedulerMetrics
 
 
@@ -99,12 +100,15 @@ def test_tracer_virtual_clock_and_span_defaults():
 
 def test_tracer_off_is_never_invoked(model, monkeypatch):
     """Overhead guard: with tracing off, the serving stack never calls into
-    the tracer's emission surface — the hot path pays one flag check."""
+    the tracer's emission surface or reads its clock — the hot path pays
+    one flag check. Covers bucketed admission, chunked admission and
+    preemption requeues."""
     def _boom(*a, **k):
         raise AssertionError("tracer emission with tracing off")
 
     monkeypatch.setattr(Tracer, "event", _boom)
     monkeypatch.setattr(Tracer, "span", _boom)
+    monkeypatch.setattr(get_tracer(), "clock", _boom)
     assert not get_tracer().enabled
     params, cfg = model
     server = api.StreamingServer(params, cfg, n_slots=2, max_len=32,
@@ -117,6 +121,103 @@ def test_tracer_off_is_never_invoked(model, monkeypatch):
             max_new_tokens=4))
     responses = server.run_until_drained()
     assert len(responses) == 3
+    for chunked in (False, True):
+        server = _small_pool_server(model, chunked=chunked)
+        assert len(server.run_until_drained()) == 6
+        assert server.metrics.preemptions > 0
+
+
+class _Ticks:
+    """A clock that advances one unit per read: every stamp is distinct, so
+    nesting and order checks are strict."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+def _small_pool_server(model, *, chunked=False, tracer=None):
+    """A paged server whose pool is too small for its six requests, so
+    some are preempted and requeued."""
+    params, cfg = model
+    sc = (SchedulerConfig(n_slots=3, max_len=48, chunked_prefill=True,
+                          chunk_size=4, chunk_budget=8) if chunked
+          else SchedulerConfig(n_slots=3, max_len=48))
+    server = api.StreamingServer(params, cfg, config=ServeConfig(
+        scheduler=sc, cache_kind="paged", block_size=4, n_blocks=12),
+        tracer=tracer)
+    rng = np.random.default_rng(0)
+    for _ in range(6):
+        server.submit(api.GenerationRequest(
+            prompt=rng.integers(0, cfg.vocab, 8).astype(np.int64),
+            max_new_tokens=16))
+    return server
+
+
+def _inside(inner, outer):
+    return outer.ts <= inner.ts and inner.ts + inner.dur <= outer.ts + \
+        outer.dur
+
+
+def test_step_span_tree_of_a_drained_server(model):
+    """The serving step is a span tree: one ``step`` per counted step,
+    every ``wait`` inside a ``sample``/``decode`` inside its ``step``, one
+    ``queue`` span per admission (requeues included) ending before that
+    admission, and a ``stream`` span after each step — all on the tracer's
+    own clock."""
+    clock = _Ticks()
+    tracer = Tracer().enable(clock)
+    server = _small_pool_server(model, tracer=tracer)
+    server.run_until_drained()
+    m = server.metrics
+    assert m.preemptions > 0
+    recs = tracer.records()
+    assert tracer.dropped == 0
+    assert all(0 < r.ts and r.ts + r.dur <= clock.t for r in recs)
+    assert not [r for r in recs if r.name == "tick"]
+    spans = {}
+    for r in recs:
+        if r.kind == "span":
+            spans.setdefault(r.name, []).append(r)
+    steps = sorted(spans["step"], key=lambda r: r.ts)
+    assert len(steps) == m.steps
+    assert [r.args["step"] for r in steps] == list(range(1, m.steps + 1))
+    assert set(steps[0].args) == {"step", "active", "finished", "queue",
+                                  "admit_launches", "blocks_in_use",
+                                  "degradation"}
+    assert sum(r.args["admit_launches"] for r in steps) == m.prefill_calls
+    parents = spans["sample"] + spans["decode"]
+    assert len(spans["wait"]) == len(parents)
+    for w in spans["wait"]:
+        (p,) = [p for p in parents if _inside(w, p)]
+        assert sum(_inside(p, st) for st in steps) == 1
+    for name in ("admit", "stage", "commit", "prefill", "sample"):
+        for r in spans[name]:
+            assert sum(_inside(r, st) for st in steps) == 1, name
+    assert "wall_us" not in {k for r in spans["decode"] for k in r.args}
+    # queue stays: one per admission, preemption requeues included
+    admits = [r for r in recs if r.kind == "event" and r.name == "admit"]
+    assert len(spans["queue"]) == len(admits) == m.admitted
+    assert len(admits) > len({r.args["uid"] for r in admits})
+    for uid in {r.args["uid"] for r in admits}:
+        qs = [q for q in spans["queue"] if q.args["uid"] == uid]
+        ads = [a for a in admits if a.args["uid"] == uid]
+        assert len(qs) == len(ads)
+        for q, a in zip(sorted(qs, key=lambda r: r.ts),
+                        sorted(ads, key=lambda r: r.ts)):
+            assert q.ts + q.dur < a.ts
+        assert [q.args["resume"] > 0 for q in qs] == \
+            [False] + [True] * (len(qs) - 1)
+    # the token callbacks follow each step
+    streams = sorted(spans["stream"], key=lambda r: r.ts)
+    assert len(streams) == len(steps)
+    for st, sm in zip(steps, streams):
+        assert sm.ts > st.ts + st.dur
+    assert sum(r.args["tokens"] for r in streams) == sum(
+        r.args["tokens"] for r in recs if r.name == "finish")
 
 
 # -- replay determinism (the timeline half of the CI latency contract) -------
