@@ -130,29 +130,22 @@ class VmemBreakdown:
         return max(self.main_bytes, self.reduce_bytes)
 
 
-def schedule_vmem_breakdown(m_tb: int, k_tb: int, n_tb: int, split_k: int, *,
-                            max_nnz: int, group: int = 1,
-                            b_dtype_bytes: int = 4,
-                            out_dtype_bytes: int = 4) -> VmemBreakdown:
-    """Model the VMEM-resident bytes of one LSCD SpMM launch.
+#: Most K tiles one LSCD grid step expands (DESIGN.md §4). Past ~8 the
+#: per-step cost is spread thin (8 and 16 time within 0.5% of each other on
+#: a v5e at OPT-30B's widths); at 16 the d-tile word and B blocks stay
+#: ~2 MB of VMEM.
+MAX_TILES_PER_STEP = 16
 
-    Mirrors the BlockSpecs in ``kernels/spmm.py`` exactly: the A stream is
-    one tile's packed words ``[slots, k_tb]`` (uint32, ``slots * k_tb ==
-    max_nnz``; VMEM pads the lane dim to 128), the expansion builds one
-    dense ``[m_tb, k_tb]`` f32 tile plus its B-dtype copy, B is a
-    ``[k_tb, n_tb]`` block, the output block is ``[group, m_tb, n_tb]`` (f32 partials
-    ``[1, (group,) m_tb, n_tb]`` for split-K pass 1), the accumulator
-    scratch is f32 ``[group, m_tb, n_tb]``. In/out blocks are charged at
-    ``DOUBLE_BUFFER`` x for the grid pipeline; scratch at 1x. For split-K
-    the reduce kernel's ``[split_k, group, m_tb, n_tb]`` f32 input block is
-    modeled too and the reported total is the max of the two launches.
-    """
+
+def _vmem_breakdown(d: int, m_tb: int, k_tb: int, n_tb: int, split_k: int,
+                    max_nnz: int, group: int, b_dtype_bytes: int,
+                    out_dtype_bytes: int) -> VmemBreakdown:
     g = max(1, group)
     slots = -(-max_nnz // k_tb)
     lanes = -(-k_tb // LANE_WIDTH) * LANE_WIDTH
-    words = 4 * slots * lanes * DOUBLE_BUFFER
+    words = 4 * d * slots * lanes * DOUBLE_BUFFER
     expand = m_tb * k_tb * (4 + b_dtype_bytes)
-    b_blk = k_tb * n_tb * b_dtype_bytes * DOUBLE_BUFFER
+    b_blk = d * k_tb * n_tb * b_dtype_bytes * DOUBLE_BUFFER
     # split-K pass 1 writes one f32 partials slice [1,(g,)m_tb,n_tb];
     # the fused kernel writes the final [g,m_tb,n_tb] in out_dtype.
     out_elem = 4 if split_k > 1 else out_dtype_bytes
@@ -165,6 +158,58 @@ def schedule_vmem_breakdown(m_tb: int, k_tb: int, n_tb: int, split_k: int, *,
                     + g * m_tb * n_tb * out_dtype_bytes * DOUBLE_BUFFER
                     + bias)
     return VmemBreakdown(words, b_blk, out_blk, bias, acc, expand, reduce_b)
+
+
+def tiles_per_step(k_tiles: int, split_k: int, *, m_tb: int, k_tb: int,
+                   n_tb: int, max_nnz: int, group: int = 1,
+                   b_dtype_bytes: int = 4, out_dtype_bytes: int = 4) -> int:
+    """K tiles ``d`` that one LSCD grid step covers.
+
+    The largest divisor of both ``k_tiles`` and the slice's tile count
+    (``k_tiles``, or ``ceil(k_tiles / split_k)`` for split-K) that is at
+    most :data:`MAX_TILES_PER_STEP` and keeps the launch inside the pallas
+    VMEM budget; 1 when no larger one does. Dividing ``k_tiles`` makes every
+    K block wholly real or wholly past the end of K, so a ragged split-K
+    slice never mixes real tiles with clamped reads. The kernels and
+    :func:`schedule_vmem_breakdown` both read ``d`` from here.
+    """
+    chunk = -(-k_tiles // split_k)
+    budget = budgets.vmem_budget("pallas")
+    for d in range(min(MAX_TILES_PER_STEP, chunk), 1, -1):
+        if k_tiles % d or chunk % d:
+            continue
+        bd = _vmem_breakdown(d, m_tb, k_tb, n_tb, split_k, max_nnz, group,
+                             b_dtype_bytes, out_dtype_bytes)
+        if bd.total_bytes <= budget:
+            return d
+    return 1
+
+
+def schedule_vmem_breakdown(m_tb: int, k_tb: int, n_tb: int, split_k: int, *,
+                            k_tiles: int, max_nnz: int, group: int = 1,
+                            b_dtype_bytes: int = 4,
+                            out_dtype_bytes: int = 4) -> VmemBreakdown:
+    """Model the VMEM-resident bytes of one LSCD SpMM launch.
+
+    Mirrors the BlockSpecs in ``kernels/spmm.py`` exactly: a grid step
+    covers ``d`` K tiles (:func:`tiles_per_step`), so the A stream is ``d``
+    tiles' packed words ``[d, slots, k_tb]`` (uint32, ``slots * k_tb ==
+    max_nnz``; VMEM pads the lane dim to 128) and B a ``[d * k_tb, n_tb]``
+    block; the expansion builds one dense ``[m_tb, k_tb]`` f32 tile at a
+    time plus its B-dtype copy; the output block is ``[group, m_tb, n_tb]``
+    (f32 partials ``[1, (group,) m_tb, n_tb]`` for split-K pass 1), the
+    accumulator scratch is f32 ``[group, m_tb, n_tb]``. In/out blocks are
+    charged at ``DOUBLE_BUFFER`` x for the grid pipeline; scratch at 1x.
+    For split-K the reduce kernel's ``[split_k, group, m_tb, n_tb]`` f32
+    input block is modeled too and the reported total is the max of the
+    two launches.
+    """
+    d = tiles_per_step(k_tiles, split_k, m_tb=m_tb, k_tb=k_tb, n_tb=n_tb,
+                       max_nnz=max_nnz, group=group,
+                       b_dtype_bytes=b_dtype_bytes,
+                       out_dtype_bytes=out_dtype_bytes)
+    return _vmem_breakdown(d, m_tb, k_tb, n_tb, split_k, max_nnz, group,
+                           b_dtype_bytes, out_dtype_bytes)
 
 
 def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
@@ -217,7 +262,8 @@ def check_schedule(m: int, k: int, n: int, *, m_tb: int, k_tb: int,
                 m_tb, k_tb, sparsity,
                 columns=max(1, group) * -(-m // m_tb) * kt * k_tb)
         bd = schedule_vmem_breakdown(
-            m_tb, k_tb, n_tb, split_k, group=group, max_nnz=max_nnz,
+            m_tb, k_tb, n_tb, split_k, k_tiles=kt, group=group,
+            max_nnz=max_nnz,
             b_dtype_bytes=b_dtype_bytes,
             out_dtype_bytes=out_dtype_bytes)
         if bd.total_bytes > budget:

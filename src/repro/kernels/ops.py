@@ -22,6 +22,7 @@ apply.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Literal
 
 import jax
@@ -42,7 +43,7 @@ def _on_tpu() -> bool:
 
 
 def _pick_schedule(t: tiled_csl.TiledCSL, n: int, backend: str,
-                   n_tb: int | None, split_k: int | None,
+                   n_tb: int | None, split_k: int | None, b_dtype, out_dtype,
                    kind: str = "spmm") -> schedule_mod.Schedule:
     # Sparsity comes from static metadata only (the true nnz sum is a
     # device value and must not be read under jit); the shared helper keeps
@@ -52,15 +53,20 @@ def _pick_schedule(t: tiled_csl.TiledCSL, n: int, backend: str,
         t.shape[0], t.shape[1], n, sparsity,
         m_tb=t.m_tb, k_tb=t.k_tb, n_tb=n_tb, split_k=split_k,
         group=t.group or 1, max_nnz=t.max_nnz, backend=backend)
-    _note_launch(kind, t, n, sparsity, backend, sched)
+    _note_launch(kind, t, n, sparsity, backend, sched, b_dtype, out_dtype)
     return sched
 
 
 def _note_launch(kind: str, t: tiled_csl.TiledCSL, n: int, sparsity: float,
-                 backend: str, sched: schedule_mod.Schedule) -> None:
+                 backend: str, sched: schedule_mod.Schedule, b_dtype,
+                 out_dtype) -> None:
     """Observability hook at the dispatch site (runs at jit-trace time, so
     once per compiled shape — an honest granularity under jit: per-call
-    wall timing needs the fenced profiling mode, obs/profile.py)."""
+    wall timing needs the fenced profiling mode, obs/profile.py).
+
+    The ``kernel`` event carries the compute kernel's ``grid_steps`` and
+    the K tiles each step expands (``tiles_per_step``; 1 means the launch
+    pays the per-step cost once per tile)."""
     prof = obs_profile.active()
     tr = obs_trace.get_tracer()
     if prof is None and not tr.enabled:
@@ -73,10 +79,16 @@ def _note_launch(kind: str, t: tiled_csl.TiledCSL, n: int, sparsity: float,
     if tr.enabled:
         terms = schedule_mod.predicted(m, k, n, sparsity, sched,
                                        group=group, max_nnz=t.max_nnz)
+        n_pad = -(-n // sched.n_tb) * sched.n_tb
+        grid, d = spmm_mod.launch_grid(
+            t, n_pad, n_tb=sched.n_tb, split_k=sched.split_k,
+            b_dtype=b_dtype, out_dtype=out_dtype)
         tr.event("kernel", f"{kind} {m}x{k}x{n}", "kernel",
                  backend=backend, schedule=sched.as_dict(), group=group,
                  sparsity=round(float(sparsity), 4),
-                 predicted_us=terms.effective_s * 1e6)
+                 predicted_us=terms.effective_s * 1e6,
+                 tiles_per_step=d,
+                 grid_steps=sched.split_k * math.prod(grid))
 
 
 def spmm(t: tiled_csl.TiledCSL,
@@ -116,7 +128,8 @@ def spmm(t: tiled_csl.TiledCSL,
                                 bias=bias)
 
     n = b.shape[1]
-    sched = _pick_schedule(t, n, backend, n_tb, split_k, kind="spmm")
+    sched = _pick_schedule(t, n, backend, n_tb, split_k, b.dtype, out_dtype,
+                           kind="spmm")
     n_pad = -(-n // sched.n_tb) * sched.n_tb
     if n_pad != n:
         b = jnp.pad(b, ((0, 0), (0, n_pad - n)))
@@ -160,7 +173,7 @@ def spmm_grouped(t: tiled_csl.TiledCSL,
                                         epilogue=epilogue, bias=bias)
 
     n = b.shape[1]
-    sched = _pick_schedule(t, n, backend, n_tb, split_k,
+    sched = _pick_schedule(t, n, backend, n_tb, split_k, b.dtype, out_dtype,
                            kind="spmm_grouped")
     n_pad = -(-n // sched.n_tb) * sched.n_tb
     if n_pad != n:

@@ -7,7 +7,8 @@ re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
 
 * **Load-as-Sparse**: the only A traffic is the compressed ``words`` block —
   ``uint32[slots, k_tb]`` per (m, k) tile, column-slotted (DESIGN.md §2) —
-  streamed HBM→VMEM by the Pallas grid pipeline. This is the paper's
+  streamed HBM→VMEM by the Pallas grid pipeline, ``d`` consecutive K tiles
+  of one M-tile row per grid step (:func:`launch_grid`). This is the paper's
   ``gmem2reg`` + the reduced-footprint insight.
 * **Sparse→Dense transform** (:func:`_expand_tile`): the dense tile is
   built on the VPU by one compare-select per slot. Slot ``r`` is a row of
@@ -19,7 +20,7 @@ re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
   never match, so the inner loop needs no bound (the paper needs Alg.2's
   ``nnz_thread``).
 * **Compute-as-Dense**: a full ``(M_TB, K_TB) @ (K_TB, N_TB)`` MXU matmul per
-  grid step in B's dtype, ``preferred_element_type=f32`` — redundant FLOPs
+  tile in B's dtype, ``preferred_element_type=f32`` — redundant FLOPs
   tolerated because the op is memory-bound (paper §3.2.2); the tile's
   values are bf16, so casting the expanded tile to a bf16 B loses nothing.
 * **Two-level overlap** (paper §4.2): inter-iteration double buffering is the
@@ -30,6 +31,12 @@ re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
   array rides in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch and
   gates an all-zero-tile fast path (``pl.when(nnz > 0)``) — a beyond-paper
   micro-optimisation that exactness of padding makes free.
+* **Several K tiles per grid step**: each step expands and multiplies
+  ``d`` tiles in K order (:func:`_block_dot`), each behind its own nnz
+  gate, into the same f32 accumulator — the association of a
+  one-tile-per-step loop, bit for bit — so the per-step cost (pipeline
+  bookkeeping, DMA issue and wait) is paid once per ``d`` tiles. ``d`` is
+  read off the launch shape (``contracts.tiles_per_step``).
 
 Two beyond-paper fusions remove the pointwise HBM round-trips the model
 stack otherwise pays after every projection (DESIGN.md §8):
@@ -51,14 +58,14 @@ stack otherwise pays after every projection (DESIGN.md §8):
 Grids (DESIGN.md §4, §9):
 
 * **Single-pass** (``lscd_spmm`` / ``lscd_spmm_grouped``):
-  ``(Mt, Nt, Kt[, G])`` with K (then G) innermost ("arbitrary" semantics);
-  the f32 accumulator lives in VMEM scratch and is flushed — bias +
-  epilogue applied, one cast — at ``k == Kt-1`` (last group for binary
-  epilogues).
+  ``(Mt, Nt, Kt/d[, G])`` with K (then G) innermost ("arbitrary"
+  semantics); the f32 accumulator lives in VMEM scratch and is flushed —
+  bias + epilogue applied, one cast — at the last K block (last group for
+  binary epilogues).
 * **Split-K** (``lscd_spmm_splitk`` / ``lscd_spmm_splitk_grouped``, paper
   §4.4's global-reduction splitting re-derived for the skinny decode
   regime): a leading *parallel* split dimension partitions the Kt tiles,
-  ``(S, Mt, Nt, ceil(Kt/S)[, G])``; each slice accumulates its K-range in
+  ``(S, Mt, Nt, ceil(Kt/S)/d[, G])``; each slice accumulates its K-range in
   VMEM scratch and writes an f32 partials block ``[S,(G,) M, N]``, and a
   second lightweight reduce kernel (grid ``(Mt, Nt)``) sums the S partials
   and applies bias + epilogue at the final flush. Partials stay f32 end to
@@ -148,20 +155,21 @@ def epilogue_kind(name: str, *, groups: int = 1) -> str:
     raise ValueError(f"unknown epilogue {name!r}; known: {known}")
 
 
-def _expand_tile(words_ref, m_tb: int, dtype) -> jax.Array:
-    """Column-slotted words ``[slots, k_tb]`` → dense ``[m_tb, k_tb]`` tile.
+def _expand_tile(words_ref, tile, m_tb: int, dtype) -> jax.Array:
+    """Tile ``tile`` of the column-slotted words block ``[d, slots, k_tb]`` →
+    dense ``[m_tb, k_tb]`` tile.
 
     Reads the slots in 8-row slabs (one uint32 vreg of sublanes); each slot
     row broadcasts down the tile and selects its value where its row field
     equals the element's row. An element takes at most one slot's value
     (rows are unique within a column), so the selects chain without adds.
     """
-    slots, k_tb = words_ref.shape
+    _, slots, k_tb = words_ref.shape
     q = tiled_csl.SLOT_QUANTUM
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (m_tb, k_tb), 0)
 
     def slab(i, a):
-        w = words_ref[pl.ds(pl.multiple_of(i * q, q), q), :]
+        w = words_ref[tile, pl.ds(pl.multiple_of(i * q, q), q), :]
         rows = (w & 0xFFFF).astype(jnp.int32)
         # The bf16 value sits in the high half: masking the row field off
         # leaves exactly that value as an f32 bit pattern.
@@ -176,45 +184,82 @@ def _expand_tile(words_ref, m_tb: int, dtype) -> jax.Array:
     return a.astype(dtype)
 
 
-def _tile_dot(words_ref, b_ref, m_tb: int) -> jax.Array:
-    """One grid step's contribution: expand the tile, MXU matmul, f32."""
-    a = _expand_tile(words_ref, m_tb, b_ref.dtype)
-    return jnp.dot(a, b_ref[...], preferred_element_type=jnp.float32)
+def _block_dot(words_ref, b_ref, m_tb: int, tile_nnz, add) -> None:
+    """One grid step's contribution, tile by tile in K order: tile ``j`` of
+    the ``d``-tile block is expanded and multiplied by its ``k_tb`` rows of
+    B when ``tile_nnz(j) > 0``, and its f32 product handed to ``add``.
+
+    The loop is unrolled (``d`` is at most ``contracts.MAX_TILES_PER_STEP``):
+    no loop bookkeeping between tiles, and constant tile offsets. Unrolled
+    through ``fori_loop`` the body is traced once and lowered ``d`` times,
+    half the trace-and-lower time of a Python loop; that time is paid at
+    every process start, compile cache or not.
+    """
+    d, _, k_tb = words_ref.shape
+
+    def tile(j, carry):
+        @pl.when(tile_nnz(j) > 0)
+        def _body():
+            # sparse -> dense transform (paper Fig.6b; VPU compare-select),
+            # then compute-as-dense (MXU)
+            a = _expand_tile(words_ref, j, m_tb, b_ref.dtype)
+            rows = pl.ds(pl.multiple_of(j * k_tb, k_tb), k_tb)
+            add(jnp.dot(a, b_ref[rows, :],
+                        preferred_element_type=jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, d, tile, 0, unroll=True)
 
 
-def _words_spec(t: tiled_csl.TiledCSL, index_map) -> pl.BlockSpec:
-    """One tile's ``[slots, k_tb]`` word block; leading axes squeezed.
-    The block's two minor dims are the array's own, which Mosaic accepts
-    at any size."""
-    lead = t.words.ndim - 2
-    return pl.BlockSpec((pl.squeezed,) * lead + (t.slots, t.k_tb), index_map)
+def launch_grid(t: tiled_csl.TiledCSL, n: int, *, n_tb: int, split_k: int,
+                b_dtype, out_dtype) -> tuple[tuple[int, ...], int]:
+    """The compute kernel's grid for one K slice of this launch, ``(Mt, Nt,
+    ceil(Kt/S)/d[, G])``, and the K tiles ``d`` each of its steps covers.
+    The split-K entries prepend the ``S`` axis; ``n`` is already padded to
+    ``n_tb``."""
+    mt, kt = t.grid
+    d = contracts.tiles_per_step(
+        kt, split_k, m_tb=t.m_tb, k_tb=t.k_tb, n_tb=n_tb,
+        max_nnz=t.max_nnz, group=t.group or 1,
+        b_dtype_bytes=jnp.dtype(b_dtype).itemsize,
+        out_dtype_bytes=jnp.dtype(out_dtype).itemsize)
+    grid = (mt, n // n_tb, _splitk_chunk(kt, split_k) // d)
+    return grid + ((t.group,) if t.group is not None else ()), d
+
+
+def _words_spec(t: tiled_csl.TiledCSL, d: int, index_map) -> pl.BlockSpec:
+    """``d`` consecutive tiles' ``[d, slots, k_tb]`` word block of one
+    (group,) M-tile row; leading axes squeezed. The block's two minor dims
+    are the array's own, which Mosaic accepts at any size."""
+    lead = t.words.ndim - 3
+    return pl.BlockSpec((pl.squeezed,) * lead + (d, t.slots, t.k_tb),
+                        index_map)
 
 
 def _lscd_spmm_kernel(nnz_ref,            # SMEM int32[Mt, Kt] (scalar prefetch)
-                      words_ref,          # VMEM uint32[slots, K_TB]
-                      b_ref,              # VMEM bf16/f32[K_TB, N_TB]
+                      words_ref,          # VMEM uint32[d, slots, K_TB]
+                      b_ref,              # VMEM bf16/f32[d*K_TB, N_TB]
                       o_ref,              # VMEM out[M_TB, N_TB]
                       acc_ref,            # VMEM scratch f32[M_TB, N_TB]
                       *,
                       m_tb: int,
-                      k_tiles: int,
+                      k_blocks: int,
                       epilogue: str = "none",
                       bias_ref=None):
-    m, k = pl.program_id(0), pl.program_id(2)
+    m, kb = pl.program_id(0), pl.program_id(2)
+    d = words_ref.shape[0]
 
-    @pl.when(k == 0)
+    @pl.when(kb == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    nnz = nnz_ref[m, k]
+    def _add(contrib):
+        acc_ref[...] += contrib
 
-    @pl.when(nnz > 0)
-    def _body():
-        # sparse -> dense transform (paper Fig.6b; VPU compare-select),
-        # then compute-as-dense (MXU)
-        acc_ref[...] += _tile_dot(words_ref, b_ref, m_tb)
+    _block_dot(words_ref, b_ref, m_tb, lambda j: nnz_ref[m, kb * d + j],
+               _add)
 
-    @pl.when(k == k_tiles - 1)
+    @pl.when(kb == k_blocks - 1)
     def _flush():
         # Fused epilogue: bias + activation applied to the f32 accumulator in
         # VMEM before the HBM write-back — the pervasive linear->activation
@@ -247,23 +292,22 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
     epilogue_kind(epilogue)  # raises on unknown / binary names
     m, k = t.shape
     n = b.shape[1]
-    mt, kt = t.grid
     if b.shape[0] != k:
         raise ValueError(f"B rows {b.shape[0]} != K {k}")
     if n % n_tb:
         raise ValueError(f"N={n} not a multiple of n_tb={n_tb}")
     _require_launch(t, n, n_tb, 1, interpret, b.dtype, out_dtype)
-    nt = n // n_tb
+    grid, d = launch_grid(t, n, n_tb=n_tb, split_k=1, b_dtype=b.dtype,
+                          out_dtype=out_dtype)
 
-    grid = (mt, nt, kt)
     in_specs = [
-        # Compressed A tile: the ONLY A traffic (load-as-sparse).
-        _words_spec(t, lambda m_, n_, k_, nnz: (m_, k_, 0, 0)),
-        # Dense activation tile.
-        pl.BlockSpec((t.k_tb, n_tb), lambda m_, n_, k_, nnz: (k_, n_)),
+        # d compressed A tiles: the ONLY A traffic (load-as-sparse).
+        _words_spec(t, d, lambda m_, n_, k_, nnz: (m_, k_, 0, 0)),
+        # The d tiles' rows of the dense activation.
+        pl.BlockSpec((d * t.k_tb, n_tb), lambda m_, n_, k_, nnz: (k_, n_)),
     ]
     args = [t.nnz, t.words, b]
-    body = dict(m_tb=t.m_tb, k_tiles=kt, epilogue=epilogue)
+    body = dict(m_tb=t.m_tb, k_blocks=grid[2], epilogue=epilogue)
     if bias is None:
         kernel = functools.partial(_lscd_spmm_kernel, bias_ref=None, **body)
     else:
@@ -291,10 +335,10 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
 
 
 def _lscd_spmm_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref, o_ref,
-                           acc_ref, *, m_tb, k_tiles, epilogue):
+                           acc_ref, *, m_tb, k_blocks, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
     _lscd_spmm_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
-                      m_tb=m_tb, k_tiles=k_tiles,
+                      m_tb=m_tb, k_blocks=k_blocks,
                       epilogue=epilogue, bias_ref=bias_ref)
 
 
@@ -302,40 +346,41 @@ def _lscd_spmm_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref, o_ref,
 # grouped LSCD SpMM: G same-shape weights, one launch, B streamed once
 # ---------------------------------------------------------------------------
 
+def _add_group(acc_ref, g, groups: int, contrib) -> None:
+    """``acc_ref[g] += contrib`` as static-index stores (unrolled over the
+    small G) — no dynamic VMEM indexing in the inner loop."""
+    for gi in range(groups):
+        @pl.when(g == gi)
+        def _store(gi=gi):
+            acc_ref[gi] += contrib
+
+
 def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
-                              words_ref,  # VMEM uint32[slots, K_TB]
-                              b_ref,      # VMEM bf16/f32[K_TB, N_TB]
+                              words_ref,  # VMEM uint32[d, slots, K_TB]
+                              b_ref,      # VMEM bf16/f32[d*K_TB, N_TB]
                               o_ref,      # VMEM out[G, M_TB, N_TB] (unary)
                                           #      or [M_TB, N_TB]   (binary)
                               acc_ref,    # VMEM scratch f32[G, M_TB, N_TB]
                               *,
                               m_tb: int,
-                              k_tiles: int,
+                              k_blocks: int,
                               groups: int,
                               epilogue: str = "none",
                               bias_ref=None):
-    m, k, g = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    m, kb, g = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    d = words_ref.shape[0]
     binary = epilogue in _BINARY_EPILOGUES
 
     # g is innermost: for a fixed (m, n) the visit order is
-    # (k=0, g=0..G-1), (k=1, g=0..G-1), ... — every accumulator slot takes
-    # its first contribution during the k==0 sweep, so one zeroing of the
-    # whole scratch at (k==0, g==0) suffices.
-    @pl.when((k == 0) & (g == 0))
+    # (kb=0, g=0..G-1), (kb=1, g=0..G-1), ... — every accumulator slot takes
+    # its first contribution during the kb==0 sweep, so one zeroing of the
+    # whole scratch at (kb==0, g==0) suffices.
+    @pl.when((kb == 0) & (g == 0))
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    nnz = nnz_ref[g, m, k]
-
-    @pl.when(nnz > 0)
-    def _body():
-        contrib = _tile_dot(words_ref, b_ref, m_tb)
-        # Static-index stores (unrolled over the small G) — no dynamic VMEM
-        # indexing in the inner loop.
-        for gi in range(groups):
-            @pl.when(g == gi)
-            def _store(gi=gi):
-                acc_ref[gi] += contrib
+    _block_dot(words_ref, b_ref, m_tb, lambda j: nnz_ref[g, m, kb * d + j],
+               functools.partial(_add_group, acc_ref, g, groups))
 
     def _biased(gi, acc):
         if bias_ref is not None:
@@ -344,13 +389,13 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
 
     if binary:
         # One C-sized write-back for the whole group pair (SwiGLU/GeGLU).
-        @pl.when((k == k_tiles - 1) & (g == groups - 1))
+        @pl.when((kb == k_blocks - 1) & (g == groups - 1))
         def _flush_binary():
             out = _BINARY_EPILOGUES[epilogue](_biased(0, acc_ref[0]),
                                               _biased(1, acc_ref[1]))
             o_ref[...] = out.astype(o_ref.dtype)
     else:
-        @pl.when(k == k_tiles - 1)
+        @pl.when(kb == k_blocks - 1)
         def _flush():
             for gi in range(groups):
                 @pl.when(g == gi)
@@ -360,11 +405,11 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
 
 
 def _lscd_spmm_grouped_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref,
-                                   o_ref, acc_ref, *, m_tb, k_tiles,
+                                   o_ref, acc_ref, *, m_tb, k_blocks,
                                    groups, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
     _lscd_spmm_grouped_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
-                              m_tb=m_tb, k_tiles=k_tiles,
+                              m_tb=m_tb, k_blocks=k_blocks,
                               groups=groups, epilogue=epilogue,
                               bias_ref=bias_ref)
 
@@ -399,24 +444,25 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
     kind = epilogue_kind(epilogue, groups=groups)
     m, k = t.shape
     n = b.shape[1]
-    mt, kt = t.grid
     if b.shape[0] != k:
         raise ValueError(f"B rows {b.shape[0]} != K {k}")
     if n % n_tb:
         raise ValueError(f"N={n} not a multiple of n_tb={n_tb}")
     _require_launch(t, n, n_tb, 1, interpret, b.dtype, out_dtype)
-    nt = n // n_tb
+    grid, d = launch_grid(t, n, n_tb=n_tb, split_k=1, b_dtype=b.dtype,
+                          out_dtype=out_dtype)
 
-    grid = (mt, nt, kt, groups)
     in_specs = [
-        # Group g's compressed A tile (the only A traffic). The B block
+        # Group g's d compressed A tiles (the only A traffic). The B block
         # index is independent of g, so the pipeliner holds B resident
         # across the G inner steps.
-        _words_spec(t, lambda m_, n_, k_, g_, nnz: (g_, m_, k_, 0, 0)),
-        pl.BlockSpec((t.k_tb, n_tb), lambda m_, n_, k_, g_, nnz: (k_, n_)),
+        _words_spec(t, d, lambda m_, n_, k_, g_, nnz: (g_, m_, k_, 0, 0)),
+        pl.BlockSpec((d * t.k_tb, n_tb),
+                     lambda m_, n_, k_, g_, nnz: (k_, n_)),
     ]
     args = [t.nnz, t.words, b]
-    body = dict(m_tb=t.m_tb, k_tiles=kt, groups=groups, epilogue=epilogue)
+    body = dict(m_tb=t.m_tb, k_blocks=grid[2], groups=groups,
+                epilogue=epilogue)
     if bias is None:
         kernel = functools.partial(_lscd_spmm_grouped_kernel, bias_ref=None,
                                    **body)
@@ -463,37 +509,48 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
 
 def _splitk_chunk(kt: int, split_k: int) -> int:
     """K tiles per split slice. The last slice may own fewer real tiles
-    (Kt % S != 0); its out-of-range steps clamp their block index and are
+    (Kt % S != 0); its out-of-range blocks (``d`` divides Kt, so a block is
+    wholly real or wholly past the end) clamp their block index and are
     predicated off via the nnz gate, contributing exact zeros."""
     return -(-kt // split_k)
 
 
+def _splitk_tile_nnz(nnz_row, k_tiles: int, kb, d: int):
+    """Tile ``j`` of global K block ``kb``'s nnz, 0 past the end of K: such
+    tiles read a clamped-index block but are masked off, so the partial
+    stays an exact zero."""
+    def tile_nnz(j):
+        k = kb * d + j
+        return jnp.where(k < k_tiles, nnz_row(jnp.minimum(k, k_tiles - 1)),
+                         0)
+    return tile_nnz
+
+
 def _lscd_spmm_splitk_kernel(nnz_ref,      # SMEM int32[Mt, Kt]
-                             words_ref,    # VMEM uint32[slots, K_TB]
-                             b_ref,        # VMEM bf16/f32[K_TB, N_TB]
+                             words_ref,    # VMEM uint32[d, slots, K_TB]
+                             b_ref,        # VMEM bf16/f32[d*K_TB, N_TB]
                              p_ref,        # VMEM f32[1, M_TB, N_TB] partials
                              acc_ref,      # VMEM scratch f32[M_TB, N_TB]
                              *,
                              m_tb: int,
                              k_tiles: int,
-                             k_chunk: int):
+                             k_blocks: int):
     m, kl = pl.program_id(1), pl.program_id(3)
-    k = pl.program_id(0) * k_chunk + kl    # global K-tile index of this step
+    kb = pl.program_id(0) * k_blocks + kl  # global K-block index of this step
+    d = words_ref.shape[0]
 
     @pl.when(kl == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Steps past the end of K (ragged last slice) read a clamped-index block
-    # but are masked off here — the partial stays an exact zero.
-    nnz = jnp.where(k < k_tiles,
-                    nnz_ref[m, jnp.minimum(k, k_tiles - 1)], 0)
+    def _add(contrib):
+        acc_ref[...] += contrib
 
-    @pl.when(nnz > 0)
-    def _body():
-        acc_ref[...] += _tile_dot(words_ref, b_ref, m_tb)
+    _block_dot(words_ref, b_ref, m_tb,
+               _splitk_tile_nnz(lambda k: nnz_ref[m, k], k_tiles, kb, d),
+               _add)
 
-    @pl.when(kl == k_chunk - 1)
+    @pl.when(kl == k_blocks - 1)
     def _flush_partial():
         # f32 partials, NO epilogue/cast: the single rounding point stays in
         # the reduce kernel's flush.
@@ -524,7 +581,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
                      interpret: bool,
                      epilogue: str = "none",
                      bias: jax.Array | None = None) -> jax.Array:
-    """Split-K kernel entry: grid ``(S, Mt, Nt, ceil(Kt/S))`` + a reduce.
+    """Split-K kernel entry: grid ``(S, Mt, Nt, ceil(Kt/S)/d)`` + a reduce.
 
     Each split slice accumulates its K-tile range into VMEM scratch and
     writes one f32 partials block; the reduce kernel (grid ``(Mt, Nt)``)
@@ -538,7 +595,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
     epilogue_kind(epilogue)
     m, k = t.shape
     n = b.shape[1]
-    mt, kt = t.grid
+    kt = t.grid[1]
     if b.shape[0] != k:
         raise ValueError(f"B rows {b.shape[0]} != K {k}")
     if n % n_tb:
@@ -546,21 +603,22 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
     # KC-SPLIT and the rest of the launch contract (VMEM footprint of both
     # the partials and the reduce launch) in one shared predicate.
     _require_launch(t, n, n_tb, split_k, interpret, b.dtype, out_dtype)
-    nt = n // n_tb
-    k_chunk = _splitk_chunk(kt, split_k)
+    grid, d = launch_grid(t, n, n_tb=n_tb, split_k=split_k, b_dtype=b.dtype,
+                          out_dtype=out_dtype)
+    k_blocks = grid[2]
 
     kernel = functools.partial(
-        _lscd_spmm_splitk_kernel, m_tb=t.m_tb, k_tiles=kt, k_chunk=k_chunk)
-    k_ix = lambda s_, kl_: jnp.minimum(s_ * k_chunk + kl_, kt - 1)
+        _lscd_spmm_splitk_kernel, m_tb=t.m_tb, k_tiles=kt, k_blocks=k_blocks)
+    k_ix = lambda s_, kl_: jnp.minimum(s_ * k_blocks + kl_, kt // d - 1)
     partials = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(split_k, mt, nt, k_chunk),
+            grid=(split_k,) + grid,
             in_specs=[
-                _words_spec(t, lambda s_, m_, n_, kl_, nnz:
+                _words_spec(t, d, lambda s_, m_, n_, kl_, nnz:
                             (m_, k_ix(s_, kl_), 0, 0)),
-                pl.BlockSpec((t.k_tb, n_tb),
+                pl.BlockSpec((d * t.k_tb, n_tb),
                              lambda s_, m_, n_, kl_, nnz: (k_ix(s_, kl_),
                                                            n_)),
             ],
@@ -588,7 +646,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
         args.append(bias.reshape(m, 1).astype(jnp.float32))
     return pl.pallas_call(
         red,
-        grid=(mt, nt),
+        grid=grid[:2],
         in_specs=in_specs,
         out_specs=pl.BlockSpec((t.m_tb, n_tb), lambda m_, n_: (m_, n_)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -600,35 +658,29 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
 
 
 def _lscd_spmm_splitk_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
-                                     words_ref,  # VMEM uint32[slots, K_TB]
-                                     b_ref,      # VMEM bf16/f32[K_TB, N_TB]
+                                     words_ref,  # VMEM uint32[d, slots, K_TB]
+                                     b_ref,      # VMEM bf16/f32[d*K_TB, N_TB]
                                      p_ref,      # VMEM f32[1, G, M_TB, N_TB]
                                      acc_ref,    # scratch f32[G, M_TB, N_TB]
                                      *,
                                      m_tb: int,
                                      k_tiles: int,
-                                     k_chunk: int,
+                                     k_blocks: int,
                                      groups: int):
     m = pl.program_id(1)
     kl, g = pl.program_id(3), pl.program_id(4)
-    k = pl.program_id(0) * k_chunk + kl
+    kb = pl.program_id(0) * k_blocks + kl
+    d = words_ref.shape[0]
 
     @pl.when((kl == 0) & (g == 0))
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    nnz = jnp.where(k < k_tiles,
-                    nnz_ref[g, m, jnp.minimum(k, k_tiles - 1)], 0)
+    _block_dot(words_ref, b_ref, m_tb,
+               _splitk_tile_nnz(lambda k: nnz_ref[g, m, k], k_tiles, kb, d),
+               functools.partial(_add_group, acc_ref, g, groups))
 
-    @pl.when(nnz > 0)
-    def _body():
-        contrib = _tile_dot(words_ref, b_ref, m_tb)
-        for gi in range(groups):
-            @pl.when(g == gi)
-            def _store(gi=gi):
-                acc_ref[gi] += contrib
-
-    @pl.when((kl == k_chunk - 1) & (g == groups - 1))
+    @pl.when((kl == k_blocks - 1) & (g == groups - 1))
     def _flush_partial():
         p_ref[0] = acc_ref[...]
 
@@ -663,7 +715,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
                              interpret: bool,
                              epilogue: str = "none",
                              bias: jax.Array | None = None) -> jax.Array:
-    """Grouped split-K entry: grid ``(S, Mt, Nt, ceil(Kt/S), G)`` + reduce.
+    """Grouped split-K entry: grid ``(S, Mt, Nt, ceil(Kt/S)/d, G)`` + reduce.
 
     Semantics match :func:`lscd_spmm_grouped` — C[G, M, N] for unary
     epilogues (bias [G, M] applied per group), C[M, N] for binary ones —
@@ -677,29 +729,30 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
     kind = epilogue_kind(epilogue, groups=groups)
     m, k = t.shape
     n = b.shape[1]
-    mt, kt = t.grid
+    kt = t.grid[1]
     if b.shape[0] != k:
         raise ValueError(f"B rows {b.shape[0]} != K {k}")
     if n % n_tb:
         raise ValueError(f"N={n} not a multiple of n_tb={n_tb}")
     # KC-SPLIT plus the VMEM contract of the [S, G, m_tb, n_tb] reduce block.
     _require_launch(t, n, n_tb, split_k, interpret, b.dtype, out_dtype)
-    nt = n // n_tb
-    k_chunk = _splitk_chunk(kt, split_k)
+    grid, d = launch_grid(t, n, n_tb=n_tb, split_k=split_k, b_dtype=b.dtype,
+                          out_dtype=out_dtype)
+    k_blocks = grid[2]
 
     kernel = functools.partial(
         _lscd_spmm_splitk_grouped_kernel, m_tb=t.m_tb, k_tiles=kt,
-        k_chunk=k_chunk, groups=groups)
-    k_ix = lambda s_, kl_: jnp.minimum(s_ * k_chunk + kl_, kt - 1)
+        k_blocks=k_blocks, groups=groups)
+    k_ix = lambda s_, kl_: jnp.minimum(s_ * k_blocks + kl_, kt // d - 1)
     partials = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(split_k, mt, nt, k_chunk, groups),
+            grid=(split_k,) + grid,
             in_specs=[
-                _words_spec(t, lambda s_, m_, n_, kl_, g_, nnz:
+                _words_spec(t, d, lambda s_, m_, n_, kl_, g_, nnz:
                             (g_, m_, k_ix(s_, kl_), 0, 0)),
-                pl.BlockSpec((t.k_tb, n_tb),
+                pl.BlockSpec((d * t.k_tb, n_tb),
                              lambda s_, m_, n_, kl_, g_, nnz:
                              (k_ix(s_, kl_), n_)),
             ],
@@ -737,7 +790,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
         args.append(bias.reshape(groups, m, 1).astype(jnp.float32))
     return pl.pallas_call(
         red,
-        grid=(mt, nt),
+        grid=grid[:2],
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

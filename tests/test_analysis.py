@@ -91,7 +91,7 @@ def test_vmem_overflow_flagged_with_breakdown():
     assert _rules(got) == ["KC-VMEM"]
     assert "reduce kernel" in got[0].message
     bd = contracts.schedule_vmem_breakdown(
-        128, 128, 128, 64, group=2,
+        128, 128, 128, 64, k_tiles=64, group=2,
         max_nnz=roofline.analytic_max_nnz(128, 128, 0.8,
                                           columns=2 * 64 * 64 * 128))
     assert bd.reduce_bytes > budgets.vmem_budget("pallas")
@@ -100,6 +100,53 @@ def test_vmem_overflow_flagged_with_breakdown():
     assert contracts.check_schedule(8192, 8192, 128, m_tb=128, k_tb=128,
                                     n_tb=128, split_k=64, group=2,
                                     sparsity=0.8, backend="xla") == []
+
+
+# OPT-30B's LSCD launches at 80% sparsity: 56 slots of 128 x 128 tiles,
+# bf16 activations, N = 64 decode slots.
+_OPT_LAUNCH = dict(m_tb=128, k_tb=128, n_tb=64, max_nnz=56 * 128,
+                   b_dtype_bytes=2, out_dtype_bytes=2)
+
+
+@pytest.mark.parametrize("kt,split_k,d", [
+    (56, 1, 14),    # fc1 (single pass)
+    (56, 4, 14),    # decode q/k/v and out: 14-tile slices
+    (224, 4, 14),   # decode fc2: 56-tile slices
+    (224, 1, 16),   # prefill fc2: the cap
+    (17, 1, 1),     # no divisor up to the cap
+    (3, 2, 1),      # ragged: 2-tile slices of an odd Kt
+    (12, 5, 3),     # ragged: the last slice lies wholly past K
+])
+def test_tiles_per_step_divides_kt_and_slice(kt, split_k, d):
+    """d is the largest divisor of both Kt and the slice's tile count up to
+    the cap, and the VMEM model charges d tiles of words and a d*k_tb-row B
+    block — the blocks the kernels launch."""
+    assert contracts.tiles_per_step(kt, split_k, **_OPT_LAUNCH) == d
+    bd = contracts.schedule_vmem_breakdown(
+        128, 128, 64, split_k, k_tiles=kt, max_nnz=56 * 128,
+        b_dtype_bytes=2, out_dtype_bytes=2)
+    dbl = contracts.DOUBLE_BUFFER
+    assert bd.words_bytes == 4 * d * 56 * 128 * dbl
+    assert bd.b_block_bytes == d * 128 * 64 * 2 * dbl
+    assert bd.expand_bytes == 128 * 128 * (4 + 2)   # one tile at a time
+
+
+def test_tiles_per_step_steps_down_to_fit_vmem():
+    """Dense 512 x 512 tiles carry 2 MiB of words each (double-buffered):
+    16 of them would break KC-VMEM, so d steps down to the largest divisor
+    of Kt that fits, and the launch passes the contract."""
+    kw = dict(m_tb=512, k_tb=512, n_tb=128, max_nnz=512 * 512)
+    d = contracts.tiles_per_step(16, 1, **kw)
+    assert d == 4
+    budget = budgets.vmem_budget("pallas")
+    fits = contracts.schedule_vmem_breakdown(512, 512, 128, 1, k_tiles=16,
+                                             max_nnz=512 * 512)
+    assert fits.total_bytes <= budget
+    assert contracts._vmem_breakdown(8, 512, 512, 128, 1, 512 * 512, 1, 4,
+                                     4).total_bytes > budget
+    assert contracts.check_schedule(512, 16 * 512, 128, m_tb=512, k_tb=512,
+                                    n_tb=128, split_k=1,
+                                    max_nnz=512 * 512) == []
 
 
 def test_select_rejects_injected_vmem_overflow():

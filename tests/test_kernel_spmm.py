@@ -80,17 +80,30 @@ def test_kernel_vs_dense_oracle():
                                rtol=0.0, atol=0.01 * scale)
 
 
-def test_empty_tiles_fast_path():
-    """All-zero tiles exercise the nnz==0 pl.when skip branch."""
-    a = np.zeros((256, 256), np.float32)
-    a[:128, :128] = np.random.default_rng(0).standard_normal((128, 128))
+@pytest.mark.parametrize("kt,split_k", [
+    (2, None),   # the schedule's pick
+    (6, 1),      # one 6-tile step per M row: zero tiles inside the block
+    (6, 2),      # two 3-tile slices
+])
+def test_empty_tiles_fast_path(kt, split_k):
+    """All-zero tiles exercise the nnz==0 pl.when skip branch, also when
+    they sit between non-zero tiles of one multi-tile grid step; an M row
+    of zero tiles comes out as exact zeros."""
+    rng = np.random.default_rng(0)
+    a = np.zeros((256, kt * 128), np.float32)
+    a[:128, :128] = rng.standard_normal((128, 128))
+    if kt > 3:
+        a[:128, 384:512] = rng.standard_normal((128, 128))
     t = tiled_csl.encode(a)
     assert int(np.asarray(t.nnz)[1, 1]) == 0
-    b = jnp.ones((256, 8), jnp.float32)
-    got = ops.spmm(t, b, backend="interpret", out_dtype=jnp.float32)
+    assert int(np.asarray(t.nnz)[0, 1]) == 0
+    b = jnp.ones((kt * 128, 8), jnp.float32)
+    got = ops.spmm(t, b, backend="interpret", out_dtype=jnp.float32,
+                   split_k=split_k)
     want = ref.spmm_ref(t, b, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got)[128:], 0.0)
 
 
 def test_vjp_through_spmm_diff():
@@ -119,26 +132,37 @@ def test_vjp_through_spmm_diff():
 
 # Same space the hypothesis sweep drew from — mt x kt x n x sparsity with a
 # seeded RNG per case — pinned to a fixed 12-case grid so the tier-1 suite
-# needs no optional deps (see requirements-dev.txt for the extras).
-@pytest.mark.parametrize("mt,kt,n,sparsity,seed", [
-    (1, 1, 1, 0.0, 101),
-    (1, 1, 8, 0.37, 202),
-    (1, 2, 24, 0.5, 303),
-    (1, 3, 64, 0.62, 404),
-    (2, 1, 1, 0.75, 505),
-    (2, 1, 64, 0.8, 606),
-    (2, 2, 8, 0.9, 707),
-    (2, 3, 24, 0.95, 808),
-    (1, 2, 1, 0.99, 909),
-    (2, 3, 64, 0.99, 1010),
-    (1, 3, 8, 0.13, 1111),
-    (2, 2, 24, 0.88, 1212),
+# needs no optional deps (see requirements-dev.txt for the extras). The
+# rows with a split_k pin the K tiles per grid step d (the largest divisor
+# of Kt and of the slice's tile count, at most 16): d = 1 at Kt = 17, a
+# proper divisor (9) at Kt = 18, Kt itself at Kt = 4, and ragged split-K
+# slices (Kt % S != 0) at Kt = 16, S = 3 (d = 2; the last slice holds one
+# block past the end of K) and Kt = 12, S = 5 (d = 3; a whole slice past it).
+@pytest.mark.parametrize("mt,kt,n,sparsity,seed,split_k", [
+    (1, 1, 1, 0.0, 101, None),
+    (1, 1, 8, 0.37, 202, None),
+    (1, 2, 24, 0.5, 303, None),
+    (1, 3, 64, 0.62, 404, None),
+    (2, 1, 1, 0.75, 505, None),
+    (2, 1, 64, 0.8, 606, None),
+    (2, 2, 8, 0.9, 707, None),
+    (2, 3, 24, 0.95, 808, None),
+    (1, 2, 1, 0.99, 909, None),
+    (2, 3, 64, 0.99, 1010, None),
+    (1, 3, 8, 0.13, 1111, None),
+    (2, 2, 24, 0.88, 1212, None),
+    (1, 17, 8, 0.8, 1313, 1),
+    (1, 18, 8, 0.8, 1414, 1),
+    (2, 4, 8, 0.8, 1515, 1),
+    (1, 16, 8, 0.8, 1616, 3),
+    (1, 12, 8, 0.8, 1717, 5),
 ])
-def test_kernel_property(mt, kt, n, sparsity, seed):
+def test_kernel_property(mt, kt, n, sparsity, seed, split_k):
     rng = np.random.default_rng(seed)
     a, t = _make(rng, mt * 128, kt * 128, sparsity)
     b = jnp.asarray(rng.standard_normal((kt * 128, n), dtype=np.float32))
-    got = ops.spmm(t, b, backend="interpret", out_dtype=jnp.float32)
+    got = ops.spmm(t, b, backend="interpret", out_dtype=jnp.float32,
+                   split_k=split_k)
     want = ref.spmm_ref(t, b, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-3)
@@ -232,19 +256,20 @@ def _make_group(rng, g, m, k, sparsities):
     return mats, tiled_csl.encode_group(mats)
 
 
-@pytest.mark.parametrize("m,k,n", [
-    (128, 128, 8),       # single tile, skinny
-    (256, 384, 16),      # multi-tile, skinny (paper's regime)
-    (384, 128, 7),       # ragged N -> padding path
+@pytest.mark.parametrize("m,k,n,split_k", [
+    (128, 128, 8, None),       # single tile, skinny
+    (256, 384, 16, None),      # multi-tile, skinny (paper's regime)
+    (384, 128, 7, None),       # ragged N -> padding path
+    (128, 2304, 8, 1),         # Kt = 18: two 9-tile grid steps
 ])
 @pytest.mark.parametrize("g", [1, 2, 3])
 @pytest.mark.parametrize("epilogue", ["none", "relu"])
-def test_grouped_kernel_matches_ref(m, k, n, g, epilogue):
+def test_grouped_kernel_matches_ref(m, k, n, split_k, g, epilogue):
     rng = np.random.default_rng(hash((m, k, n, g)) % 2 ** 31)
     _, tg = _make_group(rng, g, m, k, (0.5, 0.8, 0.95))
     b = jnp.asarray(rng.standard_normal((k, n), dtype=np.float32))
     got = ops.spmm_grouped(tg, b, backend="interpret", out_dtype=jnp.float32,
-                           epilogue=epilogue)
+                           epilogue=epilogue, split_k=split_k)
     want = ref.spmm_grouped_ref(tg, b, out_dtype=jnp.float32,
                                 epilogue=epilogue)
     assert got.shape == (g, m, n)
@@ -288,14 +313,22 @@ def test_binary_epilogue_matches_ref(epilogue, n):
                                rtol=1e-5, atol=1e-3)
 
 
+@pytest.mark.parametrize("k,split_k", [
+    (128, None),
+    (1536, 1),           # Kt = 12: one 12-tile grid step
+    (2176, 1),           # Kt = 17: no divisor up to 16, one tile a step
+])
 @pytest.mark.parametrize("epilogue", ["none", "silu", "silu_mul"])
-def test_grouped_bias_fused(epilogue):
+def test_grouped_bias_fused(epilogue, k, split_k):
     rng = np.random.default_rng(72)
-    _, tg = _make_group(rng, 2, 128, 128, (0.7, 0.7))
-    b = jnp.asarray(rng.standard_normal((128, 8), dtype=np.float32))
+    _, tg = _make_group(rng, 2, 128, k, (0.7, 0.7))
+    # B scaled by 1/sqrt(Kt) keeps the outputs at the single-tile case's
+    # magnitude, for which the f32 tolerance below is set.
+    b = jnp.asarray(rng.standard_normal((k, 8), dtype=np.float32)
+                    / np.sqrt(k // 128, dtype=np.float32))
     bias = jnp.asarray(rng.standard_normal((2, 128)), jnp.float32)
     got = ops.spmm_grouped(tg, b, backend="interpret", out_dtype=jnp.float32,
-                           epilogue=epilogue, bias=bias)
+                           epilogue=epilogue, bias=bias, split_k=split_k)
     want = ref.spmm_grouped_ref(tg, b, out_dtype=jnp.float32,
                                 epilogue=epilogue, bias=bias)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -406,17 +439,21 @@ def test_splitk_matches_splitk_ref_association(split_k):
                                rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("kt,split_k", [
+    (3, 2),      # ragged, d = 1
+    (16, 3),     # ragged, d = 2: the last slice holds a block past K
+])
 @pytest.mark.parametrize("g", [2, 3])
 @pytest.mark.parametrize("epilogue", ["none", "relu"])
-def test_splitk_grouped_matches_ref(g, epilogue):
+def test_splitk_grouped_matches_ref(g, epilogue, kt, split_k):
     rng = np.random.default_rng(82 + g)
-    _, tg = _make_group(rng, g, 256, 384, (0.5, 0.8, 0.95))
-    b = jnp.asarray(rng.standard_normal((384, 8), dtype=np.float32))
+    _, tg = _make_group(rng, g, 256, kt * 128, (0.5, 0.8, 0.95))
+    b = jnp.asarray(rng.standard_normal((kt * 128, 8), dtype=np.float32))
     bias = jnp.asarray(rng.standard_normal((g, 256)), jnp.float32)
     got = ops.spmm_grouped(tg, b, backend="interpret",
-                           out_dtype=jnp.float32, split_k=2,
+                           out_dtype=jnp.float32, split_k=split_k,
                            epilogue=epilogue, bias=bias)
-    want = ref.spmm_splitk_grouped_ref(tg, b, 2, out_dtype=jnp.float32,
+    want = ref.spmm_splitk_grouped_ref(tg, b, split_k, out_dtype=jnp.float32,
                                        epilogue=epilogue, bias=bias)
     assert got.shape == (g, 256, 8)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -425,12 +462,13 @@ def test_splitk_grouped_matches_ref(g, epilogue):
 
 @pytest.mark.parametrize("epilogue", ["silu_mul", "gelu_mul"])
 @pytest.mark.parametrize("n", [16, 7])   # 7 exercises the N-padding slice
-def test_splitk_binary_epilogue_matches_ref(epilogue, n):
+@pytest.mark.parametrize("k", [256, 2048])   # d = 1; d = 8 (Kt 16, S 2)
+def test_splitk_binary_epilogue_matches_ref(epilogue, n, k):
     """Binary epilogues combine the G=2 pair at the split-K reduce flush;
     they must commute with the N-padding slice as in the fused path."""
     rng = np.random.default_rng(83)
-    _, tg = _make_group(rng, 2, 256, 256, (0.8, 0.8))
-    b = jnp.asarray(rng.standard_normal((256, n), dtype=np.float32))
+    _, tg = _make_group(rng, 2, 256, k, (0.8, 0.8))
+    b = jnp.asarray(rng.standard_normal((k, n), dtype=np.float32))
     got = ops.spmm_grouped(tg, b, backend="interpret",
                            out_dtype=jnp.float32, split_k=2,
                            epilogue=epilogue)

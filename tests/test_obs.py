@@ -557,6 +557,29 @@ def test_kernel_launches_traced(model):
     assert ev.args["predicted_us"] > 0
 
 
+@pytest.mark.parametrize("k,split_k,tiles,steps", [
+    (256, 1, 2, 1),      # Kt 2: one 2-tile step
+    (2176, 1, 1, 17),    # Kt 17: no divisor up to 16, one tile a step
+    (2048, 2, 8, 2),     # Kt 16 in two slices: one 8-tile step each
+])
+def test_kernel_event_counts_tiles_per_step(k, split_k, tiles, steps):
+    """The kernel event shows whether a launch expands several K tiles per
+    grid step (tiles_per_step 1 means it does not) and how many steps its
+    compute kernel takes."""
+    t = _small_csl(k=k)
+    b = jnp.ones((k, 8), jnp.float32)
+    tr = Tracer().enable()
+    from repro.obs import trace as trace_mod
+    prev = trace_mod.set_tracer(tr)
+    try:
+        ops.spmm(t, b, backend="interpret", split_k=split_k)
+    finally:
+        trace_mod.set_tracer(prev)
+    ev, = [r for r in tr.records() if r.cat == "kernel"]
+    assert ev.args["tiles_per_step"] == tiles
+    assert ev.args["grid_steps"] == steps
+
+
 # -- obs cross-check pass (tools/check.py --obs) -----------------------------
 
 def test_obs_pass_clean():

@@ -1,5 +1,7 @@
 """Compile (never run) the LSCD kernel family and the dense Pallas GEMM for a
-described TPU v5e at Qwen2-1.5B widths (d_model 1536, d_ff 8960).
+described TPU v5e at Qwen2-1.5B widths (d_model 1536, d_ff 8960), and the
+LSCD launches that expand several K tiles a grid step at OPT-30B widths
+(d_model 7168, d_ff 28672).
 
 Interpret mode cannot see what Mosaic refuses (block shapes off the (8, 128)
 tiling, primitives without a TPU lowering such as scatter, VMEM overruns);
@@ -103,6 +105,34 @@ def test_lscd_spmm_splitk_grouped_pair_compiles_at_decode(one_chip):
         t, b, n_tb=sched.n_tb, split_k=sched.split_k,
         out_dtype=jnp.bfloat16, interpret=False,
         epilogue="silu_mul").compile())
+
+
+@pytest.mark.parametrize("m,k,group,n,split_k,tiles", [
+    (7168, 7168, 3, 64, 4, 14),       # decode q/k/v: grouped split-K pair
+    (7168, 28672, None, 64, 4, 14),   # decode fc2: split-K pair
+    (7168, 28672, None, 1024, 1, 16),  # prefill fc2: single pass
+])
+def test_lscd_multi_tile_steps_compile_at_opt30b_widths(one_chip, m, k, group,
+                                                        n, split_k, tiles):
+    t = _csl(m, k, one_chip, group=group)
+    sched = schedule.select(
+        m, k, n, schedule.sparsity_from_max_nnz(t.max_nnz, t.m_tb, t.k_tb),
+        m_tb=t.m_tb, k_tb=t.k_tb, group=group or 1, max_nnz=t.max_nnz,
+        backend="pallas", cache=False)
+    assert sched.split_k == split_k
+    _, d = spmm.launch_grid(t, n, n_tb=sched.n_tb, split_k=split_k,
+                            b_dtype=jnp.bfloat16, out_dtype=jnp.bfloat16)
+    assert d == tiles
+    entry = {(False, False): spmm.lscd_spmm,
+             (True, False): spmm.lscd_spmm_grouped,
+             (False, True): spmm.lscd_spmm_splitk,
+             (True, True): spmm.lscd_spmm_splitk_grouped}[
+                 (group is not None, split_k > 1)]
+    kw = {"split_k": split_k} if split_k > 1 else {}
+    b = _struct((k, n), jnp.bfloat16, one_chip)
+    _assert_kernel(entry.lower(
+        t, b, n_tb=sched.n_tb, out_dtype=jnp.bfloat16, interpret=False,
+        epilogue="gelu", **kw).compile())
 
 
 def test_dense_gemm_compiles(one_chip):
