@@ -9,6 +9,33 @@ Everything that belongs to one cell is found by name:
 * ``chipbench/cells/<cell>.json`` -- slots, cache, offered rate, warm-up,
   the correctness sample and its limit
 * ``chipbench/metrics/<m>.py``   -- one reader per per-layer metric
+* ``chipbench/<r>.py``           -- the configuration's plain reference,
+  named by the configuration's ``"reference"`` key (``reference`` when it
+  has none)
+
+A reference module describes a family of models from the configuration
+alone and imports nothing of the program. Each function takes the model
+dict ``m`` (``Cell.model``):
+
+* ``layer_shapes(m)`` (required): the leaf names and shapes of a layer, as
+  the program's parameter tree names them under ``layers`` (``attn.wq.w``;
+  [out, in] for a projection). One dict where every layer is alike, else a
+  list of one dict per layer. The program's layout must match it.
+* ``gaps(m, seed, prompts, served, control=False)`` (required): per
+  sequence, a dict with ``gap`` (the widest gap of a served token's logit
+  below the reference's best), ``tokens``, ``agree`` (served tokens that are
+  the reference's first choice) and, with ``control``, ``control_gap`` (the
+  same for the token the lower-precision control puts first).
+* ``leaf(seed, name, layer, shape, dtype)`` (optional): the seeded draw of
+  a leaf ``chipbench/weights.py`` has no rule for, such as a stack of
+  experts drawn expert by expert through ``weights.leaf``.
+* ``projection_flops(m)`` (optional): the required matmul operations of one
+  position over all layers. Without it ``chipbench/flops.py`` counts two
+  per weight of every 2-D leaf of ``layer_shapes``, kept weights only for
+  the leaves named in the module's ``SPARSE``.
+* ``attention_flops(m, context)`` (optional): one position attending
+  ``context`` positions over all layers, linear in ``context``. Without it
+  ``flops.py`` counts softmax attention over ``n_heads`` heads.
 
 From the program it takes only the system under test (``serving.api``,
 the model's parameter layout and the weight reformat tool) and its
@@ -19,6 +46,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import functools
 import hashlib
 import importlib.util
 import inspect
@@ -64,6 +92,7 @@ class Cell:
     params: Dict               # cells/<cell>.json
     per_layer: List[Dict]      # BENCHMARK.json per_layer entries
     end_to_end: List[Dict]
+    reference: Any             # the configuration's reference module
 
     @property
     def model(self) -> Dict:
@@ -83,9 +112,10 @@ def load_cell(name: str, root: str = ROOT, *, bench: Optional[Dict] = None,
                        f"(have {sorted(work)})")
     w = work[name]
     pkg = os.path.join(root, "chipbench")
+    config = config or _read_json(pkg, "configs", f"{w['config']}.json")
     return Cell(
-        name=name, workload=w,
-        config=config or _read_json(pkg, "configs", f"{w['config']}.json"),
+        name=name, workload=w, config=config,
+        reference=load_reference(config.get("reference", "reference"), root),
         traffic=traffic or _read_json(pkg, "traffic", f"{w['traffic']}.json"),
         params=params or _read_json(pkg, "cells", f"{name}.json"),
         per_layer=[m for m in bench["per_layer"]
@@ -94,13 +124,26 @@ def load_cell(name: str, root: str = ROOT, *, bench: Optional[Dict] = None,
                     if name in m.get("workloads", [name])])
 
 
-def load_reader(metric: str, root: str = ROOT) -> Callable:
-    path = os.path.join(root, "chipbench", "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, root: str = ROOT) -> Callable:
+    return _load(os.path.join(root, "chipbench", "metrics", f"{metric}.py"),
+                 f"chipbench_metric_{metric.replace('.', '_')}").read
+
+
+@functools.lru_cache(maxsize=None)
+def load_reference(module: str, root: str = ROOT):
+    """``chipbench/<module>.py`` under ``root``, loaded once a process so
+    that every cell of a configuration reuses its compiled pieces."""
+    if not module.isidentifier():
+        raise ValueError(f"reference {module!r} is not a module name")
+    return _load(os.path.join(root, "chipbench", f"{module}.py"),
+                 f"chipbench_reference_{module}")
 
 
 def enable_compile_cache() -> str:
@@ -125,11 +168,28 @@ def model_config(cell: Cell):
     return ModelConfig(name=cell.workload["config"], **cell.config["model"])
 
 
+# The layer of a leaf of a scanned stack: its leading axis is the layer.
+SCANNED = "scanned"
+
+
+def _part(k):
+    """A path entry's dict key, list index or attribute name."""
+    for field in ("key", "idx", "name"):
+        if hasattr(k, field):
+            return getattr(k, field)
+    raise TypeError(f"unknown path entry {k!r}")
+
+
 def _leaf_name(path) -> tuple:
-    keys = [getattr(k, "key", getattr(k, "name", None)) for k in path]
-    if keys[0] == "layers":
-        return ".".join(keys[1:]), True
-    return ".".join(keys), False
+    """(name, layer): the leaf's name as ``weights`` and the reference name
+    it, and its layer -- None outside the layers, ``SCANNED`` in a scanned
+    stack, else its index in the list of layers."""
+    keys = [_part(k) for k in path]
+    if keys[0] != "layers":
+        return ".".join(keys), None
+    if isinstance(keys[1], int):
+        return ".".join(keys[2:]), keys[1]
+    return ".".join(keys[1:]), SCANNED
 
 
 def _param_shapes(cfg):
@@ -142,40 +202,60 @@ def _param_shapes(cfg):
 
 def check_layout(cell: Cell, shapes) -> None:
     """The program's parameter layout must be the one the reference draws:
-    the same leaf names and shapes, or the two would compute different
-    models."""
+    the same leaf names and shapes in every layer, or the two would compute
+    different models."""
     import jax
-    from chipbench import reference
-    want = reference.layer_shapes(cell.model)
-    have = {}
+    want = cell.reference.layer_shapes(cell.model)
+    if isinstance(want, dict):
+        want = [want] * cell.model["n_layers"]
+    want = [{k: tuple(v) for k, v in layer.items()} for layer in want]
+    have: Dict[int, Dict[str, tuple]] = {}
     for path, sd in jax.tree_util.tree_flatten_with_path(shapes)[0]:
-        name, stacked = _leaf_name(path)
-        if stacked:
-            have[name] = tuple(sd.shape[1:])
-    if have != {k: tuple(v) for k, v in want.items()}:
-        raise RuntimeError(f"program layer layout {sorted(have.items())} is "
-                           f"not the reference's {sorted(want.items())}")
+        name, layer = _leaf_name(path)
+        if layer == SCANNED:
+            for i in range(sd.shape[0]):
+                have.setdefault(i, {})[name] = tuple(sd.shape[1:])
+        elif layer is not None:
+            have.setdefault(layer, {})[name] = tuple(sd.shape)
+    if len(have) != len(want):
+        raise RuntimeError(f"program has {len(have)} layers, the reference "
+                           f"{len(want)}")
+    for i, layer in enumerate(want):
+        if have[i] != layer:
+            raise RuntimeError(
+                f"program layer {i} layout {sorted(have[i].items())} is "
+                f"not the reference's {sorted(layer.items())}")
 
 
 def dense_params(cell: Cell, shapes):
-    """Every leaf drawn on the device in one jitted call, in bf16."""
+    """Every leaf drawn on the device in one jitted call, in bf16, from
+    (weight seed, name, layer): by ``weights``, else by the reference's
+    ``leaf``."""
     import jax
     import jax.numpy as jnp
     from chipbench import weights
     seed = cell.config["weight_seed"]
+    ref = cell.reference
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+
+    def draw(name, layer, shape, dtype):
+        try:
+            return weights.leaf(seed, name, layer, shape, dtype)
+        except ValueError:
+            if not hasattr(ref, "leaf"):
+                raise
+        return ref.leaf(seed, name, layer, shape, dtype)
 
     def build():
         leaves = []
         for path, sd in flat:
-            name, stacked = _leaf_name(path)
-            if stacked:
+            name, layer = _leaf_name(path)
+            if layer == SCANNED:
                 leaves.append(jnp.stack([
-                    weights.leaf(seed, name, layer, sd.shape[1:], sd.dtype)
-                    for layer in range(sd.shape[0])]))
+                    draw(name, i, sd.shape[1:], sd.dtype)
+                    for i in range(sd.shape[0])]))
             else:
-                leaves.append(weights.leaf(seed, name, 0, sd.shape,
-                                           sd.dtype))
+                leaves.append(draw(name, layer or 0, sd.shape, sd.dtype))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return jax.jit(build)()
@@ -183,13 +263,13 @@ def dense_params(cell: Cell, shapes):
 
 def _nest(path, leaf):
     for k in reversed(path):
-        leaf = {k.key: leaf}
+        leaf = {_part(k): leaf}
     return leaf
 
 
 def _dig(tree, path):
     for k in path:
-        tree = tree[k.key]
+        tree = tree[_part(k)]
     return tree
 
 
@@ -246,6 +326,9 @@ def params_key(cell: Cell, shapes) -> str:
     h.update(str(jax.tree_util.tree_structure(shapes)).encode())
     h.update(str([tuple(x.shape) for x in jax.tree.leaves(shapes)]).encode())
     h.update(_sources_digest().encode())
+    if hasattr(cell.reference, "leaf"):
+        with open(cell.reference.__file__, "rb") as f:
+            h.update(f.read())
     return h.hexdigest()[:16]
 
 
@@ -439,7 +522,7 @@ def serve(server, reqs: List[Served], *, open_at: float, seconds: float,
 def window_record(cell: Cell, run: Dict) -> Dict[str, Any]:
     """Everything the metrics read, from one served window."""
     from chipbench import flops
-    m = cell.model
+    m, ref = cell.model, cell.reference
     t0, t1 = run["open"]["t"], run["close"]["t"]
     window_s = t1 - t0
     reqs: List[Served] = run["requests"]
@@ -458,10 +541,10 @@ def window_record(cell: Cell, run: Dict) -> Dict[str, Any]:
                 continue
             n_tokens += 1
             if j == 0:
-                req_flops += flops.prefill_flops(m, len(r.prompt))
+                req_flops += flops.prefill_flops(m, len(r.prompt), ref)
             else:
                 itl.append(t - ts[j - 1])
-                req_flops += flops.decode_flops(m, len(r.prompt) + j)
+                req_flops += flops.decode_flops(m, len(r.prompt) + j, ref)
     delta = {k: run["close"][k] - run["open"][k] for k in COUNTERS}
     inside = [(b - a, dec) for a, b, dec in run["steps"] if t0 <= a < t1]
     host_step = sum(d for d, _ in inside)
@@ -518,12 +601,11 @@ def unanswered(run: Dict) -> int:
 
 def check(cell: Cell, sample: List[Served], *, control: bool = False
           ) -> Dict[str, Any]:
-    from chipbench import reference
     if not sample:
         return {"rows": [], "gap": None, "tokens": 0}
-    rows = reference.gaps(cell.model, cell.config["weight_seed"],
-                          [r.prompt for r in sample],
-                          [r.tokens for r in sample], control=control)
+    rows = cell.reference.gaps(cell.model, cell.config["weight_seed"],
+                               [r.prompt for r in sample],
+                               [r.tokens for r in sample], control=control)
     out = {"rows": rows, "gap": max(r["gap"] for r in rows),
            "tokens": sum(r["tokens"] for r in rows),
            "agree": sum(r["agree"] for r in rows)}

@@ -7,9 +7,11 @@
 For every seed: a fresh server on the cell's own weights, slots and load,
 its warm-up and a ``--seconds`` window, then the same seeded sample of
 finished requests that a benchmark run checks. Prints, per seed, the widest
-gap of a served token below the float32 reference's best logit (the
-program's reading) and, for the control seeds, the widest gap of the token
-that the float8 control puts first (the control's reading). The last line of
+gap of a served token below the best logit of the configuration's reference
+(``Cell.reference``; the float32 ``chipbench/reference.py`` unless the
+configuration names another) -- the program's reading -- and, for the
+control seeds, the widest gap of the token that the reference's
+lower-precision control puts first (the control's reading). The last line of
 standard output is a JSON summary: ``lower`` is the largest program reading,
 ``upper`` the smallest control reading. The benchmark's own runs never run
 the control.

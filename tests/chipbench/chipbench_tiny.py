@@ -26,6 +26,9 @@ MODELS = {
                "norm_kind": "rmsnorm", "tie_embeddings": True,
                "rope_theta": 1e6, "dtype": "bfloat16"},
 }
+# The gelu model with its layers as a list of per-layer trees, as a
+# non-uniform stack is held.
+MODELS["gelu_list"] = dict(MODELS["gelu"], scan_layers=False)
 TRAFFIC = {"prompt_len": {"dist": "log_uniform", "lo": 8, "hi": 32},
            "output_len": {"dist": "uniform", "lo": 8, "hi": 16}}
 # Readings at this size (CPU, xla backend, seeds 11-13): program gaps

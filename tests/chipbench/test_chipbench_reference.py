@@ -38,13 +38,37 @@ def test_reference_pruning_keeps_the_programs_weights(sparsity):
     assert kept == pytest.approx(1.0 - sparsity, abs=0.01)
 
 
+def _bits(x):
+    return np.asarray(x).view(np.uint16)
+
+
+def test_a_list_of_layers_draws_the_scanned_stacks_bits():
+    scanned = chipbench_tiny.cell("gelu")
+    listed = chipbench_tiny.cell("gelu_list")
+    stack = harness.dense_params(
+        scanned, harness._param_shapes(harness.model_config(scanned)))
+    shapes = harness._param_shapes(harness.model_config(listed))
+    assert isinstance(shapes["layers"], list) and len(shapes["layers"]) == 2
+    harness.check_layout(listed, shapes)
+    tree = harness.dense_params(listed, shapes)
+    for i, layer in enumerate(tree["layers"]):
+        jax.tree.map(lambda s, x: np.testing.assert_array_equal(
+            _bits(s[i]), _bits(x)), stack["layers"], layer)
+    jax.tree.map(lambda s, x: np.testing.assert_array_equal(
+        _bits(s), _bits(x)), {k: v for k, v in stack.items() if k != "layers"},
+        {k: v for k, v in tree.items() if k != "layers"})
+    np.testing.assert_array_equal(
+        _bits(tree["layers"][1]["mlp"]["up"]["w"]),
+        _bits(weights.leaf(7, "mlp.up.w", 1, (256, 128))))
+
+
 @pytest.fixture
 def tmp_cache(tmp_path, monkeypatch):
     monkeypatch.setattr(harness, "CACHE", str(tmp_path))
     return tmp_path
 
 
-@pytest.mark.parametrize("kind", ["gelu", "swiglu"])
+@pytest.mark.parametrize("kind", ["gelu", "swiglu", "gelu_list"])
 def test_served_tokens_pass_and_the_float8_control_fails(kind, tmp_cache):
     cell = chipbench_tiny.cell(kind)
     (row,) = limits.readings(cell, [11], {11}, 3.0)

@@ -235,6 +235,36 @@ def test_required_flops_count_kept_weights_only():
                                          + 4 * 128 * 6 * 2 + 2 * 512 * 128)
 
 
+# The opt30b-4l-s80 cell's per-layer layout and required operations,
+# computed before configurations could name their own reference.
+OPT_LAYER_SHAPES = {
+    "attn.wq.w": (7168, 7168), "attn.wk.w": (7168, 7168),
+    "attn.wv.w": (7168, 7168), "attn.wo.w": (7168, 7168),
+    "attn.wq.b": (7168,), "attn.wk.b": (7168,), "attn.wv.b": (7168,),
+    "mlp.up.w": (28672, 7168), "mlp.down.w": (7168, 28672),
+    "mlp.up.b": (28672,), "mlp.down.b": (7168,),
+    "pre_norm.scale": (7168,), "pre_norm.bias": (7168,),
+    "mlp_norm.scale": (7168,), "mlp_norm.bias": (7168,)}
+OPT_PROJECTION_FLOPS = 986500304.0
+OPT_DECODE_FLOPS_300 = 1741606096.0
+OPT_PREFILL_FLOPS_100 = 99949904192.0
+
+
+def test_opt_cell_keeps_its_reference_layout_and_required_flops():
+    cell = harness.load_cell("opt30b-4l-s80.chat")
+    assert "reference" not in cell.config
+    assert cell.reference.__file__ == os.path.join(ROOT, "chipbench",
+                                                   "reference.py")
+    m = cell.model
+    assert cell.reference.layer_shapes(m) == OPT_LAYER_SHAPES
+    harness.check_layout(cell, harness._param_shapes(
+        harness.model_config(cell)))
+    for ref in ((), (cell.reference,)):
+        assert flops.projection_flops(m, *ref) == OPT_PROJECTION_FLOPS
+        assert flops.decode_flops(m, 300, *ref) == OPT_DECODE_FLOPS_300
+        assert flops.prefill_flops(m, 100, *ref) == OPT_PREFILL_FLOPS_100
+
+
 def test_peaks_refuse_an_unknown_chip():
     assert peaks.for_kind("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
@@ -245,12 +275,16 @@ def test_peaks_refuse_an_unknown_chip():
 # found by name: a new configuration, mix or metric is a new file
 # ---------------------------------------------------------------------------
 
-def test_new_config_mix_and_metric_are_found_without_edits(tmp_path):
+def _copy(tmp_path):
+    """The benchmark's files in ``tmp_path``: (chipbench dir, bench)."""
     shutil.copytree(os.path.join(ROOT, "chipbench"), tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("cache", "__pycache__"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    pkg = tmp_path / "chipbench"
+        return tmp_path / "chipbench", json.load(f)
+
+
+def test_new_config_mix_and_metric_are_found_without_edits(tmp_path):
+    pkg, bench = _copy(tmp_path)
     (pkg / "configs" / "newmodel.json").write_text(json.dumps(
         {"model": {"n_layers": 1}, "sparsity": 0.5, "weight_seed": 3}))
     (pkg / "traffic" / "bursty.json").write_text(json.dumps(
@@ -277,6 +311,140 @@ def test_new_config_mix_and_metric_are_found_without_edits(tmp_path):
     assert harness.load_reader("queue_wait_ms", root=str(tmp_path))({}) == 7.0
     other = harness.load_cell("opt30b-4l-s80.chat", root=str(tmp_path))
     assert "queue_wait_ms" not in [m["name"] for m in other.per_layer]
+
+
+# A reference for a family the default reference does not know: latent
+# attention with a query bottleneck, routed experts stacked [E, out, in]
+# (leaves ``weights`` has no rule for) and shared experts.
+PROBE_REFERENCE = '''
+import jax.numpy as jnp
+
+from chipbench import weights
+
+ROWS = [{"gap": 0.125, "tokens": 3, "agree": 2},
+        {"gap": 0.5, "tokens": 4, "agree": 4}]
+PROJECTION = 12345.0
+ATTENTION = 100.0
+
+
+def layer_shapes(m):
+    d, h = m["d_model"], m["n_heads"]
+    qr, kvr = m["q_lora_rank"], m["kv_lora_rank"]
+    dn, dr, dv = m["qk_nope_dim"], m["qk_rope_dim"], m["v_head_dim"]
+    e, f = m["n_routed_experts"], m["d_expert"]
+    fs = m["d_shared_expert"] * m["n_shared_experts"]
+    return {"attn.w_dq.w": (qr, d), "attn.w_uq.w": (h * (dn + dr), qr),
+            "attn.w_dkv.w": (kvr + dr, d),
+            "attn.w_ukv.w": (h * (dn + dv), kvr), "attn.wo.w": (d, h * dv),
+            "moe.router.w": (e, d), "moe.gate": (e, f, d),
+            "moe.up": (e, f, d), "moe.down": (e, d, f),
+            "moe.shared.gate.w": (fs, d), "moe.shared.up.w": (fs, d),
+            "moe.shared.down.w": (d, fs),
+            "pre_norm.scale": (d,), "mlp_norm.scale": (d,)}
+
+
+def leaf(seed, name, layer, shape, dtype):
+    return jnp.stack([weights.leaf(seed, f"{name}.{e}.w", layer, shape[1:],
+                                   dtype) for e in range(shape[0])])
+
+
+def projection_flops(m):
+    return PROJECTION
+
+
+def attention_flops(m, context):
+    return ATTENTION * context
+
+
+def gaps(m, seed, prompts, served, control=False):
+    return [dict(r) for r in ROWS[:len(prompts)]]
+'''
+PROBE_MODEL = {"family": "moe", "n_layers": 2, "d_model": 128, "n_heads": 4,
+               "n_kv": 4, "vocab": 512, "attn_kind": "mla",
+               "q_lora_rank": 64, "kv_lora_rank": 32, "qk_nope_dim": 16,
+               "qk_rope_dim": 8, "v_head_dim": 16, "n_routed_experts": 8,
+               "top_k": 2, "d_expert": 32, "n_shared_experts": 2,
+               "d_shared_expert": 64, "norm_kind": "rmsnorm",
+               "tie_embeddings": True, "dtype": "bfloat16"}
+
+
+def _probe_cell(tmp_path):
+    pkg, bench = _copy(tmp_path)
+    (pkg / "ref_probe.py").write_text(PROBE_REFERENCE)
+    (pkg / "configs" / "probe.json").write_text(json.dumps(
+        {"reference": "ref_probe", "model": PROBE_MODEL, "sparsity": 0.8,
+         "weight_seed": 5}))
+    (pkg / "cells" / "probe.chat.json").write_text(json.dumps(
+        {"n_slots": 4, "max_len": 64, "block_size": 16, "n_blocks": 64,
+         "backend": "xla", "rate_per_s": 1.0, "warmup_s": 1,
+         "check_requests": 2, "gap_limit": 0.01}))
+    bench["workloads"].append({"name": "probe.chat", "config": "probe",
+                               "traffic": "chat", "chips": 1, "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return harness.load_cell("probe.chat", root=str(tmp_path))
+
+
+def test_new_architecture_brings_its_own_reference(tmp_path, monkeypatch):
+    """Load, draw, layout check, reformat, check and FLOP count of a model
+    whose reference is new files only."""
+    from repro.core.tiled_csl import TiledCSL
+
+    from chipbench import weights
+    cell = _probe_cell(tmp_path)
+    ref = cell.reference
+    assert ref.__file__ == str(tmp_path / "chipbench" / "ref_probe.py")
+    monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
+    cfg = harness.model_config(cell)
+    setup = {}
+    params = harness.served_params(cell, cfg, setup)
+    assert setup["checkpoint"] == "written"
+    moe = params["layers"]["moe"]
+    np.testing.assert_array_equal(
+        np.asarray(moe["gate"][1, 3], np.float32),
+        np.asarray(weights.leaf(5, "moe.gate.3.w", 1, (32, 128)), np.float32))
+    assert isinstance(moe["shared"]["down"]["w"], TiledCSL)
+    assert isinstance(params["layers"]["attn"]["wo"]["w"], TiledCSL)
+    again = {}
+    harness.served_params(cell, cfg, again)
+    assert again["checkpoint"] == "hit"
+
+    sample = [harness.Served(0.0, np.arange(5), 3, "window", tokens=[1, 2, 3]),
+              harness.Served(0.0, np.arange(6), 4, "window",
+                             tokens=[4, 5, 6, 7])]
+    chk = harness.check(cell, sample)
+    assert chk["rows"] == ref.ROWS
+    assert (chk["gap"], chk["tokens"], chk["agree"]) == (0.5, 7, 6)
+
+    zero = {k: 0.0 for k in harness.COUNTERS}
+    req = harness.Served(0.05, np.arange(5), 3, "window",
+                         times=[10.1, 10.2, 10.3], tokens=[1, 2, 3])
+    rec = harness.window_record(cell, {
+        "origin": 10.0, "open": dict(zero, t=10.0),
+        "close": dict(zero, t=11.0), "requests": [req], "end_t": 11.0,
+        "steps": [], "lateness": [], "queue": {}})
+    head = flops.head_flops(cell.model)
+    assert rec["required_flops"] == (
+        (5 * ref.PROJECTION + ref.ATTENTION * 15 + head)
+        + (ref.PROJECTION + ref.ATTENTION * 6 + head)
+        + (ref.PROJECTION + ref.ATTENTION * 7 + head))
+
+
+def test_layout_check_refuses_a_reference_that_disagrees(tmp_path):
+    import dataclasses
+    import types
+    cell = _probe_cell(tmp_path)
+    shapes = harness._param_shapes(harness.model_config(cell))
+    good = cell.reference.layer_shapes(cell.model)
+    per_layer = dataclasses.replace(cell, reference=types.SimpleNamespace(
+        layer_shapes=lambda m: [good, good]))
+    harness.check_layout(per_layer, shapes)
+    wrong = dict(good, **{"moe.gate": (8, 128, 32)})
+    for shapes_of in (lambda m: wrong, lambda m: [good, wrong],
+                      lambda m: [good]):
+        bad = dataclasses.replace(cell, reference=types.SimpleNamespace(
+            layer_shapes=shapes_of))
+        with pytest.raises(RuntimeError, match="layer"):
+            harness.check_layout(bad, shapes)
 
 
 # ---------------------------------------------------------------------------
