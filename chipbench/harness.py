@@ -38,8 +38,13 @@ dict ``m`` (``Cell.model``):
   ``flops.py`` counts softmax attention over ``n_heads`` heads.
 
 From the program it takes only the system under test (``serving.api``,
-the model's parameter layout and the weight reformat tool) and its
-counters (``SchedulerMetrics``).
+the model's parameter layout and the weight reformat tool), its counters
+(every numeric field of ``SchedulerMetrics``, so a field a later program
+adds reaches a reader as it is: its change over the window in
+``rec["delta"]``, its readings at the window's open and close, as a gauge
+is read, in ``rec["counters"]``) and, in a traced run, its spans
+(``repro.obs.trace``, kept as ``rec["program"]``; see
+``chipbench/program_spans.py``).
 """
 
 from __future__ import annotations
@@ -68,10 +73,6 @@ TAIL_S = 60.0
 # Projections encoded at once on first runs: each holds an f32 copy and a
 # sort on the device and ~4 GB of numpy temporaries on the host.
 REFORMAT_WORKERS = 2
-COUNTERS = ("steps", "slot_steps", "active_slot_steps", "admit_time_s",
-            "decode_time_s", "prefill_tokens", "padded_prefill_tokens",
-            "decode_tokens", "prefill_calls", "compute_positions",
-            "preemptions", "admitted", "completed")
 
 
 def log(msg: str) -> None:
@@ -427,9 +428,14 @@ class Served:
     rejected: str = ""
 
 
-def _counters(server) -> Dict[str, float]:
-    m = server.metrics
-    return {k: float(getattr(m, k)) for k in COUNTERS}
+def counters(metrics) -> Dict[str, float]:
+    """Every numeric field of the program's ``SchedulerMetrics``."""
+    out = {}
+    for f in dataclasses.fields(metrics):
+        v = getattr(metrics, f.name)
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            out[f.name] = float(v)
+    return out
 
 
 def serve(server, reqs: List[Served], *, open_at: float, seconds: float,
@@ -439,10 +445,17 @@ def serve(server, reqs: List[Served], *, open_at: float, seconds: float,
     (schedule times are seconds after this call), ``server.step()`` runs
     whenever anything is queued or decoding. After the window closes it
     goes on until every request due in the window has its first token, at
-    most ``tail_s`` (default ``TAIL_S``). Returns the window's log."""
+    most ``tail_s`` (default ``TAIL_S``). Returns the window's log.
+
+    With ``trace_dir`` the window runs under the JAX profiler, and the
+    program's tracer (``server.batcher.tracer``) and its compile listener
+    are on from the window's open to its close; the log then holds
+    ``program``, the record ``chipbench/program_spans.py`` reads."""
     tail_s = TAIL_S if tail_s is None else tail_s
     import jax
+    from repro.obs.trace import record_compiles
     from repro.serving import api
+    from chipbench import program_spans
     by_sid: Dict[str, Served] = {}
 
     def on_token(ev):
@@ -459,27 +472,38 @@ def serve(server, reqs: List[Served], *, open_at: float, seconds: float,
     lateness: List[float] = []
     snap: Dict[str, Dict] = {}
     queue: Dict[str, float] = {}
-    ann = None
+    tracer = server.batcher.tracer
+    program = ann = stop_compiles = None
     i, n = 0, len(reqs)
     while True:
         now = time.perf_counter()
         if "open" not in snap and now >= t_open:
             if trace_dir is not None:
+                tracer.clear()
+                stop_compiles = record_compiles(tracer.enable())
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 ann = jax.profiler.TraceAnnotation("chipbench.window")
                 ann.__enter__()
+                # the two clocks are set against each other at both ends
+                prog_open = tracer.clock()
             now = time.perf_counter()
-            snap["open"] = dict(_counters(server), t=now, wall=time.time())
+            snap["open"] = {"t": now, "wall": time.time(),
+                            "counters": counters(server.metrics)}
             queue["open"] = server.queue_depth
         if "close" not in snap and now >= t_close:
-            snap["close"] = dict(_counters(server), t=now)
+            snap["close"] = {"t": now, "counters": counters(server.metrics)}
             queue["close"] = server.queue_depth
             if ann is not None:
+                prog_close = tracer.clock()
                 ann.__exit__(None, None, None)
                 jax.profiler.stop_trace()
                 ann = None
+                tracer.disable()
+                stop_compiles()
+                program = program_spans.program_record(tracer, prog_open,
+                                                       prog_close)
         if "close" in snap and (all(r.times or r.rejected for r in window)
                                 or now > t_close + tail_s):
             break
@@ -507,12 +531,12 @@ def serve(server, reqs: List[Served], *, open_at: float, seconds: float,
             nxt = origin + reqs[i].due if i < n else now + 1e-3
             with jax.profiler.TraceAnnotation("chipbench.wait"):
                 time.sleep(min(max(nxt - time.perf_counter(), 0.0), 0.01))
-    if ann is not None:
-        ann.__exit__(None, None, None)
-        jax.profiler.stop_trace()
-    return {"origin": origin, "open": snap["open"], "close": snap["close"],
-            "steps": steps, "lateness": lateness, "queue": queue,
-            "requests": reqs, "end_t": time.perf_counter()}
+    out = {"origin": origin, "open": snap["open"], "close": snap["close"],
+           "steps": steps, "lateness": lateness, "queue": queue,
+           "requests": reqs, "end_t": time.perf_counter()}
+    if program is not None:
+        out["program"] = program
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -545,15 +569,21 @@ def window_record(cell: Cell, run: Dict) -> Dict[str, Any]:
             else:
                 itl.append(t - ts[j - 1])
                 req_flops += flops.decode_flops(m, len(r.prompt) + j, ref)
-    delta = {k: run["close"][k] - run["open"][k] for k in COUNTERS}
+    c0, c1 = run["open"]["counters"], run["close"]["counters"]
+    delta = {k: c1[k] - c0[k] for k in c1}
     inside = [(b - a, dec) for a, b, dec in run["steps"] if t0 <= a < t1]
     host_step = sum(d for d, _ in inside)
-    return {"window_s": window_s, "tokens": n_tokens, "ttft_s": ttft,
-            "itl_s": itl, "delta": delta, "host_step_s": host_step,
-            "steps_in_window": len(inside), "step_s": [d for d, _ in inside],
-            "decode_launches": sum(1 for _, dec in inside if dec),
-            "required_flops": req_flops,
-            "lateness_s": run["lateness"], "queue": run["queue"]}
+    rec = {"window_s": window_s, "tokens": n_tokens, "ttft_s": ttft,
+           "itl_s": itl, "delta": delta, "host_step_s": host_step,
+           "steps_in_window": len(inside),
+           "step_s": [d for d, _ in inside],
+           "decode_launches": sum(1 for _, dec in inside if dec),
+           "required_flops": req_flops,
+           "lateness_s": run["lateness"], "queue": run["queue"],
+           "counters": {"open": c0, "close": c1}}
+    if "program" in run:
+        rec["program"] = run["program"]
+    return rec
 
 
 def pctl(values: List[float], q: float) -> float:

@@ -1,9 +1,13 @@
-"""Scheduler: median time a request waited in the admission queue, from the
-program's ``queue`` spans that end in the window: from its submit, or its
-requeue after a preemption, to the start of the admission plan that takes
-it. It holds the step in flight and the admissions queued ahead, the part
-of the time to first token that is not its own prefill. None without the
-program's spans, or when their ring dropped records."""
+"""Scheduler: median time a request waited in the program's admission
+queue, from its ``queue`` spans that end in the window: from its submit, or
+its requeue after a preemption, to the start of the admission plan that
+takes it. The harness submits only between steps, on the thread that
+steps, so a request the step in flight keeps waiting waits in the
+harness's generator (``rec["lateness_s"]``), not here: with free slots the
+span holds the submit-to-plan bookkeeping alone (~25 us on a TPU v5e host) and
+cannot move with the time to first token. It is not in ``BENCHMARK.json``
+for that reason. None without the program's spans, or when their ring
+dropped records."""
 
 import numpy as np
 

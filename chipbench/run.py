@@ -8,10 +8,11 @@ warms it up, serves its traffic open-loop for ``--seconds`` on the wall clock,
 checks a seeded sample of the served tokens against the plain float32
 reference, and prints one JSON object as the last line of standard output.
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
-``--trace 1`` the window runs under the JAX profiler and the metrics are its
-per-layer metrics. Exits non-zero, printing no result, when JAX finds no
-accelerator or fewer chips than the cell asks for, or when the program under
-test (``src/``) is not beside it.
+``--trace 1`` the window runs under the JAX profiler with the program's own
+tracer on, the metrics are its per-layer metrics, and the breakdown's idle
+gaps are named by the program's span paths. Exits non-zero, printing no
+result, when JAX finds no accelerator or fewer chips than the cell asks for,
+or when the program under test (``src/``) is not beside it.
 """
 
 from __future__ import annotations
@@ -35,7 +36,7 @@ def execute(cell, *, seed: int, seconds: float, trace: bool,
             t_start: float) -> dict:
     """Set up, serve, read and check one window of ``cell``."""
     import jax
-    from chipbench import harness, trace_reduce
+    from chipbench import harness, program_spans, trace_reduce
 
     log = harness.log
     dev = jax.devices()[0]
@@ -86,8 +87,19 @@ def execute(cell, *, seed: int, seconds: float, trace: bool,
     log("steps >= 200 ms by 50 ms bin: " + " ".join(
         f"{b}:{bins[b]}" for b in sorted(bins)))
     if trace_dir is not None:
+        prog = rec["program"]
+        log(f"program spans: {len(prog['records'])} records, "
+            f"{prog['dropped']} dropped")
         path = trace_reduce.find_trace(trace_dir)
-        rec["trace"] = trace_reduce.reduce_file(path) if path else None
+        if path:
+            planes = trace_reduce.load_planes(path)
+            rec["trace"] = trace_reduce.reduce_planes(planes)
+            named = program_spans.attribute(planes, prog)
+            if rec["trace"] and named:
+                rec["trace"].update(named)
+                log("idle s by program span: " + json.dumps(
+                    dict(list(named["idle_by_span"].items())[:8])))
+            del planes
     del server, params
     gc.collect()
 

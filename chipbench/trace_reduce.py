@@ -10,6 +10,10 @@ per-layer metrics read.
   their time is summed per chip, and per compiled program (the ``XLA
   Modules`` event that holds them) with the HBM bytes their operands and
   results occupy (shapes outside HBM, memory space ``S(1)``, do not count).
+  Within a program the same sums are kept per kernel family, the
+  instruction name less its ``.N`` suffix (``lscd_spmm_splitk_grouped``),
+  so that one kernel's roofline can be read apart from the others in the
+  same program.
 * The op table names each op by its instruction name and leaves out the
   control-flow ops (``while``, ``conditional``, ``call``) that contain
   others.
@@ -42,6 +46,7 @@ def find_trace(log_dir: str) -> Optional[str]:
 PALLAS = 'custom_call_target="tpu_custom_call"'
 CONTAINERS = (" while(", " conditional(", " call(")
 MODULES_LINE = "XLA Modules"
+_SUFFIX = re.compile(r"\.\d+$")
 
 
 def is_pallas(name: str) -> bool:
@@ -51,6 +56,11 @@ def is_pallas(name: str) -> bool:
 def op_name(name: str) -> str:
     """``%lscd_spmm.16 = bf16[...] custom-call(...)`` -> ``lscd_spmm.16``."""
     return name.split(" = ", 1)[0].strip().removeprefix("ROOT ").lstrip("%")
+
+
+def family(name: str) -> str:
+    """``%lscd_spmm.16 = ...`` -> ``lscd_spmm``: the kernel, not the call."""
+    return _SUFFIX.sub("", op_name(name))
 
 
 def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -106,7 +116,8 @@ def reduce_planes(planes) -> Optional[Dict]:
         for s, e, name in runs:
             if _clip(s, e, w0, w1):
                 k = kernels.setdefault(name, {"runs": 0, "seconds": 0.0,
-                                              "hbm_bytes": 0})
+                                              "hbm_bytes": 0,
+                                              "families": {}})
                 k["runs"] += 1
         iv = []
         for ev in ops:
@@ -124,8 +135,14 @@ def reduce_planes(planes) -> Optional[Dict]:
                 if i >= 0 and runs[i][1] >= ev.start_ns and runs[i][2] in \
                         kernels:
                     k = kernels[runs[i][2]]
+                    nbytes = hbm_bytes(ev.name)
                     k["seconds"] += dur
-                    k["hbm_bytes"] += hbm_bytes(ev.name)
+                    k["hbm_bytes"] += nbytes
+                    f = k["families"].setdefault(family(ev.name), {
+                        "calls": 0, "seconds": 0.0, "hbm_bytes": 0})
+                    f["calls"] += 1
+                    f["seconds"] += dur
+                    f["hbm_bytes"] += nbytes
         merged = union(iv)
         busy_total += sum(e - s for s, e in merged) * 1e-9
         prev = w0
@@ -157,10 +174,13 @@ def reduce_planes(planes) -> Optional[Dict]:
     }
 
 
-def reduce_file(path: str) -> Optional[Dict]:
+def load_planes(path: str) -> list:
     import jax
-    data = jax.profiler.ProfileData.from_file(path)
-    return reduce_planes(data.planes)
+    return list(jax.profiler.ProfileData.from_file(path).planes)
+
+
+def reduce_file(path: str) -> Optional[Dict]:
+    return reduce_planes(load_planes(path))
 
 
 # Bytes of one element of each HLO type.

@@ -1,6 +1,7 @@
 """The program's spans beside the profiler's trace: the clock anchor, idle
 gaps named by span paths, the readers of queue wait, host self time and
-in-window compiles, and the compile listener they rest on."""
+in-window compiles, the compile listener they rest on, and the tiny cell
+served with its tracer on and off."""
 
 from __future__ import annotations
 
@@ -9,9 +10,10 @@ import time
 
 import pytest
 
-import chipbench_tiny  # noqa: F401  (puts the program on the path)
+import chipbench_tiny  # puts the program on the path
 from chipbench import harness, program_spans, trace_reduce
-from repro.obs.trace import Tracer, record_compiles
+from chipbench.run import execute
+from repro.obs.trace import Tracer, record_compiles, set_tracer
 
 Ev = collections.namedtuple("Ev", "name start_ns duration_ns stats")
 Line = collections.namedtuple("Line", "name events")
@@ -190,3 +192,86 @@ def test_record_compiles_until_unregistered():
     n = len(tr)
     jax.jit(lambda x: x - 2)(jnp.ones(5))
     assert len(tr) == n
+
+
+# ---------------------------------------------------------------------------
+# the tiny cell served with the program's tracer on, and off
+# ---------------------------------------------------------------------------
+
+SPAN_READERS = ("queue_wait_p50_ms", "step_host_self_ms",
+                "compiles_in_window")
+
+
+@pytest.fixture
+def fresh_tracer():
+    """A process-wide tracer of the default size for one test: the server
+    takes it as its own, and no other test sees what it records."""
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    yield tracer
+    set_tracer(prev)
+
+
+def _serve(tmp_path, monkeypatch, traced):
+    monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
+    cell = chipbench_tiny.cell("gelu")
+    cfg = harness.model_config(cell)
+    params = harness.served_params(cell, cfg, {})
+    server = harness.make_server(params, cfg, cell)
+    harness.warm_shapes(server, cell, cfg.vocab)
+    reqs = harness.build_requests(cell, 2 ** 31 + 5, 2.0, cfg.vocab)
+    trace_dir = str(tmp_path / "trace") if traced else None
+    run = harness.serve(server, reqs, open_at=cell.params["warmup_s"],
+                        seconds=2.0, trace_dir=trace_dir, tail_s=10.0)
+    return server, cell, run
+
+
+def test_traced_serve_keeps_the_programs_spans(tmp_path, monkeypatch,
+                                               fresh_tracer):
+    server, cell, run = _serve(tmp_path, monkeypatch, traced=True)
+    assert server.batcher.tracer.enabled is False
+    rec = harness.window_record(cell, run)
+    prog = rec["program"]
+    assert prog is run["program"] and prog["dropped"] == 0
+    t0, t1 = prog["window"]
+    steps = [r for r in program_spans.spans(prog["records"], "step")
+             if t0 <= r["ts"] < t1]
+    assert len(steps) >= 5
+    for name in SPAN_READERS:
+        assert harness.load_reader(name)(rec) is not None, name
+    assert harness.load_reader("step_host_self_ms")(rec) > 0
+    # the tracer's window stamps sit on the profiler's window event
+    planes = trace_reduce.load_planes(
+        trace_reduce.find_trace(str(tmp_path / "trace")))
+    window, _, _ = program_spans._planes(planes)
+    assert program_spans.anchor(window, prog)["shift_s"] is not None
+    assert rec["delta"]["steps"] == len(
+        [s for s in run["steps"] if run["open"]["t"] <= s[0]
+         < run["close"]["t"]])
+
+
+def test_untraced_serve_leaves_the_tracer_off(tmp_path, monkeypatch,
+                                              fresh_tracer):
+    server, cell, run = _serve(tmp_path, monkeypatch, traced=False)
+    assert fresh_tracer.enabled is False and len(fresh_tracer) == 0
+    assert "program" not in run
+    rec = harness.window_record(cell, run)
+    assert "program" not in rec
+    for name in SPAN_READERS:
+        assert harness.load_reader(name)(rec) is None, name
+
+
+def test_traced_run_reports_the_span_metrics(tmp_path, monkeypatch,
+                                             fresh_tracer):
+    monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
+    cell = chipbench_tiny.cell("gelu")
+    res = execute(cell, seed=2 ** 31 + 23, seconds=2.0, trace=True,
+                  t_start=time.time())
+    assert res["correct"] is True
+    assert fresh_tracer.dropped == 0 and fresh_tracer.enabled is False
+    spans = [m["name"] for m in cell.per_layer
+             if m["source"] == "program_span"]
+    assert {"step_host_self_ms", "compiles_in_window"} <= set(spans)
+    for name in spans:
+        assert name in res["metrics"], name
+    assert res["metrics"]["compiles_in_window"]["unit"] == "count"

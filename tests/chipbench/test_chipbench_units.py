@@ -132,9 +132,13 @@ def test_trace_reduction_on_a_small_timeline():
     assert r["device_ops"][0] == ["lscd_spmm.3", pytest.approx(3000e-9)]
     assert "while.5" not in dict(r["device_ops"])
     # the kernel's words are in HBM, its activation and result on chip
+    words = 4 * 4 * 8 * 128 * 4
     assert r["kernels"] == {"jit_decode(1)": {
         "runs": 2, "seconds": pytest.approx(3000e-9),
-        "hbm_bytes": 2 * 4 * 4 * 8 * 128 * 4}}
+        "hbm_bytes": 2 * words,
+        "families": {"lscd_spmm": {"calls": 2,
+                                   "seconds": pytest.approx(3000e-9),
+                                   "hbm_bytes": 2 * words}}}}
     # idle gaps: 4500-9000 (host waiting mostly), 10000-10500 (step)
     assert r["idle_gaps"][0] == ["wait", pytest.approx(4500e-9)]
     assert r["idle_gaps"][1] == ["step", pytest.approx(500e-9)]
@@ -156,6 +160,34 @@ def test_trace_reduction_of_a_recorded_chip_window():
     share = harness.load_reader("lscd_decode_roofline")(
         {"trace": r, "device_kind": "TPU v5 lite"})
     assert 1.0 < share < 100.0
+
+
+# The recorded window's per-layer readings before the trace was split by
+# kernel family: the split adds to the reduction and changes none of them.
+RECORDED_READINGS = {"lscd_decode_roofline": 5.77372154606043,
+                     "pallas_busy_share": 57.40404851429859,
+                     "device_idle_share": 2.6134203999999994,
+                     "step_mfu": 4.169890473885949}
+
+
+def test_kernel_families_of_the_recorded_window_sum_to_their_program():
+    r = trace_reduce.reduce_file(os.path.join(
+        os.path.dirname(__file__), "data", "opt_chat_window.xplane.pb"))
+    for prog in r["kernels"].values():
+        fams = prog["families"]
+        assert sum(f["seconds"] for f in fams.values()) == pytest.approx(
+            prog["seconds"], rel=1e-12)
+        assert sum(f["hbm_bytes"] for f in fams.values()) == prog[
+            "hbm_bytes"]
+        assert all(f["calls"] > 0 and f["seconds"] > 0
+                   for f in fams.values())
+    (decode,) = r["kernels"].values()
+    assert {"lscd_spmm", "lscd_spmm_splitk",
+            "lscd_spmm_splitk_grouped"} <= set(decode["families"])
+    assert not any(trace_reduce._SUFFIX.search(f) for f in decode["families"])
+    rec = {"trace": r, "device_kind": "TPU v5 lite", "required_flops": 4e12}
+    for name, want in RECORDED_READINGS.items():
+        assert harness.load_reader(name)(rec) == want, name
 
 
 def test_trace_reduction_finds_nothing_without_a_window_or_device():
@@ -312,6 +344,77 @@ def test_new_config_mix_and_metric_are_found_without_edits(tmp_path):
     other = harness.load_cell("opt30b-4l-s80.chat", root=str(tmp_path))
     assert "queue_wait_ms" not in [m["name"] for m in other.per_layer]
 
+    # A later program's new counter, gauge, span and kernel reach a new reader
+    # through the harness's own record, trace reduction and span record.
+    (pkg / "metrics" / "expert_probe.py").write_text(EXPERT_READER)
+    rec = harness.window_record(cell, _run_with_new_mechanism())
+    rec["trace"] = trace_reduce.reduce_planes(_expert_planes())
+    got = harness.load_reader("expert_probe", root=str(tmp_path))(rec)
+    assert got == (42.0, 61.0, 2, 3, pytest.approx(600e-9),
+                   3 * 2 * 64 * 128 * 4)
+
+
+# A reader of a counter, a gauge, a span and a kernel family that the
+# program under test does not have yet.
+EXPERT_READER = '''
+from chipbench import program_spans
+
+
+def read(rec):
+    recs, t0, t1 = program_spans.records(rec)
+    routes = [r for r in program_spans.spans(recs, "route")
+              if t0 <= r["ts"] < t1]
+    fam = [p["families"]["lscd_expert_grouped"]
+           for p in rec["trace"]["kernels"].values()
+           if "lscd_expert_grouped" in p["families"]]
+    return (rec["delta"]["expert_tokens"],
+            rec["counters"]["close"]["experts_resident"], len(routes),
+            sum(f["calls"] for f in fam), sum(f["seconds"] for f in fam),
+            sum(f["hbm_bytes"] for f in fam))
+'''
+
+
+def _run_with_new_mechanism():
+    """A window log whose program has counters and a span this program
+    lacks: the counter ``expert_tokens`` and the gauge ``experts_resident``
+    in its metrics, ``route`` spans in its tracer."""
+    import dataclasses
+    from repro.obs.trace import Tracer
+    from repro.serving.scheduler import SchedulerMetrics
+
+    from chipbench import program_spans
+    Metrics = dataclasses.make_dataclass(
+        "Metrics", [("expert_tokens", int, 0), ("experts_resident", int, 0)],
+        bases=(SchedulerMetrics,))
+    clock = iter([10.5, 10.8, 12.0]).__next__
+    tr = Tracer(clock=clock).enable()
+    tr.span("step", "route", "engine", 10.1)
+    tr.span("step", "route", "engine", 10.6)
+    tr.span("step", "route", "engine", 11.5)
+    return {"origin": 10.0, "requests": [], "end_t": 11.0, "steps": [],
+            "lateness": [], "queue": {},
+            "open": {"t": 10.0, "counters": harness.counters(
+                Metrics(expert_tokens=8, experts_resident=64, steps=3))},
+            "close": {"t": 11.0, "counters": harness.counters(
+                Metrics(expert_tokens=50, experts_resident=61, steps=9))},
+            "program": program_spans.program_record(tr, 10.0, 11.0)}
+
+
+def _expert_planes():
+    """A traced window in which one program runs a new kernel three
+    times beside LSCD."""
+    op = ('%lscd_expert_grouped.{} = bf16[64,128]{{1,0:T(8,128)(2,1)S(1)}} '
+          'custom-call(u32[2,64,128]{{2,1,0}} %w), '
+          'custom_call_target="tpu_custom_call"')
+    host = Plane("/host:CPU", [Line("python", [
+        Ev("chipbench.window", 0, 10000, [])])])
+    ops = [Ev(op.format(7), 1000, 200, []), Ev(K3, 1300, 300, []),
+           Ev(op.format(7), 2000, 200, []), Ev(op.format(12), 3000, 200, [])]
+    dev = Plane("/device:TPU:0", [
+        Line("XLA Modules", [Ev("jit_decode(1)", 900, 3000, [])]),
+        Line("XLA Ops", ops)])
+    return [host, dev]
+
 
 # A reference for a family the default reference does not know: latent
 # attention with a query bottleneck, routed experts stacked [E, out, in]
@@ -368,11 +471,11 @@ PROBE_MODEL = {"family": "moe", "n_layers": 2, "d_model": 128, "n_heads": 4,
                "tie_embeddings": True, "dtype": "bfloat16"}
 
 
-def _probe_cell(tmp_path):
+def _probe_cell(tmp_path, model=PROBE_MODEL):
     pkg, bench = _copy(tmp_path)
     (pkg / "ref_probe.py").write_text(PROBE_REFERENCE)
     (pkg / "configs" / "probe.json").write_text(json.dumps(
-        {"reference": "ref_probe", "model": PROBE_MODEL, "sparsity": 0.8,
+        {"reference": "ref_probe", "model": model, "sparsity": 0.8,
          "weight_seed": 5}))
     (pkg / "cells" / "probe.chat.json").write_text(json.dumps(
         {"n_slots": 4, "max_len": 64, "block_size": 16, "n_blocks": 64,
@@ -384,26 +487,51 @@ def _probe_cell(tmp_path):
     return harness.load_cell("probe.chat", root=str(tmp_path))
 
 
-def test_new_architecture_brings_its_own_reference(tmp_path, monkeypatch):
-    """Load, draw, layout check, reformat, check and FLOP count of a model
-    whose reference is new files only."""
-    from repro.core.tiled_csl import TiledCSL
+def _assert_encoded_as_the_rule_names(dense, served):
+    """Exactly the leaves ``serve.should_sparsify`` names, whatever that rule
+    is, are Tiled-CSL in the served tree: every other leaf is served as
+    drawn, and the encoded matrices number the named ones (the reformat
+    may group several named leaves into one)."""
+    import math
 
-    from chipbench import weights
-    cell = _probe_cell(tmp_path)
+    import jax
+    from repro.core.tiled_csl import TiledCSL
+    from repro.launch import serve
+
+    def is_csl(x):
+        return isinstance(x, TiledCSL)
+
+    named = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(dense)[0]:
+        if serve.should_sparsify(jax.tree_util.keystr(path)):
+            named += math.prod(leaf.shape[:-2])
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(harness._dig(served, path), np.float32),
+                np.asarray(leaf, np.float32))
+    encoded = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            served, is_leaf=is_csl)[0]:
+        if is_csl(leaf):
+            encoded += math.prod(leaf.words.shape[:-4])
+        else:
+            assert not serve.should_sparsify(jax.tree_util.keystr(path))
+    assert encoded == named > 0
+
+
+def _probe_serves(tmp_path, monkeypatch, model):
+    """Load, draw, layout check, reformat, checkpoint, check and FLOP count
+    of the probe: (cell, dense tree, served tree)."""
+    cell = _probe_cell(tmp_path, model)
     ref = cell.reference
     assert ref.__file__ == str(tmp_path / "chipbench" / "ref_probe.py")
     monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
     cfg = harness.model_config(cell)
+    dense = harness.dense_params(cell, harness._param_shapes(cfg))
     setup = {}
     params = harness.served_params(cell, cfg, setup)
     assert setup["checkpoint"] == "written"
-    moe = params["layers"]["moe"]
-    np.testing.assert_array_equal(
-        np.asarray(moe["gate"][1, 3], np.float32),
-        np.asarray(weights.leaf(5, "moe.gate.3.w", 1, (32, 128)), np.float32))
-    assert isinstance(moe["shared"]["down"]["w"], TiledCSL)
-    assert isinstance(params["layers"]["attn"]["wo"]["w"], TiledCSL)
+    _assert_encoded_as_the_rule_names(dense, params)
     again = {}
     harness.served_params(cell, cfg, again)
     assert again["checkpoint"] == "hit"
@@ -415,18 +543,62 @@ def test_new_architecture_brings_its_own_reference(tmp_path, monkeypatch):
     assert chk["rows"] == ref.ROWS
     assert (chk["gap"], chk["tokens"], chk["agree"]) == (0.5, 7, 6)
 
-    zero = {k: 0.0 for k in harness.COUNTERS}
+    from repro.serving.scheduler import SchedulerMetrics
+    zero = harness.counters(SchedulerMetrics())
     req = harness.Served(0.05, np.arange(5), 3, "window",
                          times=[10.1, 10.2, 10.3], tokens=[1, 2, 3])
     rec = harness.window_record(cell, {
-        "origin": 10.0, "open": dict(zero, t=10.0),
-        "close": dict(zero, t=11.0), "requests": [req], "end_t": 11.0,
-        "steps": [], "lateness": [], "queue": {}})
+        "origin": 10.0, "open": {"t": 10.0, "counters": zero},
+        "close": {"t": 11.0, "counters": zero}, "requests": [req],
+        "end_t": 11.0, "steps": [], "lateness": [], "queue": {}})
     head = flops.head_flops(cell.model)
     assert rec["required_flops"] == (
         (5 * ref.PROJECTION + ref.ATTENTION * 15 + head)
         + (ref.PROJECTION + ref.ATTENTION * 6 + head)
         + (ref.PROJECTION + ref.ATTENTION * 7 + head))
+    return cell, dense, params
+
+
+def test_new_architecture_brings_its_own_reference(tmp_path, monkeypatch):
+    """Load, draw, layout check, reformat, check and FLOP count of a model
+    whose reference is new files only."""
+    from repro.core.tiled_csl import TiledCSL
+
+    from chipbench import weights
+    _, dense, params = _probe_serves(tmp_path, monkeypatch, PROBE_MODEL)
+    np.testing.assert_array_equal(
+        np.asarray(dense["layers"]["moe"]["gate"][1, 3], np.float32),
+        np.asarray(weights.leaf(5, "moe.gate.3.w", 1, (32, 128)), np.float32))
+    assert isinstance(params["layers"]["moe"]["shared"]["down"]["w"],
+                      TiledCSL)
+    assert isinstance(params["layers"]["attn"]["wo"]["w"], TiledCSL)
+
+
+def test_probe_passes_when_the_program_prunes_its_routed_experts(
+        tmp_path, monkeypatch):
+    """A program whose rule also prunes the [E, out, in] expert stacks of a
+    list of layers (a stack whose layers differ is held so) serves the
+    probe with no edit to the benchmark."""
+    from repro.core.tiled_csl import TiledCSL
+    from repro.launch import serve
+
+    from chipbench import weights
+    rule = serve.should_sparsify
+
+    def experts_too(path):
+        return rule(path) or ("['moe']" in path and path.endswith(
+            ("['gate']", "['up']", "['down']")))
+
+    monkeypatch.setattr(serve, "should_sparsify", experts_too)
+    _, dense, params = _probe_serves(tmp_path, monkeypatch,
+                                     dict(PROBE_MODEL, scan_layers=False))
+    np.testing.assert_array_equal(
+        np.asarray(dense["layers"][1]["moe"]["gate"][3], np.float32),
+        np.asarray(weights.leaf(5, "moe.gate.3.w", 1, (32, 128)), np.float32))
+    for layer in params["layers"]:
+        for name in ("gate", "up", "down"):
+            assert isinstance(layer["moe"][name], TiledCSL)
+        assert not isinstance(layer["moe"]["router"]["w"], TiledCSL)
 
 
 def test_layout_check_refuses_a_reference_that_disagrees(tmp_path):
