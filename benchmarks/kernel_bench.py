@@ -111,11 +111,11 @@ def bench_shape(m: int, k: int, n: int, sparsity: float, *,
         b = jnp.asarray(rng.standard_normal((kp, n), dtype=np.float32))
         ad = jnp.asarray(ap)
         f_dense = jax.jit(lambda aa, bb: ref.spmm_dense_oracle(aa, bb))
-        f_sparse = jax.jit(lambda words, nnz, bb: ref.spmm_ref(
-            tiled_csl.TiledCSL(words, nnz, t.shape, t.m_tb, t.k_tb, t.dtype),
-            bb))
+        f_sparse = jax.jit(lambda words, nnz, slabs, bb: ref.spmm_ref(
+            tiled_csl.TiledCSL(words, nnz, slabs, t.shape, t.m_tb, t.k_tb,
+                               t.dtype), bb))
         us_d = _time_it(f_dense, ad, b)
-        us_s = _time_it(f_sparse, t.words, t.nnz, b)
+        us_s = _time_it(f_sparse, t.words, t.nnz, t.slabs, b)
         rows.append(f"{name}_wall_dense_xla,{us_d:.1f},cpu_ref")
         rows.append(f"{name}_wall_sparse_xla,{us_s:.1f},cpu_ref")
     return rows

@@ -71,6 +71,11 @@ class TiledCSL:
               stream all G weight streams against one activation block.
       nnz:    int32[mt, kt] (or int32[G, mt, kt]) — true non-zero count per
               tile (<= max_nnz).
+      slabs:  int32, shaped as ``nnz`` — per tile, the 8-slot slabs
+              (``SLOT_QUANTUM``) that hold a real word in any of its
+              columns: ceil(fullest column count / 8), 0 exactly when the
+              tile is empty. Every slab past it is all ``PAD_WORD``, so the
+              kernel's expansion stops there.
       shape:  logical dense shape (m, k) of *each* matrix;
               m % m_tb == 0 and k % k_tb == 0.
       m_tb, k_tb: tile geometry.
@@ -79,6 +84,7 @@ class TiledCSL:
 
     words: jax.Array
     nnz: jax.Array
+    slabs: jax.Array
     shape: Tuple[int, int]
     m_tb: int
     k_tb: int
@@ -119,8 +125,9 @@ class TiledCSL:
 
     @property
     def nbytes_sparse(self) -> int:
-        """Bytes actually streamed by the LSCD kernel for A (incl. padding)."""
-        return int(self.words.size * 4) + int(self.nnz.size * 4)
+        """Bytes actually streamed by the LSCD kernel for A (incl. padding):
+        the words and the per-tile slab counts it prefetches."""
+        return int(self.words.size * 4) + int(self.slabs.size * 4)
 
     @property
     def nbytes_dense(self) -> int:
@@ -146,15 +153,16 @@ class TiledCSL:
 
 def _tcsl_flatten_with_keys(t: TiledCSL):
     return (((jax.tree_util.GetAttrKey("words"), t.words),
-             (jax.tree_util.GetAttrKey("nnz"), t.nnz)),
+             (jax.tree_util.GetAttrKey("nnz"), t.nnz),
+             (jax.tree_util.GetAttrKey("slabs"), t.slabs)),
             (t.shape, t.m_tb, t.k_tb, t.dtype))
 
 
 def _tcsl_unflatten(aux, children):
-    words, nnz = children
+    words, nnz, slabs = children
     shape, m_tb, k_tb, dtype = aux
-    return TiledCSL(words=words, nnz=nnz, shape=shape, m_tb=m_tb, k_tb=k_tb,
-                    dtype=dtype)
+    return TiledCSL(words=words, nnz=nnz, slabs=slabs, shape=shape,
+                    m_tb=m_tb, k_tb=k_tb, dtype=dtype)
 
 
 jax.tree_util.register_pytree_with_keys(
@@ -192,15 +200,15 @@ def pad_slots(t: TiledCSL, slots: int) -> TiledCSL:
     """Pad ``t`` to ``slots`` word slots per column with ``PAD_WORD``.
 
     jit-safe (pure pad). Used to give a scan stack or a projection group
-    one shared slot count."""
+    one shared slot count. Padding adds only pad slabs, so ``slabs`` stays."""
     if slots < t.slots:
         raise ValueError(f"cannot pad {t.slots} slots down to {slots}")
     if slots == t.slots:
         return t
     widths = ((0, 0),) * (t.words.ndim - 2) + ((0, slots - t.slots), (0, 0))
     words = jnp.pad(t.words, widths, constant_values=PAD_WORD)
-    return TiledCSL(words=words, nnz=t.nnz, shape=t.shape, m_tb=t.m_tb,
-                    k_tb=t.k_tb, dtype=t.dtype)
+    return TiledCSL(words=words, nnz=t.nnz, slabs=t.slabs, shape=t.shape,
+                    m_tb=t.m_tb, k_tb=t.k_tb, dtype=t.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -243,11 +251,14 @@ def encode(dense: np.ndarray | jax.Array,
         slot = cum.reshape(m, k)[rr, cc] - 1
         words[rr // m_tb, cc // k_tb, slot, cc % k_tb] = pack_words(
             a[rr, cc], rr % m_tb)
-    nnz = col_counts.reshape(mt, kt, k_tb).sum(axis=-1, dtype=np.int32)
+    tile_cols = col_counts.reshape(mt, kt, k_tb)
+    nnz = tile_cols.sum(axis=-1, dtype=np.int32)
+    slabs = -(-tile_cols.max(axis=-1) // SLOT_QUANTUM)
 
     return TiledCSL(
         words=jnp.asarray(words),
         nnz=jnp.asarray(nnz),
+        slabs=jnp.asarray(slabs, jnp.int32),
         shape=(m, k),
         m_tb=m_tb,
         k_tb=k_tb,
@@ -279,11 +290,11 @@ def group_stack(ts: Sequence[TiledCSL]) -> TiledCSL:
     """Stack already-encoded same-shape TiledCSLs into a grouped TiledCSL.
 
     Pads every member to the group's largest ``slots`` (:func:`pad_slots`)
-    and stacks ``words``/``nnz``. jit-safe: pure pad/stack, usable at trace
-    time on weights captured as arguments — though the production path
-    pre-groups once at weight-reformat time (:func:`encode_group` /
-    ``pruning.group_projections``) so the serving hot path carries no
-    restacking traffic.
+    and stacks ``words``/``nnz``/``slabs``. jit-safe: pure pad/stack,
+    usable at trace time on weights captured as arguments — though the
+    production path pre-groups once at weight-reformat time
+    (:func:`encode_group` / ``pruning.group_projections``) so the serving
+    hot path carries no restacking traffic.
 
     Members that are themselves layer-stacked scan leaves (words
     ``[L, mt, kt, slots, k_tb]``, as produced by ``pruning.sparsify_params``
@@ -308,7 +319,8 @@ def group_stack(ts: Sequence[TiledCSL]) -> TiledCSL:
     mx = max(t.slots for t in ts)
     words = jnp.stack([pad_slots(t, mx).words for t in ts], axis=lead)
     nnz = jnp.stack([t.nnz for t in ts], axis=lead)
-    return TiledCSL(words=words, nnz=nnz, shape=ts[0].shape,
+    slabs = jnp.stack([t.slabs for t in ts], axis=lead)
+    return TiledCSL(words=words, nnz=nnz, slabs=slabs, shape=ts[0].shape,
                     m_tb=ts[0].m_tb, k_tb=ts[0].k_tb, dtype=ts[0].dtype)
 
 
@@ -316,8 +328,23 @@ def group_slice(t: TiledCSL, g: int) -> TiledCSL:
     """Member ``g`` of a grouped TiledCSL as a plain encoding."""
     if t.group is None:
         raise ValueError("group_slice needs a grouped TiledCSL")
-    return TiledCSL(words=t.words[g], nnz=t.nnz[g], shape=t.shape,
-                    m_tb=t.m_tb, k_tb=t.k_tb, dtype=t.dtype)
+    return TiledCSL(words=t.words[g], nnz=t.nnz[g], slabs=t.slabs[g],
+                    shape=t.shape, m_tb=t.m_tb, k_tb=t.k_tb, dtype=t.dtype)
+
+
+def slab_share(params) -> Optional[float]:
+    """Share (%) of the full compare-select expansion that the LSCD kernels
+    run over every Tiled-CSL leaf of ``params``: the tiles' ``slabs`` over
+    ``slots / SLOT_QUANTUM`` slabs a tile. None without a Tiled-CSL leaf."""
+    leaves = [x for x in jax.tree.leaves(
+        params, is_leaf=lambda x: isinstance(x, TiledCSL))
+        if isinstance(x, TiledCSL)]
+    full = sum(x.slabs.size * (x.slots // SLOT_QUANTUM) for x in leaves)
+    if not full:
+        return None
+    run = sum(int(np.asarray(jax.device_get(x.slabs), np.int64).sum())
+              for x in leaves)
+    return 100.0 * run / full
 
 
 def decode(t: TiledCSL) -> np.ndarray:
@@ -343,9 +370,9 @@ def decode_jax(t: TiledCSL) -> jax.Array:
     Grouped encodings decode to ``[G, m, k]`` (vmapped over the group axis).
     """
     if t.group is not None:
-        return jax.vmap(lambda w, n: decode_jax(TiledCSL(
-            words=w, nnz=n, shape=t.shape, m_tb=t.m_tb, k_tb=t.k_tb,
-            dtype=t.dtype)))(t.words, t.nnz)
+        return jax.vmap(lambda w, n, s: decode_jax(TiledCSL(
+            words=w, nnz=n, slabs=s, shape=t.shape, m_tb=t.m_tb,
+            k_tb=t.k_tb, dtype=t.dtype)))(t.words, t.nnz, t.slabs)
     words = t.words.astype(jnp.uint32)
     real = (words & 0xFFFF) != PAD_ROW
     # Padding slots add +0.0 at their tile's (0, column): exact no-ops.

@@ -119,7 +119,8 @@ def rule_for(path: str, ndim: int, *, fsdp: bool = False,
     v5e (params+AdamW moments / 256 chips). GSPMD inserts the per-layer
     all-gathers inside the scan (the overlap is the pipeliner's job)."""
     is_words = path.endswith(".words")
-    is_nnz = path.endswith(".nnz")
+    # the per-tile counts (nnz, slabs) shard as the tiles do
+    is_nnz = path.endswith((".nnz", ".slabs"))
     other = "data" if fsdp else None
 
     def family(names) -> bool:

@@ -16,9 +16,11 @@ re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
   fields are compared with the row index of every tile element and its
   values selected where they match (paper: ``rst_smem`` + ``extract`` on
   SIMT cores). Mosaic has no scatter, so the paper's per-word store becomes
-  ``slots`` full-tile selects. Padding slots carry a row no tile has and
-  never match, so the inner loop needs no bound (the paper needs Alg.2's
-  ``nnz_thread``).
+  one full-tile select per slot. Slots run in 8-slot slabs, and each
+  tile's loop stops at its own last slab that holds a real word (the
+  format's ``slabs``; the paper's Alg.2 bounds its loop by ``nnz_thread``
+  likewise): the slabs past it hold only padding, whose row no tile has,
+  so skipping them changes no bit.
 * **Compute-as-Dense**: a full ``(M_TB, K_TB) @ (K_TB, N_TB)`` MXU matmul per
   tile in B's dtype, ``preferred_element_type=f32`` — redundant FLOPs
   tolerated because the op is memory-bound (paper §3.2.2); the tile's
@@ -27,12 +29,13 @@ re-derived for the TPU memory hierarchy (DESIGN.md §2, §4):
   Mosaic grid pipeliner (HBM→VMEM DMA of block *i+1* overlaps the body of
   block *i*); intra-iteration overlap is the DMA engine running async with
   the VPU expansion and MXU dot by construction.
-* **TileOffsets prefetch** (paper Alg.1 lines 5-12): the per-tile ``nnz``
-  array rides in SMEM via ``PrefetchScalarGridSpec`` scalar prefetch and
-  gates an all-zero-tile fast path (``pl.when(nnz > 0)``) — a beyond-paper
-  micro-optimisation that exactness of padding makes free.
+* **TileOffsets prefetch** (paper Alg.1 lines 5-12): the per-tile
+  ``slabs`` array rides in SMEM via ``PrefetchScalarGridSpec`` scalar
+  prefetch; it bounds each tile's expansion loop and gates an
+  all-zero-tile fast path (``pl.when(slabs > 0)``) — beyond-paper
+  micro-optimisations that exactness of padding makes free.
 * **Several K tiles per grid step**: each step expands and multiplies
-  ``d`` tiles in K order (:func:`_block_dot`), each behind its own nnz
+  ``d`` tiles in K order (:func:`_block_dot`), each behind its own slab
   gate, into the same f32 accumulator — the association of a
   one-tile-per-step loop, bit for bit — so the per-step cost (pipeline
   bookkeeping, DMA issue and wait) is paid once per ``d`` tiles. ``d`` is
@@ -155,16 +158,18 @@ def epilogue_kind(name: str, *, groups: int = 1) -> str:
     raise ValueError(f"unknown epilogue {name!r}; known: {known}")
 
 
-def _expand_tile(words_ref, tile, m_tb: int, dtype) -> jax.Array:
+def _expand_tile(words_ref, tile, n_slabs, m_tb: int, dtype) -> jax.Array:
     """Tile ``tile`` of the column-slotted words block ``[d, slots, k_tb]`` →
     dense ``[m_tb, k_tb]`` tile.
 
-    Reads the slots in 8-row slabs (one uint32 vreg of sublanes); each slot
-    row broadcasts down the tile and selects its value where its row field
-    equals the element's row. An element takes at most one slot's value
-    (rows are unique within a column), so the selects chain without adds.
+    Reads the tile's first ``n_slabs`` 8-row slabs (one uint32 vreg of
+    sublanes each; the slabs after them, up to the block's ``slots``, hold
+    only padding); each slot row broadcasts down the tile and selects its
+    value where its row field equals the element's row. An element takes at
+    most one slot's value (rows are unique within a column), so the selects
+    chain without adds.
     """
-    _, slots, k_tb = words_ref.shape
+    k_tb = words_ref.shape[-1]
     q = tiled_csl.SLOT_QUANTUM
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (m_tb, k_tb), 0)
 
@@ -179,15 +184,16 @@ def _expand_tile(words_ref, tile, m_tb: int, dtype) -> jax.Array:
             a = jnp.where(row_ids == rows[j:j + 1], vals[j:j + 1], a)
         return a
 
-    a = jax.lax.fori_loop(0, slots // q, slab,
+    a = jax.lax.fori_loop(0, n_slabs, slab,
                           jnp.zeros((m_tb, k_tb), jnp.float32))
     return a.astype(dtype)
 
 
-def _block_dot(words_ref, b_ref, m_tb: int, tile_nnz, add) -> None:
+def _block_dot(words_ref, b_ref, m_tb: int, tile_slabs, add) -> None:
     """One grid step's contribution, tile by tile in K order: tile ``j`` of
-    the ``d``-tile block is expanded and multiplied by its ``k_tb`` rows of
-    B when ``tile_nnz(j) > 0``, and its f32 product handed to ``add``.
+    the ``d``-tile block is expanded over its ``tile_slabs(j)`` slabs and
+    multiplied by its ``k_tb`` rows of B when that count is above 0 (the
+    tile is not empty), and its f32 product handed to ``add``.
 
     The loop is unrolled (``d`` is at most ``contracts.MAX_TILES_PER_STEP``):
     no loop bookkeeping between tiles, and constant tile offsets. Unrolled
@@ -198,11 +204,13 @@ def _block_dot(words_ref, b_ref, m_tb: int, tile_nnz, add) -> None:
     d, _, k_tb = words_ref.shape
 
     def tile(j, carry):
-        @pl.when(tile_nnz(j) > 0)
+        n_slabs = tile_slabs(j)
+
+        @pl.when(n_slabs > 0)
         def _body():
             # sparse -> dense transform (paper Fig.6b; VPU compare-select),
             # then compute-as-dense (MXU)
-            a = _expand_tile(words_ref, j, m_tb, b_ref.dtype)
+            a = _expand_tile(words_ref, j, n_slabs, m_tb, b_ref.dtype)
             rows = pl.ds(pl.multiple_of(j * k_tb, k_tb), k_tb)
             add(jnp.dot(a, b_ref[rows, :],
                         preferred_element_type=jnp.float32))
@@ -236,7 +244,7 @@ def _words_spec(t: tiled_csl.TiledCSL, d: int, index_map) -> pl.BlockSpec:
                         index_map)
 
 
-def _lscd_spmm_kernel(nnz_ref,            # SMEM int32[Mt, Kt] (scalar prefetch)
+def _lscd_spmm_kernel(slabs_ref,          # SMEM int32[Mt, Kt] (scalar prefetch)
                       words_ref,          # VMEM uint32[d, slots, K_TB]
                       b_ref,              # VMEM bf16/f32[d*K_TB, N_TB]
                       o_ref,              # VMEM out[M_TB, N_TB]
@@ -256,7 +264,7 @@ def _lscd_spmm_kernel(nnz_ref,            # SMEM int32[Mt, Kt] (scalar prefetch)
     def _add(contrib):
         acc_ref[...] += contrib
 
-    _block_dot(words_ref, b_ref, m_tb, lambda j: nnz_ref[m, kb * d + j],
+    _block_dot(words_ref, b_ref, m_tb, lambda j: slabs_ref[m, kb * d + j],
                _add)
 
     @pl.when(kb == k_blocks - 1)
@@ -302,11 +310,11 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
 
     in_specs = [
         # d compressed A tiles: the ONLY A traffic (load-as-sparse).
-        _words_spec(t, d, lambda m_, n_, k_, nnz: (m_, k_, 0, 0)),
+        _words_spec(t, d, lambda m_, n_, k_, slabs: (m_, k_, 0, 0)),
         # The d tiles' rows of the dense activation.
-        pl.BlockSpec((d * t.k_tb, n_tb), lambda m_, n_, k_, nnz: (k_, n_)),
+        pl.BlockSpec((d * t.k_tb, n_tb), lambda m_, n_, k_, slabs: (k_, n_)),
     ]
-    args = [t.nnz, t.words, b]
+    args = [t.slabs, t.words, b]
     body = dict(m_tb=t.m_tb, k_blocks=grid[2], epilogue=epilogue)
     if bias is None:
         kernel = functools.partial(_lscd_spmm_kernel, bias_ref=None, **body)
@@ -314,7 +322,7 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
         # bias tile rides along as [M_TB, 1] broadcast in the epilogue
         kernel = functools.partial(_lscd_spmm_kernel_bias, **body)
         in_specs.append(
-            pl.BlockSpec((t.m_tb, 1), lambda m_, n_, k_, nnz: (m_, 0)))
+            pl.BlockSpec((t.m_tb, 1), lambda m_, n_, k_, slabs: (m_, 0)))
         args.append(bias.reshape(m, 1).astype(jnp.float32))
 
     return pl.pallas_call(
@@ -323,7 +331,7 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((t.m_tb, n_tb), lambda m_, n_, k_, nnz: (m_, n_)),
+            out_specs=pl.BlockSpec((t.m_tb, n_tb), lambda m_, n_, k_, slabs: (m_, n_)),
             scratch_shapes=[pltpu.VMEM((t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -334,10 +342,10 @@ def lscd_spmm(t: tiled_csl.TiledCSL,
     )(*args)
 
 
-def _lscd_spmm_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref, o_ref,
+def _lscd_spmm_kernel_bias(slabs_ref, words_ref, b_ref, bias_ref, o_ref,
                            acc_ref, *, m_tb, k_blocks, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
-    _lscd_spmm_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
+    _lscd_spmm_kernel(slabs_ref, words_ref, b_ref, o_ref, acc_ref,
                       m_tb=m_tb, k_blocks=k_blocks,
                       epilogue=epilogue, bias_ref=bias_ref)
 
@@ -355,7 +363,7 @@ def _add_group(acc_ref, g, groups: int, contrib) -> None:
             acc_ref[gi] += contrib
 
 
-def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
+def _lscd_spmm_grouped_kernel(slabs_ref,  # SMEM int32[G, Mt, Kt]
                               words_ref,  # VMEM uint32[d, slots, K_TB]
                               b_ref,      # VMEM bf16/f32[d*K_TB, N_TB]
                               o_ref,      # VMEM out[G, M_TB, N_TB] (unary)
@@ -379,7 +387,7 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _block_dot(words_ref, b_ref, m_tb, lambda j: nnz_ref[g, m, kb * d + j],
+    _block_dot(words_ref, b_ref, m_tb, lambda j: slabs_ref[g, m, kb * d + j],
                functools.partial(_add_group, acc_ref, g, groups))
 
     def _biased(gi, acc):
@@ -404,11 +412,11 @@ def _lscd_spmm_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
                     o_ref[gi] = out.astype(o_ref.dtype)
 
 
-def _lscd_spmm_grouped_kernel_bias(nnz_ref, words_ref, b_ref, bias_ref,
+def _lscd_spmm_grouped_kernel_bias(slabs_ref, words_ref, b_ref, bias_ref,
                                    o_ref, acc_ref, *, m_tb, k_blocks,
                                    groups, epilogue):
     """Bias-carrying variant (separate because Pallas positional refs)."""
-    _lscd_spmm_grouped_kernel(nnz_ref, words_ref, b_ref, o_ref, acc_ref,
+    _lscd_spmm_grouped_kernel(slabs_ref, words_ref, b_ref, o_ref, acc_ref,
                               m_tb=m_tb, k_blocks=k_blocks,
                               groups=groups, epilogue=epilogue,
                               bias_ref=bias_ref)
@@ -456,11 +464,11 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
         # Group g's d compressed A tiles (the only A traffic). The B block
         # index is independent of g, so the pipeliner holds B resident
         # across the G inner steps.
-        _words_spec(t, d, lambda m_, n_, k_, g_, nnz: (g_, m_, k_, 0, 0)),
+        _words_spec(t, d, lambda m_, n_, k_, g_, slabs: (g_, m_, k_, 0, 0)),
         pl.BlockSpec((d * t.k_tb, n_tb),
-                     lambda m_, n_, k_, g_, nnz: (k_, n_)),
+                     lambda m_, n_, k_, g_, slabs: (k_, n_)),
     ]
-    args = [t.nnz, t.words, b]
+    args = [t.slabs, t.words, b]
     body = dict(m_tb=t.m_tb, k_blocks=grid[2], groups=groups,
                 epilogue=epilogue)
     if bias is None:
@@ -470,19 +478,19 @@ def lscd_spmm_grouped(t: tiled_csl.TiledCSL,
         kernel = functools.partial(_lscd_spmm_grouped_kernel_bias, **body)
         in_specs.append(
             pl.BlockSpec((groups, t.m_tb, 1),
-                         lambda m_, n_, k_, g_, nnz: (0, m_, 0)))
+                         lambda m_, n_, k_, g_, slabs: (0, m_, 0)))
         args.append(bias.reshape(groups, m, 1).astype(jnp.float32))
 
     if kind == "binary":
         out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
         out_specs = pl.BlockSpec((t.m_tb, n_tb),
-                                 lambda m_, n_, k_, g_, nnz: (m_, n_))
+                                 lambda m_, n_, k_, g_, slabs: (m_, n_))
     else:
         # The whole [G, M_TB, N_TB] column is one block: its index is
         # constant over (k, g), so it is written back once per (m, n).
         out_shape = jax.ShapeDtypeStruct((groups, m, n), out_dtype)
         out_specs = pl.BlockSpec((groups, t.m_tb, n_tb),
-                                 lambda m_, n_, k_, g_, nnz: (0, m_, n_))
+                                 lambda m_, n_, k_, g_, slabs: (0, m_, n_))
 
     return pl.pallas_call(
         kernel,
@@ -511,22 +519,22 @@ def _splitk_chunk(kt: int, split_k: int) -> int:
     """K tiles per split slice. The last slice may own fewer real tiles
     (Kt % S != 0); its out-of-range blocks (``d`` divides Kt, so a block is
     wholly real or wholly past the end) clamp their block index and are
-    predicated off via the nnz gate, contributing exact zeros."""
+    predicated off via the slab gate, contributing exact zeros."""
     return -(-kt // split_k)
 
 
-def _splitk_tile_nnz(nnz_row, k_tiles: int, kb, d: int):
-    """Tile ``j`` of global K block ``kb``'s nnz, 0 past the end of K: such
-    tiles read a clamped-index block but are masked off, so the partial
-    stays an exact zero."""
-    def tile_nnz(j):
+def _splitk_tile_slabs(slabs_row, k_tiles: int, kb, d: int):
+    """Tile ``j`` of global K block ``kb``'s slab count, 0 past the end of
+    K: such tiles read a clamped-index block but are masked off, so the
+    partial stays an exact zero."""
+    def tile_slabs(j):
         k = kb * d + j
-        return jnp.where(k < k_tiles, nnz_row(jnp.minimum(k, k_tiles - 1)),
-                         0)
-    return tile_nnz
+        return jnp.where(k < k_tiles,
+                         slabs_row(jnp.minimum(k, k_tiles - 1)), 0)
+    return tile_slabs
 
 
-def _lscd_spmm_splitk_kernel(nnz_ref,      # SMEM int32[Mt, Kt]
+def _lscd_spmm_splitk_kernel(slabs_ref,    # SMEM int32[Mt, Kt]
                              words_ref,    # VMEM uint32[d, slots, K_TB]
                              b_ref,        # VMEM bf16/f32[d*K_TB, N_TB]
                              p_ref,        # VMEM f32[1, M_TB, N_TB] partials
@@ -547,7 +555,7 @@ def _lscd_spmm_splitk_kernel(nnz_ref,      # SMEM int32[Mt, Kt]
         acc_ref[...] += contrib
 
     _block_dot(words_ref, b_ref, m_tb,
-               _splitk_tile_nnz(lambda k: nnz_ref[m, k], k_tiles, kb, d),
+               _splitk_tile_slabs(lambda k: slabs_ref[m, k], k_tiles, kb, d),
                _add)
 
     @pl.when(kl == k_blocks - 1)
@@ -616,14 +624,14 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
             num_scalar_prefetch=1,
             grid=(split_k,) + grid,
             in_specs=[
-                _words_spec(t, d, lambda s_, m_, n_, kl_, nnz:
+                _words_spec(t, d, lambda s_, m_, n_, kl_, slabs:
                             (m_, k_ix(s_, kl_), 0, 0)),
                 pl.BlockSpec((d * t.k_tb, n_tb),
-                             lambda s_, m_, n_, kl_, nnz: (k_ix(s_, kl_),
+                             lambda s_, m_, n_, kl_, slabs: (k_ix(s_, kl_),
                                                            n_)),
             ],
             out_specs=pl.BlockSpec((1, t.m_tb, n_tb),
-                                   lambda s_, m_, n_, kl_, nnz: (s_, m_, n_)),
+                                   lambda s_, m_, n_, kl_, slabs: (s_, m_, n_)),
             scratch_shapes=[pltpu.VMEM((t.m_tb, n_tb), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
@@ -632,7 +640,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(t.nnz, t.words, b)
+    )(t.slabs, t.words, b)
 
     in_specs = [pl.BlockSpec((split_k, t.m_tb, n_tb),
                              lambda m_, n_: (0, m_, n_))]
@@ -657,7 +665,7 @@ def lscd_spmm_splitk(t: tiled_csl.TiledCSL,
     )(*args)
 
 
-def _lscd_spmm_splitk_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
+def _lscd_spmm_splitk_grouped_kernel(slabs_ref,  # SMEM int32[G, Mt, Kt]
                                      words_ref,  # VMEM uint32[d, slots, K_TB]
                                      b_ref,      # VMEM bf16/f32[d*K_TB, N_TB]
                                      p_ref,      # VMEM f32[1, G, M_TB, N_TB]
@@ -677,7 +685,8 @@ def _lscd_spmm_splitk_grouped_kernel(nnz_ref,    # SMEM int32[G, Mt, Kt]
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     _block_dot(words_ref, b_ref, m_tb,
-               _splitk_tile_nnz(lambda k: nnz_ref[g, m, k], k_tiles, kb, d),
+               _splitk_tile_slabs(lambda k: slabs_ref[g, m, k], k_tiles, kb,
+                                  d),
                functools.partial(_add_group, acc_ref, g, groups))
 
     @pl.when((kl == k_blocks - 1) & (g == groups - 1))
@@ -750,14 +759,14 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
             num_scalar_prefetch=1,
             grid=(split_k,) + grid,
             in_specs=[
-                _words_spec(t, d, lambda s_, m_, n_, kl_, g_, nnz:
+                _words_spec(t, d, lambda s_, m_, n_, kl_, g_, slabs:
                             (g_, m_, k_ix(s_, kl_), 0, 0)),
                 pl.BlockSpec((d * t.k_tb, n_tb),
-                             lambda s_, m_, n_, kl_, g_, nnz:
+                             lambda s_, m_, n_, kl_, g_, slabs:
                              (k_ix(s_, kl_), n_)),
             ],
             out_specs=pl.BlockSpec((1, groups, t.m_tb, n_tb),
-                                   lambda s_, m_, n_, kl_, g_, nnz:
+                                   lambda s_, m_, n_, kl_, g_, slabs:
                                    (s_, 0, m_, n_)),
             scratch_shapes=[pltpu.VMEM((groups, t.m_tb, n_tb), jnp.float32)],
         ),
@@ -767,7 +776,7 @@ def lscd_spmm_splitk_grouped(t: tiled_csl.TiledCSL,
                                  "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(t.nnz, t.words, b)
+    )(t.slabs, t.words, b)
 
     if kind == "binary":
         out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)

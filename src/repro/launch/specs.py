@@ -57,6 +57,7 @@ def _csl_struct(out_dim: int, in_dim: int, sparsity: float,
     return tiled_csl.TiledCSL(
         words=_struct(lead + (mt, kt, slots, k_tb), jnp.uint32),
         nnz=_struct(lead + (mt, kt), jnp.int32),
+        slabs=_struct(lead + (mt, kt), jnp.int32),
         shape=(mp, kp), m_tb=m_tb, k_tb=k_tb, dtype=jnp.bfloat16)
 
 
@@ -95,8 +96,9 @@ def sparse_params_struct(cfg: ModelConfig, sparsity: float,
 
 def struct_weight_bytes(params) -> int:
     """HBM bytes of a params struct tree: TiledCSL leaves count their
-    encoded streams (4 B/word + 4 B/nnz-counter, = `tiled_csl.nbytes_sparse`),
-    dense leaves their array bytes. Works on real trees and on
+    encoded streams (4 B/word + 4 B/tile slab count, =
+    `tiled_csl.nbytes_sparse`) plus a 4 B/tile nnz counter, dense leaves
+    their array bytes. Works on real trees and on
     `params_struct` / `sparse_params_struct` ShapeDtypeStruct stand-ins —
     the basis of `serving.budget`'s weight term."""
     total = 0
@@ -106,6 +108,7 @@ def struct_weight_bytes(params) -> int:
         if isinstance(leaf, tiled_csl.TiledCSL):
             total += int(np.prod(leaf.words.shape)) * 4
             total += int(np.prod(leaf.nnz.shape)) * 4
+            total += int(np.prod(leaf.slabs.shape)) * 4
         else:
             total += int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
     return total

@@ -51,12 +51,14 @@ def _expert_ffn(params, xe: jax.Array) -> jax.Array:
     """
     def one(w_stack, x, out_dim):
         if isinstance(w_stack, tiled_csl.TiledCSL):
-            def apply_e(wl_words, wl_nnz, xl):
+            def apply_e(wl_words, wl_nnz, wl_slabs, xl):
                 t = tiled_csl.TiledCSL(
-                    words=wl_words, nnz=wl_nnz, shape=w_stack.shape,
-                    m_tb=w_stack.m_tb, k_tb=w_stack.k_tb, dtype=w_stack.dtype)
+                    words=wl_words, nnz=wl_nnz, slabs=wl_slabs,
+                    shape=w_stack.shape, m_tb=w_stack.m_tb,
+                    k_tb=w_stack.k_tb, dtype=w_stack.dtype)
                 return sparse_linear.linear_logical_out(t, out_dim, xl)
-            return jax.vmap(apply_e)(w_stack.words, w_stack.nnz, x)
+            return jax.vmap(apply_e)(w_stack.words, w_stack.nnz,
+                                     w_stack.slabs, x)
         return jnp.einsum("ecd,efd->ecf", x, w_stack.astype(x.dtype))
 
     dff = (params["gate"].shape[1] if not isinstance(params["gate"], tiled_csl.TiledCSL)

@@ -258,6 +258,8 @@ _SCHED_FIELDS = [
     ("degradation_level", "gauge", "1", "Current degradation ladder rung"),
     ("degradation_transitions", "counter", "1",
      "Degradation ladder rung changes"),
+    ("lscd_slab_share", "gauge", "%",
+     "Share of the full LSCD slab expansion the kernels run"),
 ]
 
 

@@ -76,6 +76,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.core import tiled_csl
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.obs.trace import Tracer, get_tracer
@@ -220,6 +221,8 @@ class ContinuousBatcher:
             chunked=self.chunked, chunk_size=sc.chunk_size,
             chunk_budget=sc.chunk_budget,
             clock=clock, degradation=degradation, tracer=self.tracer)
+        self.sched.metrics.lscd_slab_share = (
+            tiled_csl.slab_share(params) or 0.0)
         self.stepper = DeviceStepper(
             params, cfg, n_slots=sc.n_slots, max_len=sc.max_len,
             backend=config.backend,

@@ -14,10 +14,11 @@ larger block pool at equal total budget — the quantity the paged scheduler
 
 Weight bytes come from `launch.specs` weight-mode structs (the same
 accounting the dry-run uses): dense bf16 leaves, or Tiled-CSL encoded
-streams (`tiled_csl.nbytes_sparse`: 4 B/word + 4 B/nnz counter, at the
-analytic slot count of `roofline.analytic_max_nnz`). `sparse_pallas` and
-`sparse_xla` stream the same encoded bytes — the mode names the kernel, not
-the format — so both map to the sparse struct.
+streams (`tiled_csl.nbytes_sparse`: 4 B/word + 4 B/tile slab count, and a
+4 B/tile nnz counter, at the analytic slot count of
+`roofline.analytic_max_nnz`). `sparse_pallas` and `sparse_xla` stream the
+same encoded bytes — the mode names the kernel, not the format — so both
+map to the sparse struct.
 """
 
 from __future__ import annotations

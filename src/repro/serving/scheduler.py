@@ -161,6 +161,10 @@ class SchedulerMetrics:
     # (prefill k*bucket, decode n_slots, verify/mixed n_slots*W) — feeds
     # loadgen.CostClock so virtual latency charges bucket padding honestly
     compute_positions: int = 0
+    # gauge, set once from the served params: % of the full compare-select
+    # expansion the LSCD kernels run (tiled_csl.slab_share; 0 when no
+    # weight is Tiled-CSL)
+    lscd_slab_share: float = 0.0
     # per-class (SLOSpec.tenant) soft-target attainment, recorded at finish:
     # {"ttft_ok": n, "ttft_miss": n, "tpot_ok": n, "tpot_miss": n}
     slo_attainment: Dict[str, Dict[str, int]] = dataclasses.field(

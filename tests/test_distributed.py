@@ -61,6 +61,12 @@ def test_rule_for_expected_specs():
     # Tiled-CSL words [L, mt, kt, slots, k_tb] of a column-parallel weight
     assert sharding.rule_for("['layers']['mlp']['up']['w'].words", 5) == \
         P(None, "model", None, None, None)
+    # its per-tile counts [L, mt, kt] shard as its tiles do
+    for leaf in ("nnz", "slabs"):
+        assert sharding.rule_for(f"['layers']['mlp']['up']['w'].{leaf}",
+                                 3) == P(None, "model", None)
+        assert sharding.rule_for(f"['layers']['mlp']['down']['w'].{leaf}",
+                                 3) == P(None, None, "model")
     # fsdp adds data on the free dim
     assert sharding.rule_for("['layers']['attn']['wq']['w']", 3,
                              fsdp=True) == P(None, "model", "data")

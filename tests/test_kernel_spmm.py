@@ -86,7 +86,7 @@ def test_kernel_vs_dense_oracle():
     (6, 2),      # two 3-tile slices
 ])
 def test_empty_tiles_fast_path(kt, split_k):
-    """All-zero tiles exercise the nnz==0 pl.when skip branch, also when
+    """All-zero tiles (0 slabs) exercise the pl.when skip branch, also when
     they sit between non-zero tiles of one multi-tile grid step; an M row
     of zero tiles comes out as exact zeros."""
     rng = np.random.default_rng(0)
@@ -97,6 +97,7 @@ def test_empty_tiles_fast_path(kt, split_k):
     t = tiled_csl.encode(a)
     assert int(np.asarray(t.nnz)[1, 1]) == 0
     assert int(np.asarray(t.nnz)[0, 1]) == 0
+    assert ((np.asarray(t.slabs) == 0) == (np.asarray(t.nnz) == 0)).all()
     b = jnp.ones((kt * 128, 8), jnp.float32)
     got = ops.spmm(t, b, backend="interpret", out_dtype=jnp.float32,
                    split_k=split_k)
@@ -570,3 +571,77 @@ def test_grouped_xla_backend_matches_interpret():
                                out_dtype=jnp.float32, epilogue=epi)
         np.testing.assert_allclose(np.asarray(xla), np.asarray(itp),
                                    rtol=1e-5, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# per-tile slab bound: bit-exact against the full expansion
+# ---------------------------------------------------------------------------
+
+def _hetero_tiles(rng, mt, kt, member=0):
+    """A matrix whose tiles need different slab counts: every third tile
+    empty, the rest ~6% dense (1-2 slabs), and one column of one tile
+    (moved by ``member``) 45 deep, which sets the encoding's slots."""
+    a = rng.standard_normal((mt * 128, kt * 128), dtype=np.float32)
+    a[rng.random(a.shape) < 0.94] = 0.0
+    for i in range(mt * kt):
+        if i % 3 == 1:
+            mi, ki = divmod(i, kt)
+            a[mi * 128:(mi + 1) * 128, ki * 128:(ki + 1) * 128] = 0.0
+    mi, ki = divmod((mt * kt - 1 - 2 * member) % (mt * kt), kt)
+    if (mi * kt + ki) % 3 == 1:
+        ki = (ki + 1) % kt
+    col = a[mi * 128:(mi + 1) * 128, ki * 128 + 7]
+    col[:] = 0.0
+    col[:45] = rng.standard_normal(45) + 3.0
+    return a
+
+
+def _full_expansion(t):
+    """The same encoding expanding every non-empty tile over all its
+    slots, as the kernel did before each tile carried its own bound."""
+    import dataclasses
+    full = jnp.where(t.nnz > 0, t.slots // tiled_csl.SLOT_QUANTUM, 0)
+    return dataclasses.replace(t, slabs=full.astype(jnp.int32))
+
+
+@pytest.mark.parametrize("entry,kt,split_k,g,epilogue", [
+    ("lscd_spmm", 4, 1, None, "gelu"),                # d = 4
+    ("lscd_spmm_splitk", 16, 3, None, "silu"),        # ragged, d = 2
+    ("lscd_spmm_grouped", 4, 1, 3, "relu"),           # members differ
+    ("lscd_spmm_splitk_grouped", 16, 3, 2, "silu_mul"),
+])
+def test_slab_bound_bitmatches_full_expansion(entry, kt, split_k, g,
+                                              epilogue):
+    """Stopping each tile's expansion at its own last slab changes no bit:
+    the slabs skipped hold only padding. One launch mixes empty tiles,
+    1-2-slab tiles and one 6-slab tile; grouped members differ."""
+    from repro.kernels import spmm as spmm_mod
+    rng = np.random.default_rng(90 + kt + (g or 0))
+    mt, n = 2, 8
+    if g is None:
+        t = tiled_csl.encode(_hetero_tiles(rng, mt, kt))
+        bias = jnp.asarray(rng.standard_normal(mt * 128), jnp.float32)
+    else:
+        t = tiled_csl.encode_group([_hetero_tiles(rng, mt, kt, i)
+                                    for i in range(g)])
+        bias = jnp.asarray(rng.standard_normal((g, mt * 128)), jnp.float32)
+    slabs = np.asarray(t.slabs)
+    assert t.slots == 48 and slabs.max() == 6 and (slabs == 0).any()
+    assert set(np.unique(slabs[slabs > 0])) - {6}
+    if g is not None:
+        assert not np.array_equal(slabs[0], slabs[1])
+    _, d = spmm_mod.launch_grid(t, n, n_tb=n, split_k=split_k,
+                                b_dtype=jnp.float32, out_dtype=jnp.float32)
+    assert d > 1
+    b = jnp.asarray(rng.standard_normal((kt * 128, n), dtype=np.float32))
+    kw = dict(n_tb=n, interpret=True, epilogue=epilogue, bias=bias)
+    if split_k > 1:
+        kw["split_k"] = split_k
+    fn = getattr(spmm_mod, entry)
+    got = fn(t, b, **kw)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(fn(_full_expansion(t), b, **kw)))
+    oracle = ref.spmm_ref if g is None else ref.spmm_grouped_ref
+    want = oracle(t, b, out_dtype=jnp.float32, epilogue=epilogue, bias=bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)

@@ -7,6 +7,8 @@ import pytest
 
 from repro import configs
 from repro.analysis import budgets
+from repro.core import pruning, tiled_csl
+from repro.launch import serve
 from repro.models import transformer
 from repro.serving import batching, engine
 
@@ -213,3 +215,24 @@ def test_metrics_padding_overhead_zero_before_prefill():
     assert m.as_dict()["prefill_padding_overhead"] == 0.0
     m.prefill_tokens, m.padded_prefill_tokens = 6, 8
     assert m.prefill_padding_overhead == pytest.approx(0.25)
+
+
+def test_server_gauges_the_lscd_slab_share_of_its_params():
+    """``lscd_slab_share`` is set once from the served tree: 100 x the
+    tiles' summed slab counts over tiles x slots / 8, every Tiled-CSL leaf
+    (grouped and scan-stacked ones included); 0 with dense weights."""
+    cfg = configs.smoke("tinyllama_1_1b")
+    dense = transformer.init_model(jax.random.PRNGKey(0), cfg)
+    params = pruning.group_projections(pruning.sparsify_params(
+        dense, 0.8, should_sparsify=serve.should_sparsify))
+    csl = [x for x in jax.tree.leaves(
+        params, is_leaf=lambda x: isinstance(x, tiled_csl.TiledCSL))
+        if isinstance(x, tiled_csl.TiledCSL)]
+    assert any(x.words.ndim == 6 for x in csl)       # [L, G, ...] leaves
+    run = sum(int(np.asarray(x.slabs).sum()) for x in csl)
+    full = sum(int(np.prod(x.slabs.shape)) * x.slots // 8 for x in csl)
+    b = batching.ContinuousBatcher(params, cfg, n_slots=2, max_len=32)
+    assert b.metrics.lscd_slab_share == pytest.approx(100.0 * run / full)
+    assert 0.0 < b.metrics.lscd_slab_share < 100.0
+    assert batching.ContinuousBatcher(
+        dense, cfg, n_slots=2, max_len=32).metrics.lscd_slab_share == 0.0

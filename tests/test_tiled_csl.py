@@ -1,15 +1,19 @@
 """Tiled-CSL format: roundtrip, column-slotted layout invariants, padding
-accounting.
+accounting, the per-tile slab counts.
 
 Deterministic property sweeps (seeded grids over the same space the old
 hypothesis strategies drew from) + targeted unit tests.
 """
 
+import pickle
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analysis import contracts
-from repro.core import tiled_csl
+from repro.core import pruning, tiled_csl
 
 
 def _random_sparse(rng, m, k, sparsity):
@@ -190,7 +194,8 @@ def test_roundtrip_property(mt, kt, sparsity, seed, m_tb):
 def test_slot_layout_property(seed, sparsity, m_tb):
     """Every lane lists its tile column's non-zeros in ascending row order,
     packed to the front; the slot count is the fullest column's, rounded
-    up to SLOT_QUANTUM; ``nnz`` counts the real words per tile."""
+    up to SLOT_QUANTUM; ``nnz`` counts the real words per tile and
+    ``slabs`` the 8-slot slabs up to its fullest column's last word."""
     rng = np.random.default_rng(seed)
     a = _random_sparse(rng, 2 * m_tb, 256, sparsity)
     t = tiled_csl.encode(a, m_tb=m_tb)
@@ -205,6 +210,8 @@ def test_slot_layout_property(seed, sparsity, m_tb):
     r = np.where(real, rows, np.iinfo(np.int32).max)
     assert (np.diff(r, axis=2)[real[:, :, 1:]] > 0).all()
     np.testing.assert_array_equal(np.asarray(t.nnz), col_counts.sum(-1))
+    np.testing.assert_array_equal(np.asarray(t.slabs),
+                                  -(-col_counts.max(-1) // 8))
     assert (vals[~real] == 0.0).all()
 
 
@@ -294,3 +301,109 @@ def test_encode_group_rejects_mixed_shapes():
                                 _random_sparse(rng, 256, 128, 0.5)])
     with pytest.raises(ValueError):
         tiled_csl.encode_group([])
+
+
+# ---------------------------------------------------------------------------
+# per-tile slab counts
+# ---------------------------------------------------------------------------
+
+# (rows of column 0, rows of column 5) per tile of a 2 x 3 tile grid, and
+# the slab count each tile must carry: ceil(fullest column / 8), 0 empty.
+_SLAB_TILES = {(0, 0): ((1, 0), 1), (0, 1): ((0, 0), 0), (0, 2): ((9, 3), 2),
+               (1, 0): ((8, 8), 1), (1, 1): ((40, 17), 5),
+               (1, 2): ((0, 16), 2)}
+
+
+@pytest.mark.parametrize("m_tb", [128, 64])
+def test_slabs_bound_each_tile(m_tb):
+    """``slabs`` is ceil(fullest column count / 8) per tile and 0 exactly
+    on an empty tile; every slab past it holds only PAD_WORD."""
+    a = np.zeros((2 * m_tb, 3 * 128), np.float32)
+    for (mi, ki), ((c0, c5), _) in _SLAB_TILES.items():
+        r0, k0 = mi * m_tb, ki * 128
+        a[r0:r0 + c0, k0] = np.arange(1, c0 + 1)
+        a[r0 + m_tb - c5:r0 + m_tb, k0 + 5] = -np.arange(1, c5 + 1)
+    t = tiled_csl.encode(a, m_tb=m_tb)
+    assert t.slots == 40
+    slabs = np.asarray(t.slabs)
+    assert slabs.dtype == np.int32 and slabs.shape == np.asarray(t.nnz).shape
+    for (mi, ki), (_, want) in _SLAB_TILES.items():
+        assert slabs[mi, ki] == want, (mi, ki)
+    assert ((slabs == 0) == (np.asarray(t.nnz) == 0)).all()
+    words = np.asarray(t.words)
+    for mi, ki in np.ndindex(slabs.shape):
+        tail = words[mi, ki, slabs[mi, ki] * tiled_csl.SLOT_QUANTUM:]
+        assert (tail == tiled_csl.PAD_WORD).all()
+
+
+def _carries(t, want_slabs):
+    np.testing.assert_array_equal(np.asarray(t.slabs), want_slabs)
+
+
+@pytest.mark.parametrize("how", ["pad_slots", "group_stack", "scan_stack",
+                                 "pytree", "pickle", "jit"])
+def test_slabs_survive_every_reshaping(how):
+    rng = np.random.default_rng(70)
+    mats = [_random_sparse(rng, 256, 256, s) for s in (0.6, 0.95)]
+    ts = [tiled_csl.encode(m) for m in mats]
+    want = [np.asarray(t.slabs) for t in ts]
+    assert not np.array_equal(want[0], want[1])
+    t = ts[0]
+    if how == "pad_slots":
+        _carries(tiled_csl.pad_slots(t, t.slots + 16), want[0])
+    elif how == "group_stack":
+        tg = tiled_csl.group_stack(ts)
+        _carries(tg, np.stack(want))
+        for g in range(2):
+            _carries(tiled_csl.group_slice(tg, g), want[g])
+        _carries(tiled_csl.encode_group(mats), np.stack(want))
+    elif how == "scan_stack":
+        leaf = jnp.asarray(np.stack(mats))
+        sp = pruning.sparsify_params({"w": leaf}, 0.0,
+                                     should_sparsify=lambda n: True)["w"]
+        # encoded per layer, padded to one slot count and re-stacked
+        _carries(sp, np.stack([np.asarray(tiled_csl.encode(
+            np.asarray(leaf[i])).slabs) for i in range(2)]))
+        tg = tiled_csl.group_stack([sp, sp])      # [L, G, mt, kt]
+        assert np.asarray(tg.slabs).shape == (2, 2, 2, 2)
+    elif how == "pytree":
+        leaves, treedef = jax.tree_util.tree_flatten(t)
+        assert len(leaves) == 3
+        back = jax.tree_util.tree_unflatten(treedef, leaves)
+        _carries(back, want[0])
+        paths = [jax.tree_util.keystr(p) for p, _ in
+                 jax.tree_util.tree_flatten_with_path(t)[0]]
+        assert paths == [".words", ".nnz", ".slabs"]
+    elif how == "pickle":
+        _carries(pickle.loads(pickle.dumps(jax.device_get(t))), want[0])
+    else:
+        _carries(jax.jit(lambda x: x)(t), want[0])
+
+
+def test_decode_reads_only_the_words():
+    """``slabs`` is what the kernel may skip, never what the matrix is:
+    both decoders give the same matrix whatever it holds."""
+    import dataclasses
+    rng = np.random.default_rng(71)
+    tg = tiled_csl.encode_group([_random_sparse(rng, 256, 128, s)
+                                 for s in (0.5, 0.9)])
+    zeroed = dataclasses.replace(tg, slabs=jnp.zeros_like(tg.slabs))
+    np.testing.assert_array_equal(tiled_csl.decode(zeroed),
+                                  tiled_csl.decode(tg))
+    np.testing.assert_array_equal(np.asarray(tiled_csl.decode_jax(zeroed)),
+                                  np.asarray(tiled_csl.decode_jax(tg)))
+
+
+def test_slab_share_of_a_tree():
+    """Share of the full expansion run: summed slabs over tiles x
+    slots / 8, over every Tiled-CSL leaf; None without one."""
+    rng = np.random.default_rng(72)
+    a = tiled_csl.encode(_random_sparse(rng, 256, 256, 0.8))
+    b = tiled_csl.encode_group([_random_sparse(rng, 128, 128, s)
+                                for s in (0.5, 0.97)])
+    tree = {"a": a, "b": {"w": b}, "dense": jnp.ones(3)}
+    run = int(np.asarray(a.slabs).sum() + np.asarray(b.slabs).sum())
+    full = a.slabs.size * a.slots // 8 + b.slabs.size * b.slots // 8
+    assert tiled_csl.slab_share(tree) == pytest.approx(100 * run / full)
+    assert 0 < tiled_csl.slab_share(tree) < 100
+    assert tiled_csl.slab_share({"dense": jnp.ones(3)}) is None

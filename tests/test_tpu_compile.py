@@ -53,6 +53,7 @@ def _csl(m, k, sharding, group=None):
         words=_struct(lead + (mt, kt, slots, tiled_csl.DEFAULT_K_TB),
                       jnp.uint32, sharding),
         nnz=_struct(lead + (mt, kt), jnp.int32, sharding),
+        slabs=_struct(lead + (mt, kt), jnp.int32, sharding),
         shape=(m, k), m_tb=tiled_csl.DEFAULT_M_TB,
         k_tb=tiled_csl.DEFAULT_K_TB, dtype=jnp.bfloat16)
 
